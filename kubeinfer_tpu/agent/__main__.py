@@ -15,7 +15,9 @@ duties, so its env surface covers node identity and capacity:
   GPU_MEMORY           per-node accelerator memory, e.g. 16Gi (default 16Gi)
   AUTO_DETECT_ACCELERATORS  "1": observe local JAX devices (chip count +
                        HBM) instead of the GPU_CAPACITY/GPU_MEMORY env
-                       (explicit env still wins when both are set)
+                       (explicit env still wins when both are set).
+                       Observed once at start-up, in a child process:
+                       the agent itself never holds a chip
   TOPOLOGY             "rack,island" coordinates (default 0,0)
   HEARTBEAT_INTERVAL_S node-state heartbeat period (default 10)
   START_RUNTIMES       "1" to exec real inference runtimes (default 0)
@@ -63,20 +65,17 @@ def main() -> int:
     model_root = os.environ.get("MODEL_PATH", "/models")
     gpu_capacity = float(os.environ.get("GPU_CAPACITY", "8"))
     gpu_memory = parse_quantity(os.environ.get("GPU_MEMORY", "16Gi"))
-    observe_memory = None
     if os.environ.get("AUTO_DETECT_ACCELERATORS", "0") == "1":
-        from kubeinfer_tpu.agent.probe import probe_accelerators
+        from kubeinfer_tpu.agent.probe import probe_accelerators_in_child
 
-        def observe_memory():
-            i = probe_accelerators()
-            # knownness, not truthiness: free == 0 (HBM fully exhausted
-            # by an external process) is precisely the signal the solver
-            # must see (advisor r3)
-            if i is None or not i.memory_free_known:
-                return None
-            return i.memory_bytes, i.memory_free_bytes
-
-        info = probe_accelerators()
+        # A chip serves one process, and the runtimes this agent starts
+        # need it: the probe runs in a child that has exited — and let
+        # go of the chip — before any runtime starts. For the same
+        # reason there is no per-heartbeat probe here: it would have to
+        # take the chip from the runtime, and a fresh process sees only
+        # its own allocations anyway. NodeAgent's observe_memory hook
+        # stays for an observer that needs no device of its own.
+        info = probe_accelerators_in_child()
         if info is not None:
             log.info(
                 "observed %d %s device(s), %.1f GiB HBM",
@@ -120,7 +119,6 @@ def main() -> int:
         downloader=downloader,
         start_runtimes=start_runtimes,
         lease_timings=lease_timings,
-        observe_memory=observe_memory,
     )
 
     stop = threading.Event()

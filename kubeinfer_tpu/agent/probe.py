@@ -10,11 +10,22 @@ capacity vector from the hardware it actually sees —
 
 Everything degrades to None on machines without the source (no jax, no
 /proc) so env-configured capacity keeps working everywhere.
+
+An accelerator belongs to one process at a time: a process that has
+initialised the JAX backend holds the chip until it exits, and the
+inference server the agent starts (agent/runtime.py) then cannot have
+it. So the agent never probes in its own process — it runs this module
+as a short-lived child (``probe_accelerators_in_child``) that reports
+on stdout and releases the chip by exiting.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import json
 import logging
+import subprocess
+import sys
 from dataclasses import dataclass
 
 log = logging.getLogger(__name__)
@@ -69,6 +80,35 @@ def probe_accelerators() -> AcceleratorInfo | None:
     )
 
 
+def probe_accelerators_in_child(
+    timeout_s: float = 120.0,
+) -> AcceleratorInfo | None:
+    """probe_accelerators in a child that exits before this returns, so
+    the caller never holds a device. None when the child finds nothing,
+    fails, or outlives ``timeout_s`` (a chip held by another process
+    makes backend init fail or wait)."""
+    try:
+        out = subprocess.run(
+            [sys.executable, "-m", "kubeinfer_tpu.agent.probe"],
+            capture_output=True, text=True, timeout=timeout_s,
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        log.warning("accelerator probe child did not finish: %s", e)
+        return None
+    if out.returncode != 0:
+        log.warning(
+            "accelerator probe child exited %d: %s",
+            out.returncode, out.stderr.strip()[-300:],
+        )
+        return None
+    try:
+        doc = json.loads(out.stdout.strip().splitlines()[-1])
+    except (ValueError, IndexError):
+        log.warning("accelerator probe child printed no result")
+        return None
+    return None if doc is None else AcceleratorInfo(**doc)
+
+
 def probe_host_memory() -> tuple[int, int] | None:
     """(total, available) bytes from /proc/meminfo; None off-Linux."""
     try:
@@ -82,3 +122,9 @@ def probe_host_memory() -> tuple[int, int] | None:
         return total, avail
     except (OSError, KeyError, ValueError, IndexError):
         return None
+
+
+if __name__ == "__main__":
+    _info = probe_accelerators()
+    # lint: allow[log-discipline] child half of probe_accelerators_in_child: the JSON line on stdout IS the result
+    print(json.dumps(None if _info is None else dataclasses.asdict(_info)))

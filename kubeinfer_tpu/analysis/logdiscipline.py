@@ -18,8 +18,8 @@ Exempt (CLI surfaces that OWN their stdout/root-logger):
 
 - any ``__main__.py`` (agent/manager/analysis runners);
 - ``ctl.py`` (kubectl-style CLI: tables and JSON go to stdout);
-- ``bench.py`` / ``__graft_entry__.py`` (driver contracts: the single
-  JSON result line IS the interface);
+- ``bench.py`` / ``__graft_entry__.py`` / ``chip_smoke.py`` (driver
+  contracts: the JSON result lines ARE the interface);
 - anything under ``scripts/`` (ad-hoc profiling tools);
 - test files (pytest captures stdout; prints there are a debugging aid,
   not a logging-pipeline bypass).
@@ -37,7 +37,10 @@ from kubeinfer_tpu.analysis.jitlint import _dotted
 
 __all__ = ["run"]
 
-_EXEMPT_NAMES = {"__main__.py", "ctl.py", "bench.py", "__graft_entry__.py"}
+_EXEMPT_NAMES = {
+    "__main__.py", "ctl.py", "bench.py", "__graft_entry__.py",
+    "chip_smoke.py",
+}
 
 
 def _is_exempt(path: str) -> bool:

@@ -444,7 +444,7 @@ class Engine:
         included — decodes in ONE jit invocation: decode_scan carries a
         per-row cache offset, so no per-length grouping (the pre-ragged
         engine fragmented mixed traffic into per-length micro-batches,
-        forfeiting the batch-scaling BENCH_r04 measured).
+        forfeiting what batching buys).
         """
         if not prompts:
             return GenerationResult(

@@ -78,6 +78,46 @@ from kubeinfer_tpu.inference.model import attention as dense_attention
 TILE_T = 256  # query positions per tile (rows = TILE_T * G)
 TILE_S = 512  # key/value positions per tile
 
+# Mosaic gives one kernel 16 MiB of scoped VMEM by default on v5e (the
+# smallest of the supported chips); past it the compile is refused, and
+# by then the outer jit is lowering and nothing can fall back. The model
+# below is an estimate of the compiler's own count (it read 17.0 MiB
+# where the compiler reported 16.67 at gemma-2b widths), so the budget
+# keeps a quarter of the limit as margin.
+_VMEM_BUDGET = 12 << 20
+
+
+def _prefill_tiles(q, k, tile_t, tile_s):
+    """(tile_t, tile_s) the prefill kernels run with, from the
+    [B, T, n_heads, D] / [B, S, n_kv, D] operands every entry point
+    (forward, lse-forward, backward) holds — one resolution, so primal,
+    vjp-fwd and bwd always tile alike. The requested sizes are clamped
+    to the array, then tile_t is halved until the tile's VMEM footprint
+    fits the budget. The footprint grows with the MXU row count
+    tile_t * groups and with D — 8 query heads on one KV head at D=256
+    (gemma-2b) is 4x llama-3-8b's — so a fixed TILE_T cannot serve
+    every admitted model. Only tile_t moves: rows are independent of
+    one another, whereas tile_s sets the order the S sweep accumulates
+    in, and that order is what parity tests pin."""
+    T, groups, D = q.shape[1], q.shape[2] // k.shape[2], q.shape[3]
+    itemsize = q.dtype.itemsize
+    tile_t, tile_s = min(tile_t, T), min(tile_s, k.shape[1])
+
+    def footprint(tt):
+        rows = tt * groups
+        return (
+            2 * 2 * rows * D * itemsize  # q and o blocks, double-buffered
+            + 2 * 2 * tile_s * D * itemsize  # k and v blocks, likewise
+            + rows * D * 4  # acc scratch
+            + 2 * rows * 128 * 4  # m and l scratch, lane-padded
+            + 2 * rows * tile_s * 4  # f32 scores and probabilities
+        )
+
+    # a halved tile must stay sublane-aligned (flash_available's T % 8)
+    while footprint(tile_t) > _VMEM_BUDGET and tile_t % 16 == 0:
+        tile_t //= 2
+    return tile_t, tile_s
+
 
 def _fold_tile_math(
     q,  # [TqG, D] folded (t, g) query rows
@@ -266,12 +306,12 @@ def _run_flash(
 ) -> jax.Array:
     """Shared host plumbing: GQA row fold, tile validation, pallas_call,
     and the inverse fold. ``extra_arrays``/``extra_specs`` prepend the
-    kernel's mask source (int8 tensor or SMEM scalars)."""
+    kernel's mask source (int8 tensor or SMEM scalars). ``tile_t`` and
+    ``tile_s`` are the caller's _prefill_tiles result — the kernel partial
+    and the mask BlockSpec were built from the same pair."""
     B, T, n_heads, D = q.shape
     S, n_kv = k.shape[1], k.shape[2]
     G = n_heads // n_kv
-    tile_t = min(tile_t, T)
-    tile_s = min(tile_s, S)
     if T % tile_t or S % tile_s:
         raise ValueError(
             f"{name} needs T divisible by {tile_t} and S by {tile_s}; "
@@ -336,8 +376,7 @@ def flash_attention(
     ``attention_auto``.
     """
     n_kv = k.shape[2]
-    tt = min(tile_t, q.shape[1])
-    ts_ = min(tile_s, k.shape[1])
+    tt, ts_ = _prefill_tiles(q, k, tile_t, tile_s)
     return _run_flash(
         _flash_kernel,
         (mask.astype(jnp.int8),),
@@ -348,7 +387,7 @@ def flash_attention(
                 memory_space=pltpu.VMEM,
             ),
         ],
-        q, k, v, tile_t, tile_s, interpret, "flash_attention",
+        q, k, v, tt, ts_, interpret, "flash_attention",
     )
 
 
@@ -367,8 +406,7 @@ def flash_attention_ragged(
     ``(s <= q_offset + t) & (s < row_lens[b])``, computed in-kernel from
     scalars — nothing [T, S]-sized exists anywhere, in HBM or out."""
     n_kv = k.shape[2]
-    tt = min(tile_t, q.shape[1])
-    ts_ = min(tile_s, k.shape[1])
+    tt, ts_ = _prefill_tiles(q, k, tile_t, tile_s)
     lens = jnp.asarray(row_lens, jnp.int32)
     kern = functools.partial(
         _flash_ragged_kernel, tile_t=tt, tile_s=ts_, n_kv=n_kv
@@ -388,7 +426,7 @@ def flash_attention_ragged(
                 memory_space=pltpu.SMEM,
             ),
         ],
-        q, k, v, tile_t, tile_s, interpret, "flash_attention_ragged",
+        q, k, v, tt, ts_, interpret, "flash_attention_ragged",
     )
 
 
@@ -943,16 +981,16 @@ def decode_attention_blocks_auto(q, k_pool, v_pool, block_tables, lengths,
 # base (lengths - T) // bs read the bf16 tail verbatim, so the partial
 # block is bit-exact until the stepper commits it
 # (stepper._commit_full_tails). Scales ride the scalar-prefetch SMEM
-# path bitcast to i32 (SMEM is integer-typed; one f32 per (bh, ts) grid
-# step), the same trick as the guide's quantized-matmul example.
+# path as f32 (one scalar per (bh, ts) grid step). They must NOT travel
+# as i32 bits: Mosaic's tpu.bitcast takes vectors only, so a scalar
+# bitcast back to f32 passes interpret mode and is refused on the chip.
 
 
-def _dequant_tile(kq, scale_bits, tail, use_tail, out_dtype):
+def _dequant_tile(kq, scale, tail, use_tail, out_dtype):
     """One tile's effective K (or V): dequantized int8 page, or the
     bf16 tail verbatim when ``use_tail``. Shared verbatim by the q8
     kernel and its jnp twin — the bit-identity contract runs through
     this function exactly as the fold runs through _fold_tile_math."""
-    scale = jax.lax.bitcast_convert_type(scale_bits, jnp.float32)
     deq = kq.astype(jnp.float32) * scale
     return jnp.where(use_tail, tail.astype(jnp.float32), deq).astype(
         out_dtype
@@ -963,8 +1001,8 @@ def _decode_blocks_q8_kernel(
     tbl_ref,  # scalar-prefetch i32[B, max_blocks]
     len_ref,  # scalar-prefetch i32[B]
     tb_ref,  # scalar-prefetch i32[B]: first tail-resident block per row
-    ks_ref,  # scalar-prefetch i32[B*n_kv, max_blocks]: f32 K scales, bitcast
-    vs_ref,  # scalar-prefetch i32[B*n_kv, max_blocks]
+    ks_ref,  # scalar-prefetch f32[B*n_kv, max_blocks]: K scale per tile
+    vs_ref,  # scalar-prefetch f32[B*n_kv, max_blocks]
     q_ref,  # [1, T*G, D]
     k_ref,  # [1, 1, block_size, D] int8 pool tile
     v_ref,  # [1, 1, block_size, D] int8
@@ -1049,7 +1087,7 @@ def decode_attention_blocks_q8(
     """Paged decode attention over the quantized pool. Tile walk, DMA
     clamp, and penalty are decode_attention_blocks'; the new operands
     are the two scale rows (gathered through the table at trace time —
-    ONE f32 per folded tile — and prefetched to SMEM as i32 bits) and
+    ONE f32 per folded tile — and prefetched to SMEM) and
     the per-row tails, whose BlockSpec resolves tile ts to tail slot
     clip(ts - tail_base, 0, 1). tail_base is derived from ``lengths``
     (the window START block (lengths - T) // bs), not passed, so the
@@ -1071,16 +1109,9 @@ def decode_attention_blocks_q8(
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
     tb = jnp.maximum(lens - T, 0) // block_size  # i32[B]
-    # [B, max_blocks, n_kv] gather -> one scale per (row-head, tile),
-    # bitcast because scalar-prefetch SMEM is integer-typed
-    ksb = jax.lax.bitcast_convert_type(
-        k_scales[tbl].transpose(0, 2, 1).reshape(B * n_kv, max_blocks),
-        jnp.int32,
-    )
-    vsb = jax.lax.bitcast_convert_type(
-        v_scales[tbl].transpose(0, 2, 1).reshape(B * n_kv, max_blocks),
-        jnp.int32,
-    )
+    # [B, max_blocks, n_kv] gather -> one scale per (row-head, tile)
+    ksb = k_scales[tbl].transpose(0, 2, 1).reshape(B * n_kv, max_blocks)
+    vsb = v_scales[tbl].transpose(0, 2, 1).reshape(B * n_kv, max_blocks)
 
     def _kv_map(bh, ts, tbl_ref, lens_ref, tb_ref, ks_ref, vs_ref,
                 n_kv=n_kv, bs=block_size, nq=T):
@@ -1151,10 +1182,9 @@ def decode_attention_blocks_q8_jnp(
 ) -> jax.Array:
     """The q8 kernel's jnp twin: decode_attention_blocks_jnp's walk
     with _dequant_tile spliced in front of the fold, mirroring the
-    kernel op for op (same bitcast round-trip, same clip-to-tail-slot,
-    same cast order). Gathers the UNclamped table like the bf16 twin —
-    dead tiles fold exactly 0 on both sides whatever they dequantize
-    to."""
+    kernel op for op (same clip-to-tail-slot, same cast order). Gathers
+    the UNclamped table like the bf16 twin — dead tiles fold exactly 0
+    on both sides whatever they dequantize to."""
     B, T, n_heads, D = q.shape
     block_size, n_kv = k_pool.shape[1], k_pool.shape[2]
     max_blocks = block_tables.shape[1]
@@ -1172,14 +1202,8 @@ def decode_attention_blocks_q8_jnp(
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
     tb = jnp.maximum(lens - T, 0) // block_size
-    ksb = jax.lax.bitcast_convert_type(
-        k_scales[tbl].transpose(0, 2, 1).reshape(BH, max_blocks),
-        jnp.int32,
-    )
-    vsb = jax.lax.bitcast_convert_type(
-        v_scales[tbl].transpose(0, 2, 1).reshape(BH, max_blocks),
-        jnp.int32,
-    )
+    ksb = k_scales[tbl].transpose(0, 2, 1).reshape(BH, max_blocks)
+    vsb = v_scales[tbl].transpose(0, 2, 1).reshape(BH, max_blocks)
     row_tbl = jnp.repeat(tbl, n_kv, axis=0)
     row_head = jnp.tile(jnp.arange(n_kv, dtype=jnp.int32), B)
     row_len = jnp.repeat(lens, n_kv)
@@ -1495,8 +1519,7 @@ def _diff_fwd(interpret, q, k, v, q_offset, row_lens):
     B, T, n_heads, D = q.shape
     S, n_kv = k.shape[1], k.shape[2]
     G = n_heads // n_kv
-    tile_t = min(TILE_T, T)
-    tile_s = min(TILE_S, S)
+    tile_t, tile_s = _prefill_tiles(q, k, TILE_T, TILE_S)
     _check_diff_tiles(T, S, tile_t, tile_s)
     t_tiles, s_tiles = T // tile_t, S // tile_s
     qf = _fold_qlike(q, n_kv)
@@ -1553,8 +1576,7 @@ def _diff_bwd(interpret, res, do):
     B, T, n_heads, D = q.shape
     S, n_kv = k.shape[1], k.shape[2]
     G = n_heads // n_kv
-    tile_t = min(TILE_T, T)
-    tile_s = min(TILE_S, S)
+    tile_t, tile_s = _prefill_tiles(q, k, TILE_T, TILE_S)
     t_tiles, s_tiles = T // tile_t, S // tile_s
     scale = 1.0 / float(D) ** 0.5
     qf = _fold_qlike(q, n_kv)

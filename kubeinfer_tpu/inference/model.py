@@ -36,7 +36,7 @@ Params = dict[str, Any]
 
 def init_params(
     cfg: ModelConfig, key: jax.Array, dtype=jnp.float32,
-    weight_dtype: str = "bf16",
+    weight_dtype: str = "bf16", mesh=None,
 ) -> Params:
     """Random init (normal, 0.02 std — HF default) with HF tree layout.
 
@@ -45,10 +45,20 @@ def init_params(
     path in weights.params_from_state_dict — the full-precision layer
     never outlives the loop iteration. "bf16" (the dtype axis name, not
     a cast — ``dtype`` still controls precision) leaves the tree
-    byte-identical to the pre-quantization layout."""
+    byte-identical to the pre-quantization layout.
+
+    ``mesh`` (a tensor-parallel serving mesh) places each layer on its
+    shards as soon as it is built (sharding.param_placer): same values
+    as the unplaced tree, but the whole model never sits on one
+    device."""
     if weight_dtype not in ("bf16", "int8"):
         raise ValueError(f"weight_dtype must be bf16|int8: {weight_dtype!r}")
     k_embed, k_layers, k_head = jax.random.split(key, 3)
+
+    # lazy: sharding imports this module
+    from kubeinfer_tpu.inference.sharding import param_placer
+
+    put = param_placer(mesh, cfg)
 
     def dense(k, shape):
         return (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
@@ -90,14 +100,14 @@ def init_params(
             layer["v_bias"] = jnp.zeros((kv_dim,), dtype)
         if weight_dtype == "int8":
             layer = quantize_layer(layer)
-        layers.append(layer)
+        layers.append(put("layer", layer))
     params: Params = {
-        "embed_tokens": dense(k_embed, (V, H)),
+        "embed_tokens": put("embed_tokens", dense(k_embed, (V, H))),
         "layers": layers,
-        "norm": norm_init((H,), dtype),
+        "norm": put("norm", norm_init((H,), dtype)),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = dense(k_head, (H, V))
+        params["lm_head"] = put("lm_head", dense(k_head, (H, V)))
     return params
 
 

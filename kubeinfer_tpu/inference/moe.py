@@ -19,8 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from kubeinfer_tpu.utils.jaxcompat import shard_map
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = dict

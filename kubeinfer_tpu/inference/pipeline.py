@@ -19,8 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from kubeinfer_tpu.utils.jaxcompat import pcast, shard_map
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubeinfer_tpu.inference.config import ModelConfig
@@ -107,7 +106,7 @@ def _pp_fn(cfg: ModelConfig, mesh: Mesh, M: int, tied: bool):
         # pcast to 'varying': carries start as invariant zeros but hold
         # device-varying values after the first tick (shard_map scan
         # manual-axes typing, as in ring_attention.py)
-        buf = pcast(
+        buf = lax.pcast(
             jnp.zeros((B // M, T, H), other["norm"].dtype),
             ("pp",), to="varying",
         )  # inbound activation from the previous stage
@@ -115,7 +114,7 @@ def _pp_fn(cfg: ModelConfig, mesh: Mesh, M: int, tied: bool):
         # ~16-32x bigger for real models, and projecting per tick would
         # run the model's largest matmul PP*(M+PP-1) times instead of
         # once post-scan
-        acts = pcast(
+        acts = lax.pcast(
             jnp.zeros((M, B // M, T, H), other["norm"].dtype),
             ("pp",), to="varying",
         )

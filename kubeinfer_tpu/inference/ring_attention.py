@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from kubeinfer_tpu.utils.jaxcompat import axis_size, pcast
-
 
 def _block_attention(
     q: jax.Array,  # [B, Tq, n_kv, G, D] grouped query block
@@ -77,7 +75,7 @@ def ring_attention(
     merged into them, or shard_map's manual-axes type check rejects the
     carry.
     """
-    P = axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     B, T_loc, n_heads, D = q.shape
     n_kv = k.shape[2]
@@ -92,7 +90,7 @@ def ring_attention(
     # shard_map's manual-axes type check requires the carry declared
     # varying up front.
     def vary(x):
-        return pcast(x, (axis_name,) + extra_vary, to="varying")
+        return lax.pcast(x, (axis_name,) + extra_vary, to="varying")
 
     m = vary(jnp.full((B, n_kv, G, T_loc), -jnp.inf, jnp.float32))
     l = vary(jnp.zeros((B, n_kv, G, T_loc), jnp.float32))

@@ -331,6 +331,23 @@ def _serving_metrics(registry: Registry):
             "mesh",
             registry=registry,
         ),
+        # what the device itself reports, per local device: the two
+        # static gauges above are shape arithmetic, these include
+        # activations, compiler scratch and whatever else the process
+        # holds — and under tp they show whether the weights spread
+        "device_bytes_in_use": Gauge(
+            "kubeinfer_device_bytes_in_use",
+            "Device memory this process holds now, per local device "
+            "(memory_stats bytes_in_use; absent where the backend "
+            "reports no stats)",
+            labels=("device",), registry=registry,
+        ),
+        "device_peak_bytes_in_use": Gauge(
+            "kubeinfer_device_peak_bytes_in_use",
+            "High-water mark of device memory held by this process, "
+            "per local device (memory_stats peak_bytes_in_use)",
+            labels=("device",), registry=registry,
+        ),
         "requests_shed": Counter(
             "kubeinfer_requests_shed_total",
             "Completion requests refused at the admission door, by "
@@ -718,6 +735,18 @@ class InferenceServer:
         SLO gauges refresh even without a continuous engine — every
         route feeds _observe_breakdown, so the burn rates are
         meaningful for per-request/speculative-only servers too."""
+        import jax
+
+        for d in jax.local_devices():
+            stats = d.memory_stats() or {}
+            if "bytes_in_use" in stats:
+                self.metrics["device_bytes_in_use"].set(
+                    str(d.id), stats["bytes_in_use"]
+                )
+            if "peak_bytes_in_use" in stats:
+                self.metrics["device_peak_bytes_in_use"].set(
+                    str(d.id), stats["peak_bytes_in_use"]
+                )
         snap = self.slo.snapshot()
         for name, obj in snap["objectives"].items():
             for w, d in obj["windows"].items():
@@ -1515,6 +1544,9 @@ def main(argv: list[str] | None = None) -> int:
         # across every tracer in this process or ledgers shear mid-hop
         tracing.set_span_sampling(args.span_sample_every)
 
+    from kubeinfer_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 
@@ -1524,6 +1556,21 @@ def main(argv: list[str] | None = None) -> int:
 
     dtype = {"auto": jnp.bfloat16, "bfloat16": jnp.bfloat16,
              "float32": jnp.float32}[args.dtype]
+    mesh = None
+    if args.tensor_parallel_size > 1 or args.sequence_parallel_size > 1:
+        # GSPMD partitions the jitted forward over tp, and the SP
+        # engine shard_maps prefill over sp. Built BEFORE the weights:
+        # under tp every piece of the tree is placed on its shards as
+        # it is made (``mesh=`` below), so the whole model never sits
+        # on one device — at bf16 a 7B tree is 15 GB and one 16 GB
+        # chip cannot hold it even for the moment before resharding.
+        from kubeinfer_tpu.inference.sharding import make_inference_mesh
+
+        mesh = make_inference_mesh(
+            tp=args.tensor_parallel_size,
+            sp=args.sequence_parallel_size, dp=1,
+        )
+    tp_mesh = mesh if args.tensor_parallel_size > 1 else None
     tokenizer = None
     if args.random_init:
         # --model may be a preset name or (when the lifecycle layer passes
@@ -1535,12 +1582,14 @@ def main(argv: list[str] | None = None) -> int:
                      args.model)
             cfg = PRESETS["tiny"]
         params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype,
-                             weight_dtype=args.weight_dtype)
+                             weight_dtype=args.weight_dtype,
+                             mesh=tp_mesh)
     else:
         from kubeinfer_tpu.inference.weights import load_pretrained
 
         params, cfg = load_pretrained(args.model, dtype=dtype,
-                                      weight_dtype=args.weight_dtype)
+                                      weight_dtype=args.weight_dtype,
+                                      mesh=tp_mesh)
         tokenizer = _load_tokenizer(args.model)
     if args.weight_dtype == "int8" and args.sequence_parallel_size > 1:
         # the SP engine shard_maps with manual param_specs and has no
@@ -1554,21 +1603,6 @@ def main(argv: list[str] | None = None) -> int:
         max_cache = args.max_model_len
     else:
         max_cache = cfg.max_position_embeddings
-
-    mesh = None
-    if args.tensor_parallel_size > 1 or args.sequence_parallel_size > 1:
-        # place params on a tp x sp mesh; GSPMD partitions the jitted
-        # forward over tp, and the SP engine shard_maps prefill over sp
-        from kubeinfer_tpu.inference.sharding import (
-            make_inference_mesh, shard_params,
-        )
-
-        mesh = make_inference_mesh(
-            tp=args.tensor_parallel_size,
-            sp=args.sequence_parallel_size, dp=1,
-        )
-        if args.tensor_parallel_size > 1:
-            params = shard_params(params, mesh, cfg)
 
     sp_engine = None
     if args.sequence_parallel_size > 1:

@@ -32,7 +32,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from kubeinfer_tpu.utils.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeinfer_tpu.inference.config import ModelConfig
@@ -193,8 +193,32 @@ def expand_quant_specs(specs: Params, params: Params) -> Params:
     return jax.tree.map(one, specs, params)
 
 
+def param_placer(mesh: Mesh | None, cfg: ModelConfig):
+    """``place(name, piece)`` for the builders that make the tree piece
+    by piece (model.init_params, weights.params_from_state_dict): puts
+    one top-level piece — ``"layer"``, ``"embed_tokens"``, ``"norm"``
+    or ``"lm_head"`` — on its param_specs shards the moment it exists.
+    The builder's device then holds one layer at a time, never the
+    whole tree: shard_params on a finished tree needs the whole model
+    on one device first, which a 7B model at bf16 does not fit.
+    Without a mesh the piece stays where it was built."""
+    if mesh is None:
+        return lambda name, piece: piece
+    specs = param_specs(cfg)
+
+    def place(name: str, piece):
+        spec = specs["layers"][0] if name == "layer" else specs[name]
+        return jax.tree.map(
+            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+            piece, expand_quant_specs(spec, piece),
+        )
+
+    return place
+
+
 def shard_params(params: Params, mesh: Mesh, cfg: ModelConfig) -> Params:
-    """Place a param pytree onto the mesh per param_specs."""
+    """Place a FINISHED param pytree onto the mesh per param_specs
+    (trees that already fit one device; see param_placer)."""
     specs = param_specs(cfg)
     if "lm_head" not in params:
         specs = dict(specs)

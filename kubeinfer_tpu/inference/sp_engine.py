@@ -27,9 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from kubeinfer_tpu.utils.jaxcompat import shard_map
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubeinfer_tpu.inference.config import ModelConfig

@@ -16,9 +16,9 @@ in everything but intent (ROADMAP item 3). This module collapses them:
   ONE jitted dispatch runs K steps as a ``lax.scan`` over the donated
   :class:`SlotState` and returns the ``[n_slots, K]`` token matrix.
   K is static — the scheduler picks it from a small bucket set, one
-  compiled shape each — so the per-dispatch floor (BENCH_r02–r04:
-  ~70–90 ms on the relay vs ~1–4 ms of solve) is paid once per K
-  tokens instead of once per token.
+  compiled shape each — so the per-dispatch floor (scheduler pass,
+  jit call, readback sync) is paid once per K tokens instead of once
+  per token.
 
 Bit-identity across horizons is by construction, not luck: sampling
 keys are position-folded (``sample_rows`` folds ``offset + 1``; admit

@@ -19,6 +19,7 @@ import numpy as np
 
 from kubeinfer_tpu.inference.config import ModelConfig
 from kubeinfer_tpu.inference.model import Params
+from kubeinfer_tpu.inference.sharding import param_placer
 from kubeinfer_tpu.inference.weight_quant import quantize_weight
 
 
@@ -36,7 +37,7 @@ def _to_np(t) -> np.ndarray:
 
 def params_from_state_dict(
     sd: Mapping[str, object], cfg: ModelConfig, dtype=jnp.bfloat16,
-    weight_dtype: str = "bf16",
+    weight_dtype: str = "bf16", mesh=None,
 ) -> Params:
     """HF llama state dict (name -> tensor) -> model.py param pytree.
 
@@ -44,9 +45,14 @@ def params_from_state_dict(
     (weight_quant.quantize_weight on the host tensor), so the
     full-precision [in, out] device copy of a quantized leaf never
     exists — the largest device-resident transient is one layer's
-    quantization scratch, not the whole bf16 model."""
+    quantization scratch, not the whole bf16 model.
+
+    ``mesh`` places each layer on its tensor-parallel shards as it is
+    mapped, exactly as model.init_params does."""
     if weight_dtype not in ("bf16", "int8"):
         raise ValueError(f"weight_dtype must be bf16|int8: {weight_dtype!r}")
+
+    put = param_placer(mesh, cfg)
 
     def get(name: str) -> np.ndarray:
         for key in (name, f"model.{name}"):
@@ -111,19 +117,22 @@ def params_from_state_dict(
             layer["v_bias"] = jnp.asarray(
                 get(f"{p}.self_attn.v_proj.bias"), dtype
             )
-        layers.append(layer)
+        layers.append(put("layer", layer))
     params: Params = {
-        "embed_tokens": jnp.asarray(get("embed_tokens.weight"), dtype),
+        "embed_tokens": put(
+            "embed_tokens", jnp.asarray(get("embed_tokens.weight"), dtype)
+        ),
         "layers": layers,
-        "norm": jnp.asarray(get("norm.weight"), dtype),
+        "norm": put("norm", jnp.asarray(get("norm.weight"), dtype)),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = linear("lm_head.weight")
+        params["lm_head"] = put("lm_head", linear("lm_head.weight"))
     return params
 
 
 def load_pretrained(
-    model_dir: str, dtype=jnp.bfloat16, weight_dtype: str = "bf16"
+    model_dir: str, dtype=jnp.bfloat16, weight_dtype: str = "bf16",
+    mesh=None,
 ) -> tuple[Params, ModelConfig]:
     """Load (params, config) from an HF snapshot directory."""
     root = pathlib.Path(model_dir)
@@ -140,4 +149,4 @@ def load_pretrained(
         with safe_open(str(shard), framework="np") as f:
             for name in f.keys():
                 sd[name] = f.get_tensor(name)
-    return params_from_state_dict(sd, cfg, dtype, weight_dtype), cfg
+    return params_from_state_dict(sd, cfg, dtype, weight_dtype, mesh), cfg

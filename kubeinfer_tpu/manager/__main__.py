@@ -133,7 +133,11 @@ def main(argv: list[str] | None = None) -> int:
     # single-process): must happen before any jax usage so the solver's
     # mesh spans all hosts. See kubeinfer_tpu/distributed.py topology.
     from kubeinfer_tpu import distributed
+    from kubeinfer_tpu.utils.compile_cache import enable_compile_cache
 
+    # before the solver's first compile: a restarted manager reads its
+    # bucket programs back instead of compiling them inside a tick
+    enable_compile_cache()
     distributed.initialize()
 
     stop = threading.Event()

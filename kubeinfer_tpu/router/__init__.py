@@ -3,10 +3,9 @@
 The reference operator stops at placement — its controller creates pods
 and copies ready counts (llmservice_controller.go:66-174) but never
 touches a request; clients are assumed to sit behind a dumb Service VIP.
-At fleet scale that throws away the single largest serving win this
-repo has measured: a radix prefix hit cuts TTFT to ~0.37x cold
-(docs/PROFILING.md Round 7), and which replica a request lands on
-decides whether that hit exists. Routing IS the cache policy — the
+At fleet scale that throws away what the radix prefix cache buys: a
+hit skips the prefill of every shared block, and which replica a
+request lands on decides whether that hit exists. Routing IS the cache policy — the
 same insight behind SGLang's cache-aware router and Mooncake's
 KVCache-centric scheduling.
 

@@ -1358,8 +1358,8 @@ def solve_auction(
     )
     benefit = jnp.where(feas, -(static_cost + fit_cost), -INFEASIBLE)
 
-    # Price-war handling (r3 item 4) — three measured mechanisms; ref for
-    # the fixed-eps war they fix: BENCH_r03 cfg_1kx1k_auction_placed=995.
+    # Price-war handling — three mechanisms against the fixed-eps war,
+    # which left 5 of 1000 jobs unplaced on the whole-node 1k x 1k case.
     # (1) Selection tie-breaking: a parallel (Jacobi) auction on a
     # homogeneous fleet is degenerate — identical benefit rows make every
     # job's argmax the same first index, ONE bid wins per iteration, and a

@@ -60,12 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernels run on both sides of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 TILE_N = 128
 # 1024 measured best on v5e: full HBM bandwidth on the S sweep (746GB/s vs
 # 521GB/s at 512 — per-grid-step overhead bites below 1024) while keeping
@@ -318,8 +312,8 @@ def _accept_verdict_kernel(
     """Accept totals + fit verdicts + consumed capacity in ONE sweep —
     the accept_reduce kernel plus the ~6 inter-kernel [N]-vector fusions
     (fits_all/fits_win/used_*) that each cost dispatch latency in the
-    round's critical path (docs/PROFILING.md: the solve is
-    dispatch-bound, not bandwidth-bound)."""
+    round's critical path (the pipelined solve is launch-bound, not
+    bandwidth-bound: many small kernels per round)."""
     tn = pl.program_id(0)
     tj = pl.program_id(1)
     big = jnp.int32(_I32MAX)
@@ -602,9 +596,9 @@ def fence_minrank_pallas(
 
 # --- Round-fusion mega-kernel (class-serialized greedy) ---------------------
 #
-# The pipelined round loop above is dispatch-bound, not bandwidth-bound
-# (docs/PROFILING.md): ~47 XLA fusions + 7 Pallas launches per round at
-# ~170-195us/round, of which the actual S traffic is ~15-25us. The fix is to
+# The pipelined round loop above is launch-bound, not bandwidth-bound:
+# ~47 XLA fusions + 7 Pallas launches per round, next to which the actual
+# S traffic is small. The fix is to
 # stop paying per-round launches at all: serialize the priority fence classes
 # (the job axis arrives priority-sorted from backends.py, so a fence class is
 # a contiguous column window) and run EVERY settlement round of a class
@@ -938,7 +932,7 @@ def mega_solve_pallas(
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_MEGA_VMEM_LIMIT
         ),
     )(
@@ -1312,7 +1306,7 @@ def auction_solve(
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_MEGA_VMEM_LIMIT
         ),
     )(

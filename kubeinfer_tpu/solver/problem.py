@@ -330,9 +330,8 @@ def encode_problem_arrays(
 
 # --- single-buffer packing (one host->device transfer per solve) -----------
 #
-# Under a remote PJRT attachment every device_put pays per-transfer
-# overhead; 14 field transfers per solve cost more than the solve. The
-# packed path lays the whole problem into ONE contiguous f32 buffer
+# Every device_put is a dispatch of its own, and 14 field transfers per
+# solve are 14 of them next to one solve. The packed path lays the whole problem into ONE contiguous f32 buffer
 # (i32/bool regions bitcast — no value conversion) and unpacks with free
 # slices/bitcasts inside the jitted solve.
 #

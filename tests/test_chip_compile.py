@@ -1,0 +1,173 @@
+"""Main-path kernels compiled for a TPU v5e that is described, not attached.
+
+Interpret mode runs a Pallas kernel as jnp ops and accepts what Mosaic
+refuses: a scalar bitcast, a tile that overflows scoped VMEM, a slice off
+the (8, 128) tiling. The TPU's compiler is installed with libtpu and
+compiles for a topology description without a chip, so these cases hold
+every kernel the serving path and the solver launch to "the chip's
+compiler takes it at published widths" at no chip time. A compile that
+passes is not a chip run: results and times come from chip_smoke.py.
+
+This is the only file that describes the chip. Only one process may hold
+libtpu, so the description happens inside the module-scoped fixtures
+below — never at import, in a skipif, in parametrize arguments or in
+conftest — and every compile runs in this test's own process: under
+``-n 6 --dist loadfile`` all workers import this file and exactly one
+runs it.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without the chip: the next run would
+    warn and compile again. Keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+# published attention widths: (query heads, kv heads, head_dim)
+QWEN2_7B = (28, 4, 128)
+GEMMA_2B = (8, 1, 256)
+LLAMA3_8B = (32, 8, 128)
+
+
+def _ragged(heads, T, S):
+    """Chunked-prefill attention: a T-token chunk against an S-slot
+    cache. qwen2-7b runs at T=128: its 7-row GQA group is not sublane
+    aligned and Mosaic takes ~25 s over the T=256 tile, ~5 s here."""
+    from kubeinfer_tpu.inference.flash_attention import (
+        flash_attention_ragged,
+    )
+
+    nq, nkv, D = heads
+    return flash_attention_ragged, (
+        ((1, T, nq, D), BF16), ((1, S, nkv, D), BF16),
+        ((1, S, nkv, D), BF16), ((), I32), ((1,), I32),
+    )
+
+
+# the server's pool at --batch-slots 8 --max-model-len 4096
+_B, _BS, _MB = 8, 128, 32
+_NB = 1 + 2 * _B * _MB
+
+
+def _decode_blocks(T):
+    from kubeinfer_tpu.inference.flash_attention import (
+        decode_attention_blocks,
+    )
+
+    nq, nkv, D = QWEN2_7B
+    pool = ((_NB, _BS, nkv, D), BF16)
+    return decode_attention_blocks, (
+        ((_B, T, nq, D), BF16), pool, pool, ((_B, _MB), I32), ((_B,), I32),
+    )
+
+
+def _decode_blocks_q8(T):
+    from kubeinfer_tpu.inference.flash_attention import (
+        decode_attention_blocks_q8,
+    )
+
+    nq, nkv, D = QWEN2_7B
+    pool = ((_NB, _BS, nkv, D), I8)
+    scales = ((_NB, nkv), F32)
+    tail = ((_B, 2, _BS, nkv, D), BF16)
+    return decode_attention_blocks_q8, (
+        ((_B, T, nq, D), BF16), pool, pool, scales, scales, tail, tail,
+        ((_B, _MB), I32), ((_B,), I32),
+    )
+
+
+def _quant_matmul(M, K, N):
+    from kubeinfer_tpu.inference.weight_quant import quant_matmul
+
+    return quant_matmul, (((M, K), BF16), ((K, N), I8), ((N,), F32))
+
+
+def _route_pick(B, R):
+    from kubeinfer_tpu.solver.pallas_kernels import route_pick_pallas
+
+    return route_pick_pallas, (
+        ((B, R), I32), ((R,), F32), ((B,), jnp.bool_),
+    )
+
+
+def _packed_solve(J, N, policy, accel):
+    """The whole jitted solve the scheduler backend dispatches, on the
+    single packed buffer, with the accel pinned (``auto`` would ask
+    jax.default_backend(), which is the CPU here)."""
+    from kubeinfer_tpu.scheduler.backends import _packed_solver
+    from kubeinfer_tpu.solver.problem import packed_words
+
+    fn = functools.partial(
+        _packed_solver(), J=J, N=N, policy=policy, accel=accel,
+        seeded=False,
+    )
+    return fn, (((packed_words(J, N),), F32),)
+
+
+CASES = {
+    "ragged-qwen2-7b": lambda: _ragged(QWEN2_7B, 128, 4096),
+    "ragged-gemma-2b": lambda: _ragged(GEMMA_2B, 512, 4096),
+    "ragged-llama-3-8b": lambda: _ragged(LLAMA3_8B, 512, 4096),
+    "decode-blocks-T1": lambda: _decode_blocks(1),
+    "decode-blocks-T5": lambda: _decode_blocks(5),
+    "decode-blocks-q8-T1": lambda: _decode_blocks_q8(1),
+    "decode-blocks-q8-T5": lambda: _decode_blocks_q8(5),
+    "quant-matmul-8x3584x18944": lambda: _quant_matmul(8, 3584, 18944),
+    "quant-matmul-512x18944x3584": lambda: _quant_matmul(512, 18944, 3584),
+    "route-pick-256x128": lambda: _route_pick(256, 128),
+    "solve-mega-12288x1024": lambda: _packed_solve(
+        12288, 1024, "jax-greedy", "mega"),
+    "solve-auction-1024x1024": lambda: _packed_solve(
+        1024, 1024, "jax-auction", "pallas"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, one_chip, no_compile_cache):
+    fn, operands = CASES[case]()
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in operands
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # a router that fell to its dense branch would compile too
+    assert "tpu_custom_call" in compiled.as_text()

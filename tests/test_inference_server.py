@@ -107,9 +107,9 @@ class TestRuntimeLauncherIntegration:
         lifecycle (vllm.go Start/Stop parity)."""
         import socket
 
-        from tests.conftest import scrubbed_pythonpath
+        from tests.conftest import subprocess_pythonpath
 
-        monkeypatch.setenv("PYTHONPATH", scrubbed_pythonpath())
+        monkeypatch.setenv("PYTHONPATH", subprocess_pythonpath())
 
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))

@@ -10,9 +10,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from kubeinfer_tpu.utils.jaxcompat import shard_map
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubeinfer_tpu.inference import PRESETS, forward, init_params
@@ -42,6 +42,66 @@ class TestMesh:
     def test_oversized_mesh_rejected(self):
         with pytest.raises(ValueError):
             make_inference_mesh(tp=16)
+
+
+class TestPlacedAtBirth:
+    """init_params(mesh=) / params_from_state_dict(mesh=): every piece
+    lands on its param_specs shards as it is built — the same tree
+    shard_params would place, without ever holding it on one device."""
+
+    @pytest.mark.parametrize("weight_dtype", ["bf16", "int8"])
+    def test_init_params_mesh_equals_shard_params(self, weight_dtype):
+        from kubeinfer_tpu.inference.sharding import shard_params
+
+        import dataclasses
+
+        cfg = dataclasses.replace(TINY, qkv_bias=True)  # qwen2 family
+        mesh = make_inference_mesh(tp=2, sp=1, dp=1)
+        key = jax.random.PRNGKey(3)
+        plain = init_params(cfg, key, weight_dtype=weight_dtype)
+        born = init_params(cfg, key, weight_dtype=weight_dtype, mesh=mesh)
+        placed = shard_params(plain, mesh, cfg)
+        for got, want in zip(
+            jax.tree.leaves(born), jax.tree.leaves(placed), strict=True
+        ):
+            assert got.sharding == want.sharding
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want)
+            )
+        # the big leaves really are split, not replicated
+        q = born["layers"][0]["q_proj"]
+        q = q["qw"] if isinstance(q, dict) else q
+        assert q.addressable_shards[0].data.shape[1] == q.shape[1] // 2
+
+    def test_state_dict_mesh_equals_shard_params(self):
+        from kubeinfer_tpu.inference.sharding import shard_params
+        from kubeinfer_tpu.inference.weights import params_from_state_dict
+
+        mesh = make_inference_mesh(tp=2, sp=1, dp=1)
+        src = init_params(TINY, jax.random.PRNGKey(4))
+        sd = {"embed_tokens.weight": np.asarray(src["embed_tokens"]),
+              "norm.weight": np.asarray(src["norm"])}
+        if "lm_head" in src:
+            sd["lm_head.weight"] = np.asarray(src["lm_head"]).T
+        names = {"q_proj": "self_attn.q_proj", "k_proj": "self_attn.k_proj",
+                 "v_proj": "self_attn.v_proj", "o_proj": "self_attn.o_proj",
+                 "gate_proj": "mlp.gate_proj", "up_proj": "mlp.up_proj",
+                 "down_proj": "mlp.down_proj"}
+        for i, layer in enumerate(src["layers"]):
+            for ours, hf in names.items():
+                sd[f"layers.{i}.{hf}.weight"] = np.asarray(layer[ours]).T
+            for norm in ("input_layernorm", "post_attention_layernorm"):
+                sd[f"layers.{i}.{norm}.weight"] = np.asarray(layer[norm])
+        plain = params_from_state_dict(sd, TINY, jnp.float32)
+        born = params_from_state_dict(sd, TINY, jnp.float32, mesh=mesh)
+        placed = shard_params(plain, mesh, TINY)
+        for got, want in zip(
+            jax.tree.leaves(born), jax.tree.leaves(placed), strict=True
+        ):
+            assert got.sharding == want.sharding
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want)
+            )
 
 
 class TestTensorParallel:

@@ -683,7 +683,7 @@ def test_cli_entrypoints_exempt():
         print("ready")
     """
     for path in ("pkg/__main__.py", "pkg/ctl.py", "bench.py",
-                 "__graft_entry__.py", "scripts/tool.py",
+                 "__graft_entry__.py", "chip_smoke.py", "scripts/tool.py",
                  "tests/test_thing.py"):
         assert run_src(src, path=path) == []
     assert rules_of(run_src(src, path="pkg/server.py")) == [
@@ -1091,7 +1091,7 @@ def test_cycle_report_independent_of_edge_insertion_order():
 def test_repo_surface_has_zero_unsuppressed_findings():
     paths = [REPO / p for p in
              ("kubeinfer_tpu", "tests", "scripts", "bench.py",
-              "__graft_entry__.py")]
+              "__graft_entry__.py", "chip_smoke.py")]
     findings, nfiles = analyze_paths([p for p in paths if p.exists()])
     assert nfiles > 50, "scan surface collapsed — path wiring broke"
     msgs = "\n".join(f.render() for f in findings)
