@@ -64,7 +64,11 @@ from kubeinfer_tpu.inference.model import Params, forward
 from kubeinfer_tpu.observability import tracing
 from kubeinfer_tpu.observability.flightrecorder import FlightRecorder
 from kubeinfer_tpu.observability.slo import SLOMonitor, SLOObjective
-from kubeinfer_tpu.observability.stepprof import StepProfiler
+from kubeinfer_tpu.observability.stepprof import (
+    StepProfiler,
+    annotate,
+    profiling,
+)
 from kubeinfer_tpu.inference.sharding import EngineLayout
 from kubeinfer_tpu.inference.weight_quant import (
     params_weight_dtype,
@@ -612,6 +616,12 @@ class _Request:
     trace_parent: "tracing.SpanContext | None" = None
     t_submit: float = 0.0
     t_admit: float = 0.0
+    # t_admit - t_submit split at the first decode/verify window
+    # boundary at or after t_submit (ContinuousEngine._split_wait): the
+    # wait for the window in flight, and the rest (slots, pool, another
+    # request's admit). They sum to the queue wait.
+    wait_window_s: float = 0.0
+    wait_backlog_s: float = 0.0
     t_first: float = 0.0
     t_done: float = 0.0
     token_times: list[float] = field(default_factory=list)
@@ -905,6 +915,15 @@ class ContinuousEngine:
         self.preempted_total = 0  # telemetry: rows parked
         self.resumed_total = 0  # telemetry: parked rows readmitted
         self.chunks_total = 0  # telemetry: intermediate chunk dispatches
+        # prompt tokens of admitted requests by what happened to them:
+        # run through _prefill_chunk/_admit_slot, taken from the radix
+        # cache (reuse * block_size), or bucket padding (T - suffix)
+        self.prefill_tokens = {"computed": 0, "cached": 0, "padded": 0}
+        # step_t of recent decode/verify windows, for _split_wait; 4096
+        # windows is minutes of decoding at any step time seen so far
+        self._boundaries: collections.deque[float] = collections.deque(
+            maxlen=4096
+        )
         # step-level observability (docs/OBSERVABILITY.md): one record
         # per device dispatch, plus the scheduler-decision flight ring.
         # The kv_stats callback reads the pool's own locked counters and
@@ -1356,99 +1375,100 @@ class ContinuousEngine:
             task = self._imports.pop(0) if self._imports else None
         if task is None:
             return
-        n = int(task.pages_k.shape[1])
-        L = len(self._state.caches_k)
-        _nb, bs, n_kv, D = self._state.caches_k[0].shape
-        want = (L, n, bs, n_kv, D)
-        cache_dt = np.dtype(self._state.caches_k[0].dtype)
-        if (
-            np.dtype(task.pages_k.dtype) != cache_dt
-            or np.dtype(task.pages_v.dtype) != cache_dt
-        ):
-            # distinct from shape_mismatch: a dtype disagreement means
-            # the fleet mixes kv_dtype configurations, which the wire's
-            # version negotiation should have caught upstream
-            task.reason = "kv_dtype_mismatch"
-            self._note("import_reject", blocks=n, reason=task.reason)
-            task.done.set()
-            return
-        if (
-            tuple(task.pages_k.shape) != want
-            or tuple(task.pages_v.shape) != want
-        ):
-            task.reason = "shape_mismatch"
-            self._note("import_reject", blocks=n, reason=task.reason)
-            task.done.set()
-            return
-        # trie/pool mutations take _lock (HTTP threads walk the trie in
-        # cache_summary); the jit scatter between them stays OFF-lock —
-        # only this thread allocs, so the two sections can't interleave
-        start = task.start_block
-        with self._lock:
-            shared: list[int] = []
-            if start:
-                # chunked import (wire v3): this chunk stacks on the
-                # blocks the previous chunks inserted. The trie walk
-                # refs its matches (ours until the final insert/unref
-                # below); fewer matches than start_block means the base
-                # was evicted between chunks — reject rather than cache
-                # a chain with a hole, the importer restarts the prefix
-                matched = self._radix.match(
-                    task.tokens[: start * self.block_size]
-                )
-                if len(matched) < start:
-                    if matched:
-                        self._pool.unref(matched)
-                    task.reason = "missing_prefix"
-                    self._note("import_reject", blocks=n,
-                               reason=task.reason)
-                    task.done.set()
-                    return
-                shared = matched[:start]
-                if len(matched) > start:
-                    self._pool.unref(matched[start:])
-            if not self._radix.ensure_free(n):
-                if shared:
-                    self._pool.unref(shared)
-                task.reason = "backpressure"
+        with annotate("engine.import"):
+            n = int(task.pages_k.shape[1])
+            L = len(self._state.caches_k)
+            _nb, bs, n_kv, D = self._state.caches_k[0].shape
+            want = (L, n, bs, n_kv, D)
+            cache_dt = np.dtype(self._state.caches_k[0].dtype)
+            if (
+                np.dtype(task.pages_k.dtype) != cache_dt
+                or np.dtype(task.pages_v.dtype) != cache_dt
+            ):
+                # distinct from shape_mismatch: a dtype disagreement means
+                # the fleet mixes kv_dtype configurations, which the wire's
+                # version negotiation should have caught upstream
+                task.reason = "kv_dtype_mismatch"
                 self._note("import_reject", blocks=n, reason=task.reason)
                 task.done.set()
                 return
-            fresh = self._pool.alloc(n)
-        table_row = np.zeros(self.max_blocks, np.int32)
-        table_row[:n] = fresh
-        own_mask = np.zeros(self.max_blocks, bool)
-        own_mask[:n] = True
-        pk = np.zeros((L, self.max_blocks, bs, n_kv, D), cache_dt)
-        pk[:, :n] = task.pages_k
-        pv = np.zeros((L, self.max_blocks, bs, n_kv, D), cache_dt)
-        pv[:, :n] = task.pages_v
-        # all-ones padding keeps null-block scales at their init value;
-        # the bf16 pytree carries no scale leaves and jit drops these
-        sk = np.ones((L, self.max_blocks, n_kv), np.float32)
-        sv = np.ones((L, self.max_blocks, n_kv), np.float32)
-        if task.scales_k is not None:
-            sk[:, :n] = task.scales_k
-            sv[:, :n] = task.scales_v
-        # lint: allow[lock-discipline] scheduler thread is the only _state writer; see _loop
-        self._state = _import_blocks(
-            self._state, jnp.asarray(table_row), jnp.asarray(own_mask),
-            jnp.asarray(pk), jnp.asarray(pv),
-            jnp.asarray(sk), jnp.asarray(sv),
-        )
-        with self._lock:
-            # the insert covers the WHOLE chain so far (shared base +
-            # this chunk); the trie takes its own reference per block
-            # and both our holds return here, leaving the chain at
-            # trie-only refcount — LRU-evictable like any parked prefix
-            created = self._radix.insert(task.tokens, shared + fresh)
-            self._pool.unref(shared + fresh)
-        self.imports_total += 1
-        self.imported_blocks_total += n
-        task.imported = n
-        self._note("import", blocks=n, created_nodes=created,
-                   start_block=start)
-        task.done.set()
+            if (
+                tuple(task.pages_k.shape) != want
+                or tuple(task.pages_v.shape) != want
+            ):
+                task.reason = "shape_mismatch"
+                self._note("import_reject", blocks=n, reason=task.reason)
+                task.done.set()
+                return
+            # trie/pool mutations take _lock (HTTP threads walk the trie in
+            # cache_summary); the jit scatter between them stays OFF-lock —
+            # only this thread allocs, so the two sections can't interleave
+            start = task.start_block
+            with self._lock:
+                shared: list[int] = []
+                if start:
+                    # chunked import (wire v3): this chunk stacks on the
+                    # blocks the previous chunks inserted. The trie walk
+                    # refs its matches (ours until the final insert/unref
+                    # below); fewer matches than start_block means the base
+                    # was evicted between chunks — reject rather than cache
+                    # a chain with a hole, the importer restarts the prefix
+                    matched = self._radix.match(
+                        task.tokens[: start * self.block_size]
+                    )
+                    if len(matched) < start:
+                        if matched:
+                            self._pool.unref(matched)
+                        task.reason = "missing_prefix"
+                        self._note("import_reject", blocks=n,
+                                   reason=task.reason)
+                        task.done.set()
+                        return
+                    shared = matched[:start]
+                    if len(matched) > start:
+                        self._pool.unref(matched[start:])
+                if not self._radix.ensure_free(n):
+                    if shared:
+                        self._pool.unref(shared)
+                    task.reason = "backpressure"
+                    self._note("import_reject", blocks=n, reason=task.reason)
+                    task.done.set()
+                    return
+                fresh = self._pool.alloc(n)
+            table_row = np.zeros(self.max_blocks, np.int32)
+            table_row[:n] = fresh
+            own_mask = np.zeros(self.max_blocks, bool)
+            own_mask[:n] = True
+            pk = np.zeros((L, self.max_blocks, bs, n_kv, D), cache_dt)
+            pk[:, :n] = task.pages_k
+            pv = np.zeros((L, self.max_blocks, bs, n_kv, D), cache_dt)
+            pv[:, :n] = task.pages_v
+            # all-ones padding keeps null-block scales at their init value;
+            # the bf16 pytree carries no scale leaves and jit drops these
+            sk = np.ones((L, self.max_blocks, n_kv), np.float32)
+            sv = np.ones((L, self.max_blocks, n_kv), np.float32)
+            if task.scales_k is not None:
+                sk[:, :n] = task.scales_k
+                sv[:, :n] = task.scales_v
+            # lint: allow[lock-discipline] scheduler thread is the only _state writer; see _loop
+            self._state = _import_blocks(
+                self._state, jnp.asarray(table_row), jnp.asarray(own_mask),
+                jnp.asarray(pk), jnp.asarray(pv),
+                jnp.asarray(sk), jnp.asarray(sv),
+            )
+            with self._lock:
+                # the insert covers the WHOLE chain so far (shared base +
+                # this chunk); the trie takes its own reference per block
+                # and both our holds return here, leaving the chain at
+                # trie-only refcount — LRU-evictable like any parked prefix
+                created = self._radix.insert(task.tokens, shared + fresh)
+                self._pool.unref(shared + fresh)
+            self.imports_total += 1
+            self.imported_blocks_total += n
+            task.imported = n
+            self._note("import", blocks=n, created_nodes=created,
+                       start_block=start)
+            task.done.set()
 
     def scheduler_stats(self) -> dict:
         """Preemption/chunking accounting for /metrics: monotonic
@@ -1457,10 +1477,17 @@ class ContinuousEngine:
         parked-row depths. Lockless reads, same torn-read tolerance as
         stats_summary — a scrape must never stall behind an admit
         compile."""
+        dispatches, decode_steps = self.profiler.totals()
         return {
             "preempted": self.preempted_total,
             "resumed": self.resumed_total,
             "chunks": self.chunks_total,
+            # where the work happened (stepprof totals + the admit
+            # path's token accounting): dispatches by phase, model
+            # steps of decode/verify windows, prompt tokens by fate
+            "dispatches": dispatches,
+            "decode_steps": decode_steps,
+            "prefill_tokens": dict(self.prefill_tokens),
             "chunk_queue": len(self._prefills),
             "parked": len(self._parked),
             # fused decode dispatches (each covers 1..max_window steps)
@@ -1815,9 +1842,14 @@ class ContinuousEngine:
             # request's TTFT clock kept running while parked — it
             # already has tokens)
             req.t_admit = tracing.now()
+            req.wait_window_s, req.wait_backlog_s = self._split_wait(
+                req.t_submit, req.t_admit
+            )
             _TRACER.record_span(
                 "engine.queue_wait", start=req.t_submit, end=req.t_admit,
                 parent=req.trace_parent, slot=slot,
+                window_s=req.wait_window_s,
+                backlog_s=req.wait_backlog_s,
                 **self._span_ids(req),
             )
             if self._slo is not None:
@@ -1841,6 +1873,27 @@ class ContinuousEngine:
             self._prefills.append(task)
             return
         self._finalize_admit(task)
+
+    def _split_wait(self, t_submit: float,
+                    t_admit: float) -> tuple[float, float]:
+        """(window, backlog) seconds of one first admission's queue
+        wait. ``b`` is the first window boundary (the ``step_t`` of a
+        decode or verify window) at or after ``t_submit``: up to it the
+        request waited for the window in flight, which no admission
+        can interrupt; after it, for something else (slots full, pool
+        backpressure, another request's admit at the same boundary).
+        No boundary before ``t_admit`` (idle engine): all of it is
+        window. A wait longer than the ring remembers counts the
+        oldest boundary it still has, which only moves time from
+        backlog to window."""
+        b = None
+        for t in reversed(self._boundaries):
+            if t < t_submit:
+                break
+            b = t
+        if b is None or b >= t_admit:
+            return t_admit - t_submit, 0.0
+        return b - t_submit, t_admit - b
 
     def _next_chunk_len(self, task: _PrefillTask) -> int | None:
         """Chunk width for ``task``'s next dispatch, or None when the
@@ -1889,15 +1942,21 @@ class ContinuousEngine:
         t0 = tracing.now()
         # device work outside the lock (first chunk of a width pays its
         # compile; stop() must still be able to fail the slots)
-        # lint: allow[lock-discipline] scheduler thread is the only _state writer; see _loop
-        self._state = _prefill_chunk(
-            self.params, self._state, jnp.asarray(window),
-            jnp.int32(task.pos), self.cfg,
-            jnp.asarray(task.table_row), jnp.asarray(task.own_mask),
-            wq_gspmd=self._sharded,
-        )
+        with annotate("engine.chunk.dispatch", rid=task.req.rid,
+                      slot=task.slot, tokens=C, pos=task.pos):
+            # lint: allow[lock-discipline] scheduler thread is the only _state writer; see _loop
+            self._state = _prefill_chunk(
+                self.params, self._state, jnp.asarray(window),
+                jnp.int32(task.pos), self.cfg,
+                jnp.asarray(task.table_row), jnp.asarray(task.own_mask),
+                wq_gspmd=self._sharded,
+            )
         task.pos += C
         self.chunks_total += 1
+        self.prefill_tokens["computed"] += C
+        # the dispatch is asynchronous and nothing is read back here:
+        # t1 - t0 is the dispatch alone, and the chunk's device time
+        # shows in the profile (jit__prefill_chunk), not in this record
         t1 = tracing.now()
         with self._lock:
             live_rows = sum(1 for r in self._slot_req if r is not None)
@@ -1943,158 +2002,169 @@ class ContinuousEngine:
         suffix_len = p - start
         t0 = tracing.now()
         T = _bucket(suffix_len)  # _next_chunk_len kept start + T fitting
-        padded = np.zeros((1, T), np.int32)
-        padded[0, :suffix_len] = tokens[start:]
-        # full effective-prompt id set computed host-side: the jit only
-        # sees the suffix, but repetition penalty must cover reused and
-        # pre-preemption tokens too
-        seen_row = np.zeros((1, self.cfg.vocab_size), bool)
-        seen_row[0, np.asarray(tokens, np.int64)] = True
-        # explicit impl: stepper.sample_rows wraps with threefry2x32 and
-        # SlotState.rng is u32[B, 2]; deriving from the default-impl
-        # PRNGKey would break under jax_default_prng_impl=rbg (u32[4])
-        key_data = jax.random.key_data(
-            jax.random.key(req.seed, impl="threefry2x32")
-        ).astype(jnp.uint32)
-        self._state = _admit_slot(
-            self.params, self._state, jnp.asarray(padded),
-            jnp.int32(suffix_len), jnp.int32(start), jnp.int32(p),
-            self.cfg, jnp.int32(slot),
-            jnp.asarray(task.table_row), jnp.asarray(task.own_mask),
-            jnp.float32(req.temperature), jnp.int32(req.top_k),
-            jnp.float32(req.top_p), jnp.float32(req.rep_penalty), key_data,
-            jnp.asarray(seen_row), wq_gspmd=self._sharded,
-        )
-        if self.spec_draft is not None and task.spec_ok:
-            # draft-row prefill rides the same boundary: the draft has
-            # no radix reuse (and no chunking — it is small enough not
-            # to need either), so the FULL effective prompt recomputes
-            # in one dispatch, compiled per full-prompt bucket. The
-            # bucket fits by the same guards that admitted the target
-            # (submit's fits() for fresh prompts, _pick_victim's bucket
-            # check for readmits).
-            Td = _bucket(p)
-            dwin = np.zeros((1, Td), np.int32)
-            dwin[0, :p] = tokens
-            self._dstate = _admit_draft(
-                self._dparams, self._dstate, jnp.asarray(dwin),
-                jnp.int32(p), self._dcfg, jnp.int32(slot),
-            )
-            self._slot_spec_ok[slot] = True
-        # cache the effective prompt's FULL blocks for later admits —
-        # including this one's fresh blocks (their KV is committed by
-        # the scatter above; the partial tail block stays private)
-        full = p // self.block_size
-        if self.kv_dtype == "int8":
-            # every owned full block was quantize-committed by the
-            # scatter above (chunked prefills requantize the same
-            # blocks — one logical commit, counted once here)
-            self.quant_blocks_total += max(0, full - reuse)
-        if full:
-            self._radix.insert(
-                tokens, [int(b) for b in task.table_row[:full]]
-            )
-        if req.export_kv and full:
-            # disaggregated prefill export (disagg/): capture the
-            # committed full-block pages HERE — the scheduler thread is
-            # the only safe _state reader (jit donation deletes buffers
-            # under any racing HTTP-thread read), and right after the
-            # insert above the trie holds exactly these blocks. The
-            # fingerprints ride out of the trie walk
-            # (match_with_fingerprints) so the wire's content addresses
-            # are the very chain the router and importers recompute.
-            idx = jnp.asarray(
-                np.asarray(task.table_row[:full], np.int32)
-            )
-            pages_k = np.stack([
-                # lint: allow[host-sync] export capture: the prefilled pages must reach host memory before the request completes (one gather per layer, prefill-only requests never decode)
-                np.asarray(ck[idx]) for ck in self._state.caches_k
-            ])
-            pages_v = np.stack([
-                # lint: allow[host-sync] export capture (same boundary as pages_k above)
-                np.asarray(cv[idx]) for cv in self._state.caches_v
-            ])
-            pairs = self._radix.match_with_fingerprints(
-                tokens[:full * self.block_size]
-            )
-            # the walk refs its matches for us; the slot already holds
-            # these blocks, so the extra hold is returned immediately
-            self._pool.unref([b for b, _ in pairs])
-            req.kv_export = {
-                "pages_k": pages_k,
-                "pages_v": pages_v,
-                "fingerprints": [fp for _, fp in pairs],
-                "block_size": self.block_size,
-                "kv_dtype": self.kv_dtype,
-            }
+        with annotate("engine.admit", rid=req.rid, slot=slot, bucket=T,
+                      suffix_tokens=suffix_len,
+                      cached_tokens=reuse * self.block_size):
+            with annotate("engine.admit.host_prep"):
+                padded = np.zeros((1, T), np.int32)
+                padded[0, :suffix_len] = tokens[start:]
+                # full effective-prompt id set computed host-side: the jit
+                # only sees the suffix, but repetition penalty must cover
+                # reused and pre-preemption tokens too
+                seen_row = np.zeros((1, self.cfg.vocab_size), bool)
+                seen_row[0, np.asarray(tokens, np.int64)] = True
+                # explicit impl: stepper.sample_rows wraps with threefry2x32
+                # and SlotState.rng is u32[B, 2]; deriving from the
+                # default-impl PRNGKey would break under
+                # jax_default_prng_impl=rbg (u32[4])
+                key_data = jax.random.key_data(
+                    jax.random.key(req.seed, impl="threefry2x32")
+                ).astype(jnp.uint32)
+            with annotate("engine.admit.dispatch"):
+                self._state = _admit_slot(
+                    self.params, self._state, jnp.asarray(padded),
+                    jnp.int32(suffix_len), jnp.int32(start), jnp.int32(p),
+                    self.cfg, jnp.int32(slot),
+                    jnp.asarray(task.table_row), jnp.asarray(task.own_mask),
+                    jnp.float32(req.temperature), jnp.int32(req.top_k),
+                    jnp.float32(req.top_p), jnp.float32(req.rep_penalty),
+                    key_data, jnp.asarray(seen_row), wq_gspmd=self._sharded,
+                )
+            counted = self.prefill_tokens
+            counted["computed"] += suffix_len
+            counted["cached"] += reuse * self.block_size
+            counted["padded"] += T - suffix_len
+            if self.spec_draft is not None and task.spec_ok:
+                # draft-row prefill rides the same boundary: the draft has
+                # no radix reuse (and no chunking — it is small enough not
+                # to need either), so the FULL effective prompt recomputes
+                # in one dispatch, compiled per full-prompt bucket. The
+                # bucket fits by the same guards that admitted the target
+                # (submit's fits() for fresh prompts, _pick_victim's bucket
+                # check for readmits).
+                Td = _bucket(p)
+                dwin = np.zeros((1, Td), np.int32)
+                dwin[0, :p] = tokens
+                self._dstate = _admit_draft(
+                    self._dparams, self._dstate, jnp.asarray(dwin),
+                    jnp.int32(p), self._dcfg, jnp.int32(slot),
+                )
+                self._slot_spec_ok[slot] = True
+            # cache the effective prompt's FULL blocks for later admits —
+            # including this one's fresh blocks (their KV is committed by
+            # the scatter above; the partial tail block stays private)
+            full = p // self.block_size
             if self.kv_dtype == "int8":
-                # committed pages are int8 — the scales travel with
-                # them so the importer lands bit-identical blocks (the
-                # partial tail block is NOT in table_row[:full] and
-                # never leaves the engine in bf16)
-                req.kv_export["scales_k"] = np.stack([
-                    # lint: allow[host-sync] export capture (same boundary as pages_k above)
-                    np.asarray(sk[idx]) for sk in self._state.scales_k
+                # every owned full block was quantize-committed by the
+                # scatter above (chunked prefills requantize the same
+                # blocks — one logical commit, counted once here)
+                self.quant_blocks_total += max(0, full - reuse)
+            if full:
+                self._radix.insert(
+                    tokens, [int(b) for b in task.table_row[:full]]
+                )
+            if req.export_kv and full:
+                # disaggregated prefill export (disagg/): capture the
+                # committed full-block pages HERE — the scheduler thread is
+                # the only safe _state reader (jit donation deletes buffers
+                # under any racing HTTP-thread read), and right after the
+                # insert above the trie holds exactly these blocks. The
+                # fingerprints ride out of the trie walk
+                # (match_with_fingerprints) so the wire's content addresses
+                # are the very chain the router and importers recompute.
+                idx = jnp.asarray(
+                    np.asarray(task.table_row[:full], np.int32)
+                )
+                pages_k = np.stack([
+                    # lint: allow[host-sync] export capture: the prefilled pages must reach host memory before the request completes (one gather per layer, prefill-only requests never decode)
+                    np.asarray(ck[idx]) for ck in self._state.caches_k
                 ])
-                req.kv_export["scales_v"] = np.stack([
+                pages_v = np.stack([
                     # lint: allow[host-sync] export capture (same boundary as pages_k above)
-                    np.asarray(sv[idx]) for sv in self._state.scales_v
+                    np.asarray(cv[idx]) for cv in self._state.caches_v
                 ])
-        # the prefill already produced the next generated token —
-        # except in prefill-only mode (max_new == 0, the disagg export
-        # role), where the sampled token is discarded: the request's
-        # contract is "KV cached, nothing generated", and the decode
-        # replica resamples token #1 itself from the identical
-        # distribution (committed-blocks rule: it recomputes the last
-        # prompt position)
-        # lint: allow[host-sync] admission boundary: the first token must reach the request result now
-        first = int(self._state.last_token[slot])
-        now = tracing.now()
-        if req.max_new > 0:
-            req.out_tokens.append(first)
-            req.token_times.append(now)
-        # a preemption readmit keeps the stamp from its original admit,
-        # but a server-level resume (migration hand-off) never had one
-        # in THIS engine — without the stamp here the server's TTFT
-        # breakdown degrades to whole-request duration and the
-        # import-vs-reprefill comparison measures the decode tail
-        if not req.t_first:
-            req.t_first = now
-        # one profiler record per prefill dispatch, bracketing the
-        # _admit_slot call + its host sync above. The dispatch's one
-        # live token is the sampled token; the padding waste is the
-        # bucket tail (T - suffix_len) the static shapes force us to
-        # compute.
-        live_rows = sum(1 for r in self._slot_req if r is not None)
-        self.profiler.record(
-            "prefill", bucket=T, live_rows=live_rows,
-            live_tokens=suffix_len, padded_tokens=T - suffix_len,
-            start=t0, end=now,
-        )
-        if task.resumed:
-            self.resumed_total += 1
-            self._note("resume", req=req.rid, slot=slot, suffix_bucket=T,
-                       reuse_blocks=reuse, total_blocks=total,
-                       preemptions=req.preemptions)
-        else:
-            self._note("admit", req=req.rid, slot=slot, suffix_bucket=T,
-                       reuse_blocks=reuse, total_blocks=total)
-        # span start: a FRESH admission's prefill phase begins at
-        # t_admit — exactly where engine.queue_wait ends (the serving
-        # breakdown is contiguous by construction, and with chunking
-        # the intermediate chunk dispatches belong inside the prefill
-        # phase). A readmit never exited a queue, so its span brackets
-        # just the finalize dispatch.
-        sp = _TRACER.start_span(
-            "engine.prefill", parent=req.trace_parent,
-            start=t0 if task.resumed else req.t_admit,
-            slot=slot, prompt_tokens=p, bucket=T,
-            reused_tokens=reuse * self.block_size, prefix_hit=reuse > 0,
-            **self._span_ids(req),
-        )
-        sp.event("first-token", ts=now)
-        _TRACER.finish(sp, end=now)
-        self._maybe_retire(slot)
+                pairs = self._radix.match_with_fingerprints(
+                    tokens[:full * self.block_size]
+                )
+                # the walk refs its matches for us; the slot already holds
+                # these blocks, so the extra hold is returned immediately
+                self._pool.unref([b for b, _ in pairs])
+                req.kv_export = {
+                    "pages_k": pages_k,
+                    "pages_v": pages_v,
+                    "fingerprints": [fp for _, fp in pairs],
+                    "block_size": self.block_size,
+                    "kv_dtype": self.kv_dtype,
+                }
+                if self.kv_dtype == "int8":
+                    # committed pages are int8 — the scales travel with
+                    # them so the importer lands bit-identical blocks (the
+                    # partial tail block is NOT in table_row[:full] and
+                    # never leaves the engine in bf16)
+                    req.kv_export["scales_k"] = np.stack([
+                        # lint: allow[host-sync] export capture (same boundary as pages_k above)
+                        np.asarray(sk[idx]) for sk in self._state.scales_k
+                    ])
+                    req.kv_export["scales_v"] = np.stack([
+                        # lint: allow[host-sync] export capture (same boundary as pages_k above)
+                        np.asarray(sv[idx]) for sv in self._state.scales_v
+                    ])
+            # the prefill already produced the next generated token —
+            # except in prefill-only mode (max_new == 0, the disagg export
+            # role), where the sampled token is discarded: the request's
+            # contract is "KV cached, nothing generated", and the decode
+            # replica resamples token #1 itself from the identical
+            # distribution (committed-blocks rule: it recomputes the last
+            # prompt position)
+            with annotate("engine.admit.readback"):
+                # lint: allow[host-sync] admission boundary: the first token must reach the request result now
+                first = int(self._state.last_token[slot])
+            now = tracing.now()
+            if req.max_new > 0:
+                req.out_tokens.append(first)
+                req.token_times.append(now)
+            # a preemption readmit keeps the stamp from its original admit,
+            # but a server-level resume (migration hand-off) never had one
+            # in THIS engine — without the stamp here the server's TTFT
+            # breakdown degrades to whole-request duration and the
+            # import-vs-reprefill comparison measures the decode tail
+            if not req.t_first:
+                req.t_first = now
+            # one profiler record per prefill dispatch, bracketing the
+            # _admit_slot call + its host sync above. The dispatch's one
+            # live token is the sampled token; the padding waste is the
+            # bucket tail (T - suffix_len) the static shapes force us to
+            # compute.
+            live_rows = sum(1 for r in self._slot_req if r is not None)
+            self.profiler.record(
+                "prefill", bucket=T, live_rows=live_rows,
+                live_tokens=suffix_len, padded_tokens=T - suffix_len,
+                start=t0, end=now,
+            )
+            if task.resumed:
+                self.resumed_total += 1
+                self._note("resume", req=req.rid, slot=slot, suffix_bucket=T,
+                           reuse_blocks=reuse, total_blocks=total,
+                           preemptions=req.preemptions)
+            else:
+                self._note("admit", req=req.rid, slot=slot, suffix_bucket=T,
+                           reuse_blocks=reuse, total_blocks=total)
+            # span start: a FRESH admission's prefill phase begins at
+            # t_admit — exactly where engine.queue_wait ends (the serving
+            # breakdown is contiguous by construction, and with chunking
+            # the intermediate chunk dispatches belong inside the prefill
+            # phase). A readmit never exited a queue, so its span brackets
+            # just the finalize dispatch.
+            sp = _TRACER.start_span(
+                "engine.prefill", parent=req.trace_parent,
+                start=t0 if task.resumed else req.t_admit,
+                slot=slot, prompt_tokens=p, bucket=T,
+                reused_tokens=reuse * self.block_size, prefix_hit=reuse > 0,
+                **self._span_ids(req),
+            )
+            sp.event("first-token", ts=now)
+            _TRACER.finish(sp, end=now)
+            self._maybe_retire(slot)
 
     def _maybe_retire(self, slot: int) -> None:
         req = self._slot_req[slot]
@@ -2435,29 +2505,30 @@ class ContinuousEngine:
             free = any(r is None for r in self._slot_req)
         if waiter is None or free:
             return
-        now = tracing.now()
-        wait = now - waiter.pending_since
-        if wait < pol.threshold_s or \
-                self._steps_since_preempt < pol.cooldown_steps:
-            return
-        # feed the live head-wait in: a fully wedged engine admits
-        # nothing, so admit-time observations alone would never show
-        # the burn rising exactly when preemption is needed most
-        self._slo.observe("queue_wait", wait, t=now)
-        burn = max(self._slo.burn_rates(now=now)["queue_wait"].values())
-        if burn < pol.burn_limit:
-            return
-        with self._lock:
-            victim = self._pick_victim(pol)
-            if victim is None:
+        with annotate("engine.preempt_check"):
+            now = tracing.now()
+            wait = now - waiter.pending_since
+            if wait < pol.threshold_s or \
+                    self._steps_since_preempt < pol.cooldown_steps:
                 return
-            self._park_slot(victim)
-        self._steps_since_preempt = 0
-        # admit the waiter into the freed slot NOW — the parked victim
-        # re-enters the pending order behind it (pending_since just
-        # reset), so each preemption transfers the slot to strictly
-        # older work
-        self._admit_pending()
+            # feed the live head-wait in: a fully wedged engine admits
+            # nothing, so admit-time observations alone would never show
+            # the burn rising exactly when preemption is needed most
+            self._slo.observe("queue_wait", wait, t=now)
+            burn = max(self._slo.burn_rates(now=now)["queue_wait"].values())
+            if burn < pol.burn_limit:
+                return
+            with self._lock:
+                victim = self._pick_victim(pol)
+                if victim is None:
+                    return
+                self._park_slot(victim)
+            self._steps_since_preempt = 0
+            # admit the waiter into the freed slot NOW — the parked victim
+            # re-enters the pending order behind it (pending_since just
+            # reset), so each preemption transfers the slot to strictly
+            # older work
+            self._admit_pending()
 
     def _drain_spec_group(
         self, first: "_Request"
@@ -2694,35 +2765,38 @@ class ContinuousEngine:
         last decode window was in flight go first: their radix/alloc
         work is already done, and they were popped from the pending
         order ahead of whatever is still queued."""
-        with self._lock:
-            staged, self._staged = self._staged, []
-        for req, slot, kv_plan, tokens in staged:
+        with annotate("engine.admit_pending") as span:
             with self._lock:
-                if req.cancelled.is_set() or \
-                        self._slot_req[slot] is not None:
-                    # release the plan's block holds; a cancelled
-                    # request retires unserved, an occupied slot (only
-                    # reachable through a future scheduler change —
-                    # this thread is the sole admitter) sends the
-                    # request back to the head of the line
-                    table_row, _own, _reuse, total, _spec = kv_plan
-                    self._pool.unref(
-                        [int(b) for b in table_row[:total]]
-                    )
-                    if req.cancelled.is_set():
-                        req.t_done = tracing.now()
-                        req.done.set()
-                    else:
-                        self._holdover.appendleft(req)
-                    continue
-                # lint: allow[blocking-under-lock] same ceiling as _place: the admit-path jit compile (cold bucket ~tens of seconds) runs under _lock so stop() sees a consistent slot/pool state
-                self._admit(slot, req, kv_plan, tokens)
-        while True:
-            req = self._pop_pending()
-            if req is None:
-                return
-            if not self._place(req):
-                return
+                staged, self._staged = self._staged, []
+            placed = 0
+            for req, slot, kv_plan, tokens in staged:
+                with self._lock:
+                    if req.cancelled.is_set() or \
+                            self._slot_req[slot] is not None:
+                        # release the plan's block holds; a cancelled
+                        # request retires unserved, an occupied slot (only
+                        # reachable through a future scheduler change —
+                        # this thread is the sole admitter) sends the
+                        # request back to the head of the line
+                        table_row, _own, _reuse, total, _spec = kv_plan
+                        self._pool.unref(
+                            [int(b) for b in table_row[:total]]
+                        )
+                        if req.cancelled.is_set():
+                            req.t_done = tracing.now()
+                            req.done.set()
+                        else:
+                            self._holdover.appendleft(req)
+                        continue
+                    # lint: allow[blocking-under-lock] same ceiling as _place: the admit-path jit compile (cold bucket ~tens of seconds) runs under _lock so stop() sees a consistent slot/pool state
+                    self._admit(slot, req, kv_plan, tokens)
+                    placed += 1
+            while True:
+                req = self._pop_pending()
+                if req is None or not self._place(req):
+                    break
+                placed += 1
+            span.set_metadata(placed=placed)
 
     def _plan_admissions(self) -> None:
         """The host half of admission, overlapped with the in-flight
@@ -2736,48 +2810,52 @@ class ContinuousEngine:
         back for ``_place`` — forming a draft group dispatches device
         work immediately, which must not race the window's donated
         state."""
-        while True:
-            with self._lock:
-                taken = {s for _r, s, _p, _t in self._staged}
-                free = [
-                    s for s in range(self.n_slots)
-                    if self._slot_req[s] is None and s not in taken
-                ]
-            if not free:
-                return
-            req = self._pop_pending()
-            if req is None:
-                return
-            if req.cancelled.is_set():
-                req.t_done = tracing.now()
-                req.done.set()
-                continue
-            resumed = bool(req.out_tokens)
-            with self._lock:
-                group_free = self._spec_group is None
-            if (
-                self.speculative is not None
-                and self.spec_draft is None
-                and group_free
-                and not resumed
-                and req.rep_penalty == 1.0
-                and self.speculative.fits(len(req.prompt), req.max_new)
-            ):
+        with annotate("engine.plan_admissions") as span:
+            staged = 0
+            while True:
                 with self._lock:
-                    # head of the line again: _place routes it at the
-                    # boundary (it was the oldest pending request)
-                    self._holdover.appendleft(req)
-                return
-            with self._lock:
-                tokens = req.prompt + req.out_tokens
-                kv_plan = self._plan_kv(
-                    tokens, req.max_new - len(req.out_tokens),
-                    rid=req.rid,
-                )
-                if kv_plan is None:
-                    self._holdover.appendleft(req)
-                    return
-                self._staged.append((req, free[0], kv_plan, tokens))
+                    taken = {s for _r, s, _p, _t in self._staged}
+                    free = [
+                        s for s in range(self.n_slots)
+                        if self._slot_req[s] is None and s not in taken
+                    ]
+                if not free:
+                    break
+                req = self._pop_pending()
+                if req is None:
+                    break
+                if req.cancelled.is_set():
+                    req.t_done = tracing.now()
+                    req.done.set()
+                    continue
+                resumed = bool(req.out_tokens)
+                with self._lock:
+                    group_free = self._spec_group is None
+                if (
+                    self.speculative is not None
+                    and self.spec_draft is None
+                    and group_free
+                    and not resumed
+                    and req.rep_penalty == 1.0
+                    and self.speculative.fits(len(req.prompt), req.max_new)
+                ):
+                    with self._lock:
+                        # head of the line again: _place routes it at the
+                        # boundary (it was the oldest pending request)
+                        self._holdover.appendleft(req)
+                    break
+                with self._lock:
+                    tokens = req.prompt + req.out_tokens
+                    kv_plan = self._plan_kv(
+                        tokens, req.max_new - len(req.out_tokens),
+                        rid=req.rid,
+                    )
+                    if kv_plan is None:
+                        self._holdover.appendleft(req)
+                        break
+                    self._staged.append((req, free[0], kv_plan, tokens))
+                    staged += 1
+            span.set_metadata(staged=staged)
 
     def _pick_horizon(self, budgets: list[int], host_work: bool) -> int:
         """Decode-window horizon for this pass, from the static bucket
@@ -2803,263 +2881,290 @@ class ContinuousEngine:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
-            # staged KV imports first (at most one per pass): an import
-            # usually precedes the very request that wants its blocks,
-            # so servicing it ahead of admissions turns that request's
-            # admit into a warm one instead of a cold prefill
-            self._step_import()
-            with self._lock:
-                busy = any(r is not None for r in self._slot_req)
-                idle = (not busy and self._spec_group is None
-                        and not self._parked)
-                have_holdover = bool(self._holdover)
-            if idle:
-                if self._draining:
-                    # drain sweeps the queue itself (racing submits
-                    # land there past the lockless refusal) and flips
-                    # _drained once every population is empty
-                    self._step_drain()
-                    self._stop.wait(0.05)
-                    continue
-                # fully idle: block briefly for the next arrival
-                if not have_holdover:
-                    try:
-                        nxt = self._queue.get(timeout=0.05)
-                    except queue.Empty:
-                        continue
-                    with self._lock:
-                        self._holdover.append(nxt)
-                self._admit_pending()
-                continue
-            # live work: non-blocking admissions, a preemption check
-            # when the waiters' SLO pressure warrants one, then one
-            # step of each active machine — the decode batch, at most
-            # ONE prefill chunk, and a live draft group advance in
-            # lockstep per loop pass, so none starves the others. This
-            # interleave is the tentpole: prefill stopped being one
-            # atomic dispatch and became schedulable work competing
-            # with decode under an explicit policy.
+            with annotate("engine.pass") as span:
+                self._pass(span)
+        # epilogue: anything published after stop()'s sweep (admission
+        # was mid-compile during the snapshot) is released here — the
+        # last observer of the handoff fields cleans up
+        if self._stop.is_set():
+            self._fail_inflight()
+
+    def _pass(self, span) -> None:
+        """One scheduler pass, inside its ``engine.pass`` span (every
+        other ``engine.*`` span of this thread opens under it)."""
+        # staged KV imports first (at most one per pass): an import
+        # usually precedes the very request that wants its blocks,
+        # so servicing it ahead of admissions turns that request's
+        # admit into a warm one instead of a cold prefill
+        self._step_import()
+        with self._lock:
+            busy = any(r is not None for r in self._slot_req)
+            idle = (not busy and self._spec_group is None
+                    and not self._parked)
+            have_holdover = bool(self._holdover)
+        if idle:
             if self._draining:
-                # admission and preemption stand down; the drain pass
-                # streams one chunk (or finalizes one caught-up slot)
-                # and the decode window below keeps the batch emitting
-                # tokens between chunks
+                # drain sweeps the queue itself (racing submits
+                # land there past the lockless refusal) and flips
+                # _drained once every population is empty
+                with annotate("engine.drain"):
+                    self._step_drain()
+                with annotate("engine.idle_wait"):
+                    self._stop.wait(0.05)
+                return
+            # fully idle: block briefly for the next arrival
+            if not have_holdover:
+                try:
+                    with annotate("engine.idle_wait"):
+                        nxt = self._queue.get(timeout=0.05)
+                except queue.Empty:
+                    return
+                with self._lock:
+                    self._holdover.append(nxt)
+            self._admit_pending()
+            return
+        # live work: non-blocking admissions, a preemption check
+        # when the waiters' SLO pressure warrants one, then one
+        # step of each active machine — the decode batch, at most
+        # ONE prefill chunk, and a live draft group advance in
+        # lockstep per loop pass, so none starves the others. This
+        # interleave is the tentpole: prefill stopped being one
+        # atomic dispatch and became schedulable work competing
+        # with decode under an explicit policy.
+        if self._draining:
+            # admission and preemption stand down; the drain pass
+            # streams one chunk (or finalizes one caught-up slot)
+            # and the decode window below keeps the batch emitting
+            # tokens between chunks
+            with annotate("engine.drain"):
                 self._step_drain()
-            else:
-                self._admit_pending()
-                self._maybe_preempt()
-            with self._lock:
-                # mid-prefill rows are reserved but not yet decoding
-                # (active=False, null tables); they are padding in the
-                # decode dispatch, not live rows
-                prefilling = {t.slot for t in self._prefills}
-                budgets = [
-                    r.max_new - len(r.out_tokens)
-                    for s, r in enumerate(self._slot_req)
-                    if r is not None and s not in prefilling
-                ]
-                decode_rows = len(budgets)
-                # verify windows are all-or-nothing: a live row whose
-                # admit fell back to the plain block budget (_plan_kv
-                # spec_ok=False) has no +spec_k slack, and the fused
-                # dispatch cannot exclude single rows — so any such row
-                # drops the whole batch to plain decode until it
-                # retires or parks
-                spec_ready = self.spec_draft is not None and bool(
-                    budgets
-                ) and all(
-                    self._slot_spec_ok[s]
-                    for s, r in enumerate(self._slot_req)
-                    if r is not None and s not in prefilling
+        else:
+            self._admit_pending()
+            self._maybe_preempt()
+        with self._lock:
+            # mid-prefill rows are reserved but not yet decoding
+            # (active=False, null tables); they are padding in the
+            # decode dispatch, not live rows
+            prefilling = {t.slot for t in self._prefills}
+            budgets = [
+                r.max_new - len(r.out_tokens)
+                for s, r in enumerate(self._slot_req)
+                if r is not None and s not in prefilling
+            ]
+            decode_rows = len(budgets)
+            # verify windows are all-or-nothing: a live row whose
+            # admit fell back to the plain block budget (_plan_kv
+            # spec_ok=False) has no +spec_k slack, and the fused
+            # dispatch cannot exclude single rows — so any such row
+            # drops the whole batch to plain decode until it
+            # retires or parks
+            spec_ready = self.spec_draft is not None and bool(
+                budgets
+            ) and all(
+                self._slot_spec_ok[s]
+                for s, r in enumerate(self._slot_req)
+                if r is not None and s not in prefilling
+            )
+            waiting = len(self._holdover)
+            host_work = (
+                bool(self._holdover) or bool(self._parked)
+                or bool(self._prefills)
+                or self._spec_group is not None
+                or any(
+                    r is not None and r.cancelled.is_set()
+                    for r in self._slot_req
                 )
-                host_work = (
-                    bool(self._holdover) or bool(self._parked)
-                    or bool(self._prefills)
-                    or self._spec_group is not None
-                    or any(
-                        r is not None and r.cancelled.is_set()
-                        for r in self._slot_req
-                    )
-                )
-            # arrival-queue peek outside the lock (qsize takes the
-            # queue's own lock); a racing submit only costs one pass
-            # of K=1 or one window of delayed admission — never
-            # correctness
-            host_work = host_work or not self._queue.empty()
-            # draining forces K=1: short windows keep the chunk stream
-            # close behind the decode head, so the park-and-move tail
-            # (and the drain itself) lands sooner
-            host_work = host_work or self._draining
-            if decode_rows and spec_ready:
-                # the speculative twin of the fused branch below: one
-                # verify dispatch advances every row by 1..spec_k+1
-                # tokens (data-dependent, unlike the fixed-K window),
-                # and the boundary drain is where accept/rollback meets
-                # the scheduler — truncation below always coincides
-                # with retirement, so discarded device progress never
-                # leaks into a continuing row
-                step_t0 = tracing.now()
+            )
+        # arrival-queue peek outside the lock (qsize takes the
+        # queue's own lock); a racing submit only costs one pass
+        # of K=1 or one window of delayed admission — never
+        # correctness
+        host_work = host_work or not self._queue.empty()
+        # draining forces K=1: short windows keep the chunk stream
+        # close behind the decode head, so the park-and-move tail
+        # (and the drain itself) lands sooner
+        host_work = host_work or self._draining
+        if profiling():
+            # qsize takes the queue's lock: only for a recorded span
+            span.set_metadata(
+                decode_rows=decode_rows,
+                queue_depth=self._queue.qsize() + waiting,
+            )
+        if decode_rows and spec_ready:
+            # the speculative twin of the fused branch below: one
+            # verify dispatch advances every row by 1..spec_k+1
+            # tokens (data-dependent, unlike the fixed-K window),
+            # and the boundary drain is where accept/rollback meets
+            # the scheduler — truncation below always coincides
+            # with retirement, so discarded device progress never
+            # leaks into a continuing row
+            step_t0 = tracing.now()
+            with annotate("engine.verify.dispatch", k=self.spec_k,
+                          rows=decode_rows):
                 # lint: allow[lock-discipline] scheduler thread is the only _state writer; see comment above
                 self._state, self._dstate, tokens = verify_window(
                     self.params, self._state, self._dparams,
                     self._dstate, self.cfg, self._dcfg, self.spec_k,
                     sharded=self._sharded,
                 )
-                if not self._draining:
-                    # a drain must not stage new plans (their block
-                    # holds would just be unwound by the next sweep)
-                    self._plan_admissions()
+            if not self._draining:
+                # a drain must not stage new plans (their block
+                # holds would just be unwound by the next sweep)
+                self._plan_admissions()
+            with annotate("engine.verify.readback"):
                 # lint: allow[host-sync] window boundary: the [n_slots, spec_k+1] token matrix feeds the Python result queues
                 toks = np.asarray(tokens)
-                step_t = tracing.now()
-                self.windows_total += 1
-                self._steps_since_preempt += self.spec_k
-                accepted = 0
-                with self._lock:
-                    for slot in range(self.n_slots):
-                        req = self._slot_req[slot]
-                        row = toks[slot]
-                        n_dev = int((row >= 0).sum())
-                        if req is None or n_dev == 0:
-                            continue
-                        self.spec_draft_tokens += self.spec_k
-                        if self.kv_dtype == "int8":
-                            # offset invariant: p + emitted - 1 (the
-                            # newest token's KV is uncommitted); the
-                            # device advanced this row n_dev positions
-                            # and quantize-committed one block per
-                            # boundary crossing
-                            old = len(req.prompt) \
-                                + len(req.out_tokens) - 1
-                            self.quant_blocks_total += (
-                                (old + n_dev) // self.block_size
-                                - old // self.block_size
-                            )
-                        # device acceptance may overshoot the request
-                        # budget or run past EOS (the window cannot
-                        # stop mid-dispatch); the host emits the
-                        # truncated prefix and every truncation lands
-                        # on a retirement below, so the row's advanced
-                        # device state is discarded, never resumed —
-                        # that is what keeps truncation identity-safe
-                        n_host = min(n_dev, req.max_new
-                                     - len(req.out_tokens))
-                        if req.eos_id >= 0:
-                            for i in range(n_host):
-                                if int(row[i]) == req.eos_id:
-                                    n_host = i + 1
-                                    break
-                        for j in range(n_host):
-                            t_j = step_t0 + (j + 1) * (
-                                step_t - step_t0) / n_host
-                            req.out_tokens.append(int(row[j]))
-                            req.token_times.append(t_j)
-                        if n_host > 1:
-                            req.interpolated = True
-                        accepted += n_host
-                        # n_dev = accepted drafts + the bonus token
-                        # the verify forward samples past the last
-                        # accepted draft, so drafts-accepted is n_dev-1
-                        acc_d = n_dev - 1
-                        self.spec_accepted_tokens += acc_d
-                        req.spec_accepted += acc_d
-                        if acc_d < self.spec_k:
-                            self.spec_rollbacks += 1
-                            req.spec_rollbacks += 1
-                        self._maybe_retire(slot)
-                # ONE record per verify dispatch, phase "verify" so the
-                # decode-dispatches-per-token summary and the compile
-                # proxy (first-seen phase/bucket) stay honest about
-                # which compiled shape ran; bucket is spec_k (one
-                # compiled verify shape per K)
-                self.profiler.record(
-                    "verify", bucket=self.spec_k,
-                    live_rows=decode_rows, live_tokens=accepted,
-                    padded_tokens=(
-                        self.n_slots * (self.spec_k + 1) - accepted
-                    ),
-                    start=step_t0, end=step_t, steps=self.spec_k,
-                )
-            elif decode_rows:
-                k = self._pick_horizon(budgets, host_work)
-                # device window outside the lock (it can block on a
-                # compile; stop() must still be able to fail the slots)
-                step_t0 = tracing.now()
+            step_t = tracing.now()
+            self._boundaries.append(step_t)
+            self.windows_total += 1
+            self._steps_since_preempt += self.spec_k
+            accepted = 0
+            with annotate("engine.verify.emit") as emit, self._lock:
+                for slot in range(self.n_slots):
+                    req = self._slot_req[slot]
+                    row = toks[slot]
+                    n_dev = int((row >= 0).sum())
+                    if req is None or n_dev == 0:
+                        continue
+                    self.spec_draft_tokens += self.spec_k
+                    if self.kv_dtype == "int8":
+                        # offset invariant: p + emitted - 1 (the
+                        # newest token's KV is uncommitted); the
+                        # device advanced this row n_dev positions
+                        # and quantize-committed one block per
+                        # boundary crossing
+                        old = len(req.prompt) \
+                            + len(req.out_tokens) - 1
+                        self.quant_blocks_total += (
+                            (old + n_dev) // self.block_size
+                            - old // self.block_size
+                        )
+                    # device acceptance may overshoot the request
+                    # budget or run past EOS (the window cannot
+                    # stop mid-dispatch); the host emits the
+                    # truncated prefix and every truncation lands
+                    # on a retirement below, so the row's advanced
+                    # device state is discarded, never resumed —
+                    # that is what keeps truncation identity-safe
+                    n_host = min(n_dev, req.max_new
+                                 - len(req.out_tokens))
+                    if req.eos_id >= 0:
+                        for i in range(n_host):
+                            if int(row[i]) == req.eos_id:
+                                n_host = i + 1
+                                break
+                    for j in range(n_host):
+                        t_j = step_t0 + (j + 1) * (
+                            step_t - step_t0) / n_host
+                        req.out_tokens.append(int(row[j]))
+                        req.token_times.append(t_j)
+                    if n_host > 1:
+                        req.interpolated = True
+                    accepted += n_host
+                    # n_dev = accepted drafts + the bonus token
+                    # the verify forward samples past the last
+                    # accepted draft, so drafts-accepted is n_dev-1
+                    acc_d = n_dev - 1
+                    self.spec_accepted_tokens += acc_d
+                    req.spec_accepted += acc_d
+                    if acc_d < self.spec_k:
+                        self.spec_rollbacks += 1
+                        req.spec_rollbacks += 1
+                    self._maybe_retire(slot)
+                emit.set_metadata(tokens=accepted)
+            # ONE record per verify dispatch, phase "verify" so the
+            # decode-dispatches-per-token summary and the compile
+            # proxy (first-seen phase/bucket) stay honest about
+            # which compiled shape ran; bucket is spec_k (one
+            # compiled verify shape per K)
+            self.profiler.record(
+                "verify", bucket=self.spec_k,
+                live_rows=decode_rows, live_tokens=accepted,
+                padded_tokens=(
+                    self.n_slots * (self.spec_k + 1) - accepted
+                ),
+                start=step_t0, end=step_t, steps=self.spec_k,
+            )
+        elif decode_rows:
+            k = self._pick_horizon(budgets, host_work)
+            # device window outside the lock (it can block on a
+            # compile; stop() must still be able to fail the slots)
+            step_t0 = tracing.now()
+            with annotate("engine.decode.dispatch", k=k,
+                          rows=decode_rows):
                 # lint: allow[lock-discipline] scheduler thread is the only _state writer; see comment above
                 self._state, tokens = decode_window(
                     self.params, self._state, self.cfg, k,
                     sharded=self._sharded,
                 )
-                # the dispatch returns a future immediately (JAX async
-                # dispatch): the admission planning below is the host
-                # work overlapped with the device window, and the
-                # readback after it is the one synchronization point
-                if not self._draining:
-                    # same stand-down as the verify branch: no new
-                    # plans while draining
-                    self._plan_admissions()
+            # the dispatch returns a future immediately (JAX async
+            # dispatch): the admission planning below is the host
+            # work overlapped with the device window, and the
+            # readback after it is the one synchronization point
+            if not self._draining:
+                # same stand-down as the verify branch: no new
+                # plans while draining
+                self._plan_admissions()
+            with annotate("engine.decode.readback"):
                 # lint: allow[host-sync] window boundary: the [n_slots, k] token matrix feeds the Python result queues
                 toks = np.asarray(tokens)
-                # one clock read per WINDOW, outside the lock: token
-                # times inside the bracket are interpolated below
-                # (docs/OBSERVABILITY.md — traces carry
-                # kubeinfer.interpolated so nobody reads them as
-                # per-step measurements)
-                step_t = tracing.now()
-                self.windows_total += 1
-                self._steps_since_preempt += k
-                accepted = 0
-                with self._lock:
-                    if self.kv_dtype == "int8":
-                        # every decoding row advanced k positions on
-                        # the device (retirement is host work below);
-                        # one tail block quantize-commits per boundary
-                        # crossing. Offset invariant: p + emitted - 1.
-                        for s, r in enumerate(self._slot_req):
-                            if r is None or s in prefilling:
-                                continue
-                            old = len(r.prompt) + len(r.out_tokens) - 1
-                            self.quant_blocks_total += (
-                                (old + k) // self.block_size
-                                - old // self.block_size
-                            )
-                    for j in range(k):
-                        t_j = step_t0 + (j + 1) * (step_t - step_t0) / k
-                        for slot in range(self.n_slots):
-                            # host-side EOS masking: _maybe_retire
-                            # clears _slot_req at the EOS/budget token,
-                            # so a retired row's tail tokens in the
-                            # same window fall through the req-is-None
-                            # check — the device kept scattering junk
-                            # into the row's own refcounted blocks,
-                            # which nobody reads (same null-block
-                            # discipline as retirement, and always
-                            # inside the row's allocated span by the
-                            # horizon clamp)
-                            req = self._slot_req[slot]
-                            if req is None or toks[slot, j] < 0:
-                                continue
-                            req.out_tokens.append(int(toks[slot, j]))
-                            req.token_times.append(t_j)
-                            if k > 1:
-                                req.interpolated = True
-                            accepted += 1
-                            self._maybe_retire(slot)
-                # ONE record per fused dispatch: bucket=k is the
-                # compiled-shape knob (first-seen per window bucket ==
-                # one compile each), live_tokens counts only tokens
-                # that reached a request — inactive rows and masked
-                # post-EOS tails are padding of the n_slots x k window
-                self.profiler.record(
-                    "decode", bucket=k, live_rows=decode_rows,
-                    live_tokens=accepted,
-                    padded_tokens=self.n_slots * k - accepted,
-                    start=step_t0, end=step_t, steps=k,
-                )
-            self._step_prefill()  # at most one chunk per pass
-            self._step_spec_group()  # locked no-op when no group is live
-        # epilogue: anything published after stop()'s sweep (admission
-        # was mid-compile during the snapshot) is released here — the
-        # last observer of the handoff fields cleans up
-        if self._stop.is_set():
-            self._fail_inflight()
+            # one clock read per WINDOW, outside the lock: token
+            # times inside the bracket are interpolated below
+            # (docs/OBSERVABILITY.md — traces carry
+            # kubeinfer.interpolated so nobody reads them as
+            # per-step measurements)
+            step_t = tracing.now()
+            self._boundaries.append(step_t)
+            self.windows_total += 1
+            self._steps_since_preempt += k
+            accepted = 0
+            with annotate("engine.decode.emit") as emit, self._lock:
+                if self.kv_dtype == "int8":
+                    # every decoding row advanced k positions on
+                    # the device (retirement is host work below);
+                    # one tail block quantize-commits per boundary
+                    # crossing. Offset invariant: p + emitted - 1.
+                    for s, r in enumerate(self._slot_req):
+                        if r is None or s in prefilling:
+                            continue
+                        old = len(r.prompt) + len(r.out_tokens) - 1
+                        self.quant_blocks_total += (
+                            (old + k) // self.block_size
+                            - old // self.block_size
+                        )
+                for j in range(k):
+                    t_j = step_t0 + (j + 1) * (step_t - step_t0) / k
+                    for slot in range(self.n_slots):
+                        # host-side EOS masking: _maybe_retire
+                        # clears _slot_req at the EOS/budget token,
+                        # so a retired row's tail tokens in the
+                        # same window fall through the req-is-None
+                        # check — the device kept scattering junk
+                        # into the row's own refcounted blocks,
+                        # which nobody reads (same null-block
+                        # discipline as retirement, and always
+                        # inside the row's allocated span by the
+                        # horizon clamp)
+                        req = self._slot_req[slot]
+                        if req is None or toks[slot, j] < 0:
+                            continue
+                        req.out_tokens.append(int(toks[slot, j]))
+                        req.token_times.append(t_j)
+                        if k > 1:
+                            req.interpolated = True
+                        accepted += 1
+                        self._maybe_retire(slot)
+                emit.set_metadata(tokens=accepted)
+            # ONE record per fused dispatch: bucket=k is the
+            # compiled-shape knob (first-seen per window bucket ==
+            # one compile each), live_tokens counts only tokens
+            # that reached a request — inactive rows and masked
+            # post-EOS tails are padding of the n_slots x k window
+            self.profiler.record(
+                "decode", bucket=k, live_rows=decode_rows,
+                live_tokens=accepted,
+                padded_tokens=self.n_slots * k - accepted,
+                start=step_t0, end=step_t, steps=k,
+            )
+        self._step_prefill()  # at most one chunk per pass
+        self._step_spec_group()  # locked no-op when no group is live

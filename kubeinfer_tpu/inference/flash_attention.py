@@ -355,6 +355,7 @@ def _run_flash(
             pltpu.VMEM((tile_t * G, D), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(*extra_arrays, qf, kf, vf)
     out = out.reshape(B, n_kv, T, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, n_heads, D)
@@ -557,6 +558,7 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B * n_kv, G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lens, qf, kf, vf)
     return out.reshape(B, 1, n_heads, D)
 
@@ -833,6 +835,7 @@ def decode_attention_blocks(
         ),
         out_shape=jax.ShapeDtypeStruct((B * n_kv, T * G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention_blocks",
     )(tbl, lens, qf, kp, vp)
     return out.reshape(B, n_kv, T, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, n_heads, D
@@ -1163,6 +1166,7 @@ def decode_attention_blocks_q8(
         ),
         out_shape=jax.ShapeDtypeStruct((B * n_kv, T * G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention_blocks_q8",
     )(tbl, lens, tb, ksb, vsb, qf, kp, vp, kt, vt)
     return out.reshape(B, n_kv, T, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, n_heads, D

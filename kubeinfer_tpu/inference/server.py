@@ -50,6 +50,7 @@ from kubeinfer_tpu.metrics.registry import (
 )
 from kubeinfer_tpu.observability import tracing
 from kubeinfer_tpu.observability.slo import SLOMonitor
+from kubeinfer_tpu.observability.stepprof import PHASES
 from kubeinfer_tpu.utils.httpbase import BaseEndpointHandler, token_matches
 
 log = logging.getLogger(__name__)
@@ -117,11 +118,6 @@ def _serving_metrics(registry: Registry):
         "spec_served": Gauge(
             "kubeinfer_inference_spec_served_requests",
             "Requests served via speculative draft groups",
-            registry=registry,
-        ),
-        "spec_accepted": Gauge(
-            "kubeinfer_inference_spec_accepted_drafts",
-            "Draft tokens accepted by the target across all groups",
             registry=registry,
         ),
         # paged speculative decoding (batching.py verify windows, gated
@@ -232,10 +228,41 @@ def _serving_metrics(registry: Registry):
         ),
         "step_duration": Histogram(
             "kubeinfer_engine_step_duration_seconds",
-            "Device dispatch wall time by phase (prefill/decode/spec)",
+            "Device dispatch wall time by phase (prefill/decode/verify/"
+            "spec/chunk; a chunk's is the dispatch alone)",
             buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
-                     0.1, 0.25, 0.5, 1.0, 5.0, 30.0),
+                     0.1, 0.15, 0.2, 0.25, 0.5, 1.0, 5.0, 30.0),
             labels=("phase",), registry=registry,
+        ),
+        # counters where the work happens (stepprof.StepProfiler totals
+        # and the admit path's token accounting): monotonic engine ints
+        # converted by delta at scrape time, like the radix counters
+        "dispatches": Counter(
+            "kubeinfer_engine_dispatches_total",
+            "Device dispatches by phase",
+            labels=("phase",), registry=registry,
+        ),
+        "decode_steps": Counter(
+            "kubeinfer_engine_decode_steps_total",
+            "Model steps run by decode and verify windows (sum of K)",
+            registry=registry,
+        ),
+        "prefill_tokens": Counter(
+            "kubeinfer_engine_prefill_tokens_total",
+            "Prompt tokens of admitted requests: computed (run through "
+            "the prefill programs), cached (taken from the radix "
+            "cache), padded (bucket padding computed for nothing)",
+            labels=("kind",), registry=registry,
+        ),
+        "admission_wait": Histogram(
+            "kubeinfer_engine_admission_wait_seconds",
+            "A first admission's queue wait split at the first decode "
+            "window boundary after submit: window (the window in "
+            "flight) and backlog (slots, pool, another admit); the two "
+            "sum to kubeinfer_inference_queue_wait_seconds",
+            buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0,
+                     30.0),
+            labels=("stage",), registry=registry,
         ),
         "compiles": Counter(
             "kubeinfer_engine_compiles_total",
@@ -755,7 +782,6 @@ class InferenceServer:
         if self.continuous is None:
             return
         self.metrics["spec_served"].set(self.continuous.spec_served)
-        self.metrics["spec_accepted"].set(self.continuous.spec_accepted)
         stats = self.continuous.kv_cache_stats()
         self.metrics["kv_blocks_in_use"].set(stats["blocks_in_use"])
         self.metrics["kv_blocks_free"].set(stats["blocks_free"])
@@ -812,10 +838,29 @@ class InferenceServer:
                 ("spec_rollbacks", "spec_rollbacks"),
                 ("migrated", "migrations"),
                 ("migration_chunks", "migration_chunks"),
+                ("decode_steps", "decode_steps"),
             ):
                 delta = sched[key] - self._kv_last.get(key, 0)
                 self.metrics[name].inc(by=delta)
                 self._kv_last[key] = sched[key]
+            for name, totals, labels in (
+                ("dispatches", sched["dispatches"], PHASES),
+                ("prefill_tokens", sched["prefill_tokens"],
+                 ("computed", "cached", "padded")),
+            ):
+                for label in labels:
+                    key = f"{name}.{label}"
+                    total = totals.get(label, 0)
+                    self.metrics[name].inc(
+                        label, by=total - self._kv_last.get(key, 0)
+                    )
+                    self._kv_last[key] = total
+            # a reader of deltas sums phases (decode + verify windows
+            # per decode_steps_total): each needs its series at 0
+            for phase in PHASES:
+                self.metrics["step_duration"].ensure(phase)
+            for stage in ("window", "backlog"):
+                self.metrics["admission_wait"].ensure(stage)
             if self.kv_exports is not None:
                 # export-cache evictions ride the same delta-to-Counter
                 # conversion (the cache's int is monotonic per process)
@@ -937,6 +982,12 @@ class InferenceServer:
                 wait = max(0.0, req.t_admit - req.t_submit)
                 self.metrics["queue_wait"].observe(route, wait)
                 self.slo.observe("queue_wait", wait)
+                # the same wait, by what was waited for; observed here
+                # so both halves cover exactly queue_wait's population
+                self.metrics["admission_wait"].observe(
+                    "window", req.wait_window_s)
+                self.metrics["admission_wait"].observe(
+                    "backlog", req.wait_backlog_s)
             end = req.t_done or req.t_submit + total_s
             if req.t_first:
                 ttft = max(0.0, req.t_first - req.t_submit)

@@ -246,6 +246,7 @@ def quant_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((mt * block_m, nt * block_n), x.dtype),
         interpret=interpret,
+        name="quant_matmul",
     )(xp, qp, sp)
     return out[:M, :N]
 

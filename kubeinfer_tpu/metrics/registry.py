@@ -164,6 +164,16 @@ class Histogram(_Collector):
             self._sums[key] = self._sums.get(key, 0.0) + value
             self._totals[key] = self._totals.get(key, 0) + 1
 
+    def ensure(self, *label_values: str) -> None:
+        """Materialize the series at zero (the histogram twin of
+        ``Counter.inc(by=0)``): it then exists from the first scrape,
+        so a reader of deltas can tell 0 from absent."""
+        key = self._check(label_values)
+        with self._lock:
+            self._counts.setdefault(key, [0] * len(self.buckets))
+            self._sums.setdefault(key, 0.0)
+            self._totals.setdefault(key, 0)
+
     def count(self, *label_values: str) -> int:
         with self._lock:
             return self._totals.get(self._check(label_values), 0)

@@ -25,6 +25,11 @@ Design constraints, matching tracing.py:
 Readers (the /metrics scrape, ``stats_summary()``, bench) pull
 snapshots; the monotonic ``seq`` lets a scraper replay only the records
 it has not yet folded into its histograms.
+
+The same sites that call ``record()`` also write into the device
+profile: :func:`annotate` opens a host span on the profiler's own
+clock, and the names below are what a reader of that profile matches
+(docs/OBSERVABILITY.md, "Device profile").
 """
 
 from __future__ import annotations
@@ -32,12 +37,92 @@ from __future__ import annotations
 import collections
 from dataclasses import dataclass
 
+from jax.profiler import TraceAnnotation
+
 from kubeinfer_tpu.analysis.racecheck import make_lock
 from kubeinfer_tpu.observability import tracing
 
-__all__ = ["StepRecord", "StepProfiler"]
+__all__ = [
+    "StepRecord", "StepProfiler", "annotate", "profiling", "PHASES",
+    "STEP_PROGRAMS", "KERNEL_NAMES", "HOST_SPANS", "PROFILE_NAMES",
+]
 
-PHASES = ("prefill", "decode", "spec", "chunk")
+# every ``phase`` a record can carry (the batcher records paged verify
+# windows as "verify", the dense draft-group side-car as "spec")
+PHASES = ("prefill", "decode", "verify", "spec", "chunk")
+
+# --- the names the device profile carries ----------------------------------
+# The contract the benchmark's patterns are written against: renaming
+# one of these is a change to every reader that matches it
+# (benchmarks/layer_metrics/*.json, benchmarks/lib/hostspans.py).
+# tests/test_observability_profile.py and tests/test_chip_compile.py
+# hold the programs to this list. A jax.named_scope is not on it: a TPU
+# trace carries a scope's name in no field jax.profiler.ProfileData
+# reads (PERF.md section 6, PR 27), so the step programs open none.
+
+# the three step programs, as the profile's "XLA Modules" line prints
+# them (jit_<function name>)
+STEP_PROGRAMS = ("jit__admit_slot", "jit__prefill_chunk",
+                 "jit_decode_window")
+
+# ``name=`` of every pl.pallas_call the server can reach, each the name
+# of its public function; it heads the event's name on "XLA Ops"
+KERNEL_NAMES = (
+    "quant_matmul", "decode_attention", "decode_attention_blocks",
+    "decode_attention_blocks_q8", "flash_attention",
+    "flash_attention_ragged",
+)
+
+# host spans (:func:`annotate`), all on the scheduler thread and inside
+# one engine.pass
+HOST_SPANS = (
+    "engine.pass", "engine.idle_wait", "engine.import", "engine.drain",
+    "engine.preempt_check", "engine.admit_pending",
+    "engine.plan_admissions", "engine.admit", "engine.admit.host_prep",
+    "engine.admit.dispatch", "engine.admit.readback",
+    "engine.chunk.dispatch", "engine.decode.dispatch",
+    "engine.decode.readback", "engine.decode.emit",
+    "engine.verify.dispatch", "engine.verify.readback",
+    "engine.verify.emit",
+)
+
+PROFILE_NAMES = STEP_PROGRAMS + KERNEL_NAMES + HOST_SPANS
+
+# is a profiler session recording host spans right now? Sites whose
+# span arguments cost a lock or a loop ask before computing them.
+profiling = TraceAnnotation.is_enabled
+
+
+class _NoSpan:
+    """What :func:`annotate` hands out while no session records: the
+    context manager and ``set_metadata`` of a TraceAnnotation, doing
+    nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **args) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+def annotate(name: str, **args) -> "TraceAnnotation | _NoSpan":
+    """A host span on the profiler's clock, next to the device's lines
+    in the same ``.xplane.pb``. ``args`` become the event's stats (the
+    numbers the same site hands to :meth:`StepProfiler.record`), and
+    ``set_metadata(**more)`` adds what is only known before the exit.
+    With no profiler session running nothing is built: every caller
+    gets the one shared :class:`_NoSpan`."""
+    if not TraceAnnotation.is_enabled():
+        return _NO_SPAN
+    return TraceAnnotation(name, **args)
 
 
 @dataclass(frozen=True)
@@ -46,13 +131,20 @@ class StepRecord:
 
     seq: int  # monotonic dispatch index (scrape cursors key on it)
     t: float  # dispatch end, tracing-clock seconds
-    phase: str  # "prefill" | "decode" | "spec" | "chunk"
+    phase: str  # "prefill" | "decode" | "verify" | "spec" | "chunk"
     bucket: int  # compiled-shape knob: suffix bucket / batch width
     live_rows: int  # rows carrying a real request
     n_slots: int  # batch capacity the dispatch was padded to
     live_tokens: int  # tokens that reached a request this step
     padded_tokens: int  # tokens computed for padding only
-    dur_s: float  # step wall time (end - start)
+    # step wall time (end - start) on the HOST clock. prefill, decode
+    # and verify records end after their tokens were read back, so they
+    # cover the device's work; a "chunk" record ends when the
+    # asynchronous dispatch returns, so it is the dispatch alone and
+    # the chunk's device time lands in the next record that reads
+    # back. Device time per program is read from the profile
+    # (STEP_PROGRAMS on the "XLA Modules" line), never from here.
+    dur_s: float
     compiled: bool  # first dispatch of (phase, bucket) on this profiler
     kv_in_use: int  # sampled pool blocks referenced (-1 = not sampled)
     kv_free: int  # sampled pool free-list size (-1 = not sampled)
@@ -104,6 +196,11 @@ class StepProfiler:
         self._seen_shapes: set[tuple[str, int]] = set()
         self._compile_count = 0
         self._last_kv = (-1, -1)
+        # monotonic totals, never lost to the ring's wrap: dispatches by
+        # phase, and the model steps decode/verify windows ran (sum of
+        # their ``steps``)
+        self._dispatches: dict[str, int] = {}
+        self._decode_steps = 0
 
     # -- writer (scheduler thread) -----------------------------------------
 
@@ -136,6 +233,9 @@ class StepProfiler:
             )
             self._seq += 1
             self._ring.append(rec)
+            self._dispatches[phase] = self._dispatches.get(phase, 0) + 1
+            if phase in ("decode", "verify"):
+                self._decode_steps += steps
         return rec
 
     # -- readers (any thread) ----------------------------------------------
@@ -144,6 +244,12 @@ class StepProfiler:
     def compile_count(self) -> int:
         with self._lock:
             return self._compile_count
+
+    def totals(self) -> tuple[dict[str, int], int]:
+        """(dispatches by phase, decode steps) since the engine
+        started; the server turns them into counters by delta."""
+        with self._lock:
+            return dict(self._dispatches), self._decode_steps
 
     def snapshot(self, since_seq: int = -1) -> list[StepRecord]:
         """Records with ``seq > since_seq`` (all, by default). The

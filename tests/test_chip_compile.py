@@ -19,11 +19,14 @@ runs it.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+from kubeinfer_tpu.observability.stepprof import KERNEL_NAMES
 
 
 @pytest.fixture(scope="module")
@@ -171,3 +174,55 @@ def test_compiles_for_v5e(case, one_chip, no_compile_cache):
     compiled = jax.jit(fn).lower(*args).compile()
     # a router that fell to its dense branch would compile too
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the names the device profile carries ----------------------------------
+# stepprof.KERNEL_NAMES is what a reader of the profile matches; a
+# Pallas kernel's name exists only in a TPU compile, so it is held
+# here, at small shapes (the kernel's name does not depend on them).
+
+_SMALL = (8, 2, 128)  # query heads, kv heads, head_dim
+
+
+def _named_kernel(name):
+    from kubeinfer_tpu.inference import flash_attention as fa
+    from kubeinfer_tpu.inference import weight_quant as wq
+
+    nq, nkv, D = _SMALL
+    q1 = ((2, 1, nq, D), BF16)
+    dense = ((2, 256, nkv, D), BF16)
+    qT = ((1, 128, nq, D), BF16)
+    kvT = ((1, 256, nkv, D), BF16)
+    pool, pool8 = ((9, 128, nkv, D), BF16), ((9, 128, nkv, D), I8)
+    table, lens = ((2, 4), I32), ((2,), I32)
+    scales, tail = ((9, nkv), F32), ((2, 2, 128, nkv, D), BF16)
+    return {
+        "quant_matmul": (wq.quant_matmul, (
+            ((8, 256), BF16), ((256, 256), I8), ((256,), F32))),
+        "decode_attention": (fa.decode_attention, (q1, dense, dense, lens)),
+        "decode_attention_blocks": (fa.decode_attention_blocks, (
+            q1, pool, pool, table, lens)),
+        "decode_attention_blocks_q8": (fa.decode_attention_blocks_q8, (
+            q1, pool8, pool8, scales, scales, tail, tail, table, lens)),
+        "flash_attention": (fa.flash_attention, (
+            qT, kvT, kvT, ((1, 128, 256), jnp.bool_))),
+        "flash_attention_ragged": (fa.flash_attention_ragged, (
+            qT, kvT, kvT, ((), I32), ((1,), I32))),
+    }[name]
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_kernel_carries_its_name_for_v5e(name, one_chip, no_compile_cache):
+    fn, operands = _named_kernel(name)
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in operands
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # the custom call's instruction is named after the kernel, which is
+    # the head of the HLO line a TPU trace prints for the event, and
+    # its op_name ends .../<name>/pallas_call
+    assert re.search(
+        rf'%{name}(\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        text)
+    assert f'/{name}/pallas_call"' in text
