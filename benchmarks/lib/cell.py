@@ -4,7 +4,7 @@ name."""
 
 from __future__ import annotations
 
-import importlib
+import importlib.util
 import json
 import os
 import re
@@ -44,6 +44,7 @@ class Cell:
     traffic: dict
     end_to_end: list[dict] = field(default_factory=list)
     per_layer: list[dict] = field(default_factory=list)
+    checks: list = field(default_factory=list)  # checks/<kind>.py modules
 
 
 def manifest() -> dict:
@@ -65,7 +66,9 @@ def load_cell(name: str) -> Cell:
         end_to_end=[_named("end_to_end", m) for m in spec["end_to_end"]],
         per_layer=[_named("layer_metrics", m)
                    for m in spec["layer_metrics"]],
+        checks=[check(k) for k in spec.get("checks", [])],
     )
+    sizes(cell.config["model_type"])  # a model with no sizes/ file stops here
     have = set(spec["end_to_end"])
     if "setup_s" not in have or len(have) < 2:
         raise CellError(f"{name}: a cell reports setup_s and at least one "
@@ -83,10 +86,25 @@ def load_cell(name: str) -> Cell:
     return cell
 
 
+_LOADED: dict = {}
+
+
 def _module(package: str, name: str):
+    """``<package>/<name>.py`` under ROOT, loaded by its path: the file
+    is the registration, so a model type, a check, a reader kind or a
+    kernel nobody wrote a file for is a CellError that names the file."""
     if not NAME.match(name):
         raise CellError(f"{name!r} is not a name")
-    return importlib.import_module(f"{package}.{name}")
+    path = os.path.join(ROOT, package, name + ".py")
+    if path not in _LOADED:
+        if not os.path.isfile(path):
+            raise CellError(f"no file {os.path.relpath(path, CHECKOUT)}")
+        spec = importlib.util.spec_from_file_location(
+            f"{package}.{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
 
 
 def reader(kind: str):
@@ -98,3 +116,15 @@ def reader(kind: str):
 def opcount(kernel: str):
     """opcount/<kernel>.py: ``count`` and ``shapes_from_hlo``."""
     return _module("opcount", kernel)
+
+
+def sizes(model_type: str):
+    """sizes/<model_type>.py: ``param_bytes(conf, weight_dtype)`` and
+    ``flops_per_token(conf)``, the only place a model's shape is known."""
+    return _module("sizes", model_type)
+
+
+def check(kind: str):
+    """checks/<kind>.py: ``before_window(run)`` and ``after_exit(run)``,
+    either of which may be missing; each returns what it found wrong."""
+    return _module("checks", kind)

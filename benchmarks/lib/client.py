@@ -10,13 +10,13 @@ the server's stamps are pinned to the client's clock by ``check_stamps``.
 
 from __future__ import annotations
 
-import http.client
 import json
 import threading
 import time
 import urllib.error
 import urllib.request
 from dataclasses import dataclass, field
+from http.client import HTTPException
 
 from lib import schedule as sched
 
@@ -137,11 +137,24 @@ def post(url: str, rec: Record, prompt: list[int], t_open: float,
     except urllib.error.HTTPError as e:
         rec.done_s = time.monotonic() - t_open
         rec.status, rec.error = e.code, e.read().decode()[:200]
-    except (OSError, ValueError, KeyError,
-            http.client.HTTPException) as e:
+    except (OSError, ValueError, KeyError, HTTPException) as e:
         rec.done_s = time.monotonic() - t_open
         rec.error = f"{type(e).__name__}: {e}"[:200]
     return rec
+
+
+def judged(records, seconds: float, due=None) -> list[Record]:
+    """The replied Records a cell judges. Open loop (``due``: the
+    schedule's requests): those due in ``[0, seconds)``, whenever the
+    reply came, so that a backlog draining into the window is not
+    credited to it and a request answered after its close is not lost.
+    Closed loop (no ``due``): those replied inside the window, its only
+    population."""
+    if due is None:
+        return [r for r in records if r.done_s and 0 <= r.done_s <= seconds]
+    by_index = {r.index: r for r in records if r.done_s}
+    return [by_index[q.index] for q in due
+            if 0 <= q.due_s < seconds and q.index in by_index]
 
 
 class OpenLoop:

@@ -29,8 +29,7 @@ sys.path.insert(0, HERE)
 
 from lib import cell as cells  # noqa: E402
 from lib import client, prom, schedule, trace  # noqa: E402
-from lib.context import Context  # noqa: E402
-from lib.model_size import param_bytes  # noqa: E402
+from lib.context import CheckRun, Context  # noqa: E402
 from lib.peaks import DEVICE_PEAKS  # noqa: E402
 from lib.stats import percentile  # noqa: E402
 
@@ -181,16 +180,20 @@ def warm_up(child: Child, warmup: list[dict], seed: int, vocab: int,
     return wrong
 
 
-def check_identity(text: str, conf: dict) -> list[str]:
-    wrong = []
-    want = param_bytes(conf, conf["weight_dtype"])
-    got = prom.value(text, "kubeinfer_model_param_bytes")
-    if got != want:
-        wrong.append(f"kubeinfer_model_param_bytes is {got}, "
-                     f"{conf['name']} is {want}: another model is served")
-    if prom.value(text, "kubeinfer_engine_tp_degree") != conf["tp"]:
-        wrong.append("kubeinfer_engine_tp_degree is not the "
-                     "configuration's")
+def device_has_peaks(device: dict) -> bool:
+    """The look for a chip: a number from anything else is no result."""
+    return device["platform"] == "tpu" and device["kind"] in DEVICE_PEAKS
+
+
+def run_checks(cell, moment: str, check_run: CheckRun) -> list[str]:
+    """Each of the cell's checks/<kind>.py at one of its two moments
+    (``before_window``, ``after_exit``); a file that has nothing to do
+    at a moment leaves the function out."""
+    wrong: list[str] = []
+    for mod in cell.checks:
+        fn = getattr(mod, moment, None)
+        if fn is not None:
+            wrong += fn(check_run)
     return wrong
 
 
@@ -269,9 +272,13 @@ def run(args) -> int:
         child.wait_healthy()
         with open(os.path.join(out, "device.json")) as f:
             device = json.load(f)
+        phases = {"healthy": time.monotonic() - T_START}
         wrong = warm_up(child, cell.spec["warmup"], args.seed, vocab,
                         preload)
-        wrong += check_identity(child.metrics(), conf)
+        phases["warmed_up"] = time.monotonic() - T_START
+        check_run = CheckRun(cell=cell, config=conf, seed=args.seed,
+                             out=out, url=child.url, requests=requests)
+        wrong += run_checks(cell, "before_window", check_run)
 
         # the ramp runs into the window: no pause between them
         t_open = time.monotonic() + traffic["ramp_s"]
@@ -323,19 +330,15 @@ def run(args) -> int:
     if rc != 0:
         wrong.append(f"the server exited {rc} on SIGTERM")
 
-    # --- the window's populations
-    completed = [r for r in records if r.done_s and 0 <= r.done_s <= seconds]
+    # --- the requests the cell judges: due in the window and answered
+    # by the end of the drain (open loop), replied in it (closed loop)
+    window = client.judged(records, seconds, requests if open_loop else None)
     if open_loop:
-        due = [q for q in requests if 0 <= q.due_s < seconds]
-        by_index = {r.index: r for r in records}
-        window = [by_index[q.index] for q in due if q.index in by_index
-                  and by_index[q.index].done_s]
-        attempted = len(due)
+        attempted = sum(1 for q in requests if 0 <= q.due_s < seconds)
         failed = attempted - sum(1 for r in window if r.ok)
     else:
-        window = completed
-        attempted = len(completed)
-        failed = sum(1 for r in completed if not r.ok)
+        attempted = len(window)
+        failed = sum(1 for r in window if not r.ok)
     for r in window:
         bad = client.check_reply(r, vocab)
         if bad:
@@ -356,13 +359,19 @@ def run(args) -> int:
     else:
         wrong.append("fewer than two scrapes of /metrics")
 
-    known = device["platform"] == "tpu" and device["kind"] in DEVICE_PEAKS
-    if not known:
+    if not device_has_peaks(device):
         wrong.append(f"the device is {device['platform']} "
                      f"{device['kind']!r}, not a TPU of the peaks table")
 
-    ctx = Context(seconds=seconds, setup_s=setup_s,
-                  window=window, completed=completed, scrapes=scrapes,
+    # the peak was read before the child went; the chips are free now
+    peak = prom.by_label(last, "kubeinfer_device_peak_bytes_in_use",
+                         "device")
+    device["memory_peak_bytes"] = int(max(peak.values())) if peak else 0
+    check_run.url, check_run.window = None, window
+    wrong += run_checks(cell, "after_exit", check_run)
+
+    ctx = Context(seconds=seconds, setup_s=setup_s, config=conf, out=out,
+                  window=window, scrapes=scrapes,
                   peaks=DEVICE_PEAKS.get(device["kind"]))
     if args.trace:
         ctx.trace = reduce_trace(out)
@@ -379,10 +388,8 @@ def run(args) -> int:
          "generator_lateness_ms": {
              "p50": percentile(late, 50) if late else None,
              "max": max(late) if late else None},
-         "notes": ctx.notes, "wrong": wrong[:20]})
-    peak = prom.by_label(last, "kubeinfer_device_peak_bytes_in_use",
-                         "device")
-    device["memory_peak_bytes"] = int(max(peak.values())) if peak else 0
+         "setup_phases_s": phases,
+         "notes": ctx.notes + check_run.notes, "wrong": wrong[:20]})
     result = {"correct": not wrong, "attempted": attempted,
               "failed": failed, "metrics": metrics, "device": device}
     if args.trace:
@@ -405,7 +412,15 @@ def run(args) -> int:
         result["end_to_end_while_traced"] = {
             k: v["value"]
             for k, v in read_metrics(cell.end_to_end, ctx).items()}
+    # every number compared, beside its limit: in the line, last, and
+    # as the last lines on standard error
+    compared = {"failed_requests": [failed, 0],
+                "compiled_in_window": [len(compiled), 0],
+                "server_exit_code": [rc, 0], **check_run.compared}
+    result["compared"] = compared
     say(result)
+    for name, (value, limit) in compared.items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
     return 0
 
 

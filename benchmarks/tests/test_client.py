@@ -45,3 +45,17 @@ def test_a_wrong_reply_is_named(kw, word):
 def test_one_token_has_no_decode_span():
     r = _rec(max_tokens=1, tokens=[5], server_tpot_ms=9.0)
     assert r.server_span_ms == 500.0
+
+
+def test_a_connection_cut_mid_reply_is_a_failed_record(monkeypatch):
+    # http.client.HTTPException, which ``http`` the function shadowed:
+    # a request cut off by the server's SIGTERM raised AttributeError
+    from http.client import IncompleteRead
+
+    def cut(url, body=None, timeout=60.0):
+        raise IncompleteRead(b"")
+
+    monkeypatch.setattr(client, "http", cut)
+    rec = client.post("http://x", client.Record(0, 0.0, max_tokens=4),
+                      [1, 2, 3], t_open=0.0, timeout=1.0)
+    assert not rec.ok and rec.error.startswith("IncompleteRead")

@@ -85,12 +85,12 @@ def _records():
 
 def test_client_records_stats():
     recs = _records()
-    ctx = _ctx(window=recs, completed=recs[:2])
+    ctx = _ctx(window=recs)
     p50 = client_records.read({"field": "ttft_ms", "stat": "p50"}, ctx)
     assert p50 == pytest.approx(300.0)
-    tok = client_records.read({"field": "n_tokens", "stat": "sum_per_s",
-                               "population": "completed"}, ctx)
-    assert tok == pytest.approx(3.2)
+    tok = client_records.read({"field": "n_tokens", "stat": "sum_per_s"},
+                              ctx)
+    assert tok == pytest.approx(sum(len(r.tokens) for r in recs) / 10.0)
     # a one-token reply has no decode span: left out of the gap statistic
     assert client_records.read({"field": "tpot_ms", "stat": "mean"},
                                ctx) == pytest.approx(100.0)
@@ -103,3 +103,47 @@ def test_trace_readers_report_nothing_without_a_trace():
     assert trace_ops.read({"stat": "idle_share"}, _ctx()) is None
     assert trace_roofline.read({"match": "x", "opcount": "quant_matmul"},
                                _ctx()) is None
+
+
+MFU0 = '''kubeinfer_engine_prefill_tokens_total{kind="computed"} 1000
+kubeinfer_engine_prefill_tokens_total{kind="cached"} 5000
+kubeinfer_inference_completion_tokens_total 200
+kubeinfer_inference_requests_total{route="continuous",outcome="ok"} 10
+kubeinfer_engine_step_duration_seconds_sum{phase="decode"} 10.0
+kubeinfer_engine_step_duration_seconds_sum{phase="prefill"} 1.0
+'''
+MFU1 = MFU0.replace('computed"} 1000', 'computed"} 4000') \
+    .replace("tokens_total 200", "tokens_total 1200") \
+    .replace('"ok"} 10', '"ok"} 30') \
+    .replace('decode"} 10.0', 'decode"} 17.0') \
+    .replace('prefill"} 1.0', 'prefill"} 2.0')
+
+
+def test_step_mfu_prices_the_computed_tokens_over_the_steps_own_time():
+    from lib import cell as cells
+    from readers import model_flops
+
+    conf = cells.load_json("configs", "qwen2-7b-w8.json")
+    args = dict(cells.load_json("layer_metrics", "step.mfu.json")["reader"])
+    args.pop("kind")
+    ctx = Context(seconds=51.0, setup_s=1.0, config=conf,
+                  scrapes=[(0.0, MFU0), (51.0, MFU1)],
+                  peaks={"bf16_flops": 197e12})
+    price = cells.sizes("qwen2").flops_per_token(conf)
+    ops = price["layers"] * (3000 + 1000 - 20) + price["head"] * 1000
+    assert model_flops.read(args, ctx) == pytest.approx(
+        100 * ops / (8.0 * 197e12))
+    # cached tokens are not computed: they move nothing
+    more = MFU1.replace('cached"} 5000', 'cached"} 9000')
+    ctx.scrapes = [(0.0, MFU0), (51.0, more)]
+    assert model_flops.read(args, ctx) == pytest.approx(
+        100 * ops / (8.0 * 197e12))
+    # nothing computed, no peaks or a missing counter: nothing, never 0
+    ctx.scrapes = [(0.0, MFU0), (51.0, MFU0)]
+    assert model_flops.read(args, ctx) is None
+    ctx.scrapes = [(0.0, MFU0), (51.0, MFU1)]
+    ctx.peaks = None
+    assert model_flops.read(args, ctx) is None
+    ctx.peaks = {"bf16_flops": 197e12}
+    ctx.scrapes = [(0.0, MFU0), (51.0, "nothing 1\n")]
+    assert model_flops.read(args, ctx) is None
