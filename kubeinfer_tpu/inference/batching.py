@@ -61,6 +61,7 @@ from kubeinfer_tpu.inference.kv_blocks import (
 )
 from kubeinfer_tpu.analysis.racecheck import guard, make_lock
 from kubeinfer_tpu.inference.model import Params, forward
+from kubeinfer_tpu.inference.moe import STATS as MOE_STATS
 from kubeinfer_tpu.observability import tracing
 from kubeinfer_tpu.observability.flightrecorder import FlightRecorder
 from kubeinfer_tpu.observability.slo import SLOMonitor, SLOObjective
@@ -78,10 +79,13 @@ from kubeinfer_tpu.inference.stepper import (
     DraftState,
     SlotState,
     WINDOW_BUCKETS,
+    add_moe_stats,
     decode_window,
     init_draft_state,
     init_slot_state,
+    layer_caches,
     sample_rows,
+    split_layer_caches,
     verify_window,
 )
 
@@ -107,6 +111,30 @@ DEFAULT_BLOCK_SIZE = 128
 # sequence-parallel engine, and this batcher); the admit/prefill-chunk
 # dispatches below stay here — they are paged-pool plumbing the other
 # engines never touch.
+
+
+def _row_recurrent(state: SlotState, slot, start) -> list:
+    """One slot's (state, convolution tail) of every linear-attention
+    layer, as 1-row batches: what the slot holds when the prefill
+    continues (``start > 0``: an earlier chunk left it), zeros when a
+    request begins."""
+    def row(x):
+        return jnp.where(start > 0, x[slot], jnp.zeros_like(x[0]))[None]
+
+    return [(row(s), row(c))
+            for s, c in zip(state.gdn_state, state.gdn_conv)]
+
+
+def _put_row_recurrent(state: SlotState, slot, linear: list) -> dict:
+    """The SlotState fields with one slot's recurrent rows replaced."""
+    if not linear:
+        return {}
+    return dict(
+        gdn_state=[s.at[slot].set(n[0][0])
+                   for s, n in zip(state.gdn_state, linear)],
+        gdn_conv=[c.at[slot].set(n[1][0])
+                  for c, n in zip(state.gdn_conv, linear)],
+    )
 
 
 @functools.partial(
@@ -190,10 +218,17 @@ def _admit_slot(
             )
             for ck, cv in zip(state.caches_k, state.caches_v)
         ]
+    stats: list = []
     logits, caches = forward(
         params, suffix, cfg, positions=q_pos[None, :], attn_mask=mask,
-        kv_caches=caches, cache_offset=start, wq_gspmd=wq_gspmd,
+        kv_caches=layer_caches(state, cfg, caches,
+                               _row_recurrent(state, slot, start)),
+        cache_offset=start, wq_gspmd=wq_gspmd,
+        # the bucket's padding must not reach a recurrent state or an
+        # expert
+        valid_len=suffix_len[None], moe_stats=stats,
     )
+    caches, linear = split_layer_caches(cfg, caches)
 
     last = jnp.clip(suffix_len - 1, 0, T - 1)
     first = sample_rows(
@@ -268,6 +303,8 @@ def _admit_slot(
     return dataclasses.replace(
         state,
         **kv_fields,
+        **_put_row_recurrent(state, slot, linear),
+        **add_moe_stats(state, stats),
         tables=state.tables.at[slot].set(table_row),
         last_token=state.last_token.at[slot].set(first),
         offset=state.offset.at[slot].set(prompt_len),
@@ -295,6 +332,7 @@ def _prefill_chunk(
     table_row: jax.Array,  # i32[max_blocks] this slot's block table
     own_mask: jax.Array,  # bool[max_blocks] True = freshly allocated block
     wq_gspmd: bool = False,  # static: dense dequant route under GSPMD
+    slot: jax.Array | None = None,  # i32[]: models with recurrent layers
 ) -> SlotState:
     """Commit ONE fixed-size prefill chunk's KV into the pool — no
     sampling, no slot-state installation (``_admit_slot`` finishes the
@@ -346,11 +384,19 @@ def _prefill_chunk(
             )
             for ck, cv in zip(state.caches_k, state.caches_v)
         ]
+    stats: list = []
     _, caches = forward(
         params, window, cfg, positions=q_pos[None, :], attn_mask=mask,
-        kv_caches=caches, cache_offset=pos, return_hidden=True,
-        wq_gspmd=wq_gspmd,
+        kv_caches=layer_caches(state, cfg, caches,
+                               _row_recurrent(state, slot, pos)),
+        cache_offset=pos, return_hidden=True,
+        wq_gspmd=wq_gspmd, moe_stats=stats,
     )
+    caches, linear = split_layer_caches(cfg, caches)
+    # the slot is not live yet, but it already holds its own recurrent
+    # rows: decode windows leave an inactive row's untouched
+    extra = {**_put_row_recurrent(state, slot, linear),
+             **add_moe_stats(state, stats)}
 
     own = own_mask[:, None, None, None]
 
@@ -388,12 +434,14 @@ def _prefill_chunk(
             scales_k=[s for _, s in qk],
             caches_v=[p for p, _ in qv_],
             scales_v=[s for _, s in qv_],
+            **extra,
         )
 
     return dataclasses.replace(
         state,
         caches_k=[put(b, c[0]) for b, c in zip(state.caches_k, caches)],
         caches_v=[put(b, c[1]) for b, c in zip(state.caches_v, caches)],
+        **extra,
     )
 
 
@@ -522,6 +570,12 @@ def _import_blocks(
 
 
 # --- host-side scheduler ---------------------------------------------------
+
+_RECURRENT_WIRE = (
+    "this model has linear-attention layers whose recurrent state the "
+    "KV wire does not carry: live migration and disaggregated prefill "
+    "are refused for it (blocks alone cannot resume a request)"
+)
 
 
 class EngineDrainingError(RuntimeError):
@@ -768,6 +822,14 @@ class ContinuousEngine:
         self.layout = layout if layout is not None else EngineLayout()
         self.layout.check_model(cfg)
         self._sharded = self.layout.sharded
+        # models with recurrent layers: what cannot follow the state
+        # yet is refused here, by name, never served from pages alone
+        self._recurrent = cfg.recurrent
+        cfg.check_serving(
+            weight_dtype=weight_dtype, kv_dtype=kv_dtype,
+            tp=self.layout.tp,
+            speculation=speculative is not None or spec_draft is not None,
+        )
         # weight precision axis (ISSUE 20), kv_dtype's load-time
         # mirror: "int8" accepts either pre-quantized params (the
         # load-time path — weights.params_from_state_dict /
@@ -815,8 +877,10 @@ class ContinuousEngine:
             # 2x slot capacity (+ the reserved null block): the surplus
             # is what the radix cache retains between requests — with
             # exactly slot capacity every admit would evict the prefix
-            # it hopes to reuse
-            num_blocks = 1 + 2 * n_slots * self.max_blocks
+            # it hopes to reuse. A model with recurrent layers reuses
+            # no prefix, so it retains none.
+            num_blocks = 1 + (1 if self._recurrent else 2) \
+                * n_slots * self.max_blocks
         if num_blocks < 1 + n_slots * self.max_blocks:
             # below this floor a full-length request could find the pool
             # permanently short even after evicting the whole trie (its
@@ -919,6 +983,14 @@ class ContinuousEngine:
         # run through _prefill_chunk/_admit_slot, taken from the radix
         # cache (reuse * block_size), or bucket padding (T - suffix)
         self.prefill_tokens = {"computed": 0, "cached": 0, "padded": 0}
+        # admissions whose prefix lookup was refused, by reason: a
+        # model with recurrent layers cannot resume from blocks alone
+        self.prefix_refused = {"recurrent_state": 0}
+        # routed experts: the device-side counters (moe.STATS), as
+        # Python ints; _moe_seen is the device's wrapping u32 at the
+        # last read
+        self.moe_counts = dict.fromkeys(MOE_STATS, 0)
+        self._moe_seen: np.ndarray | None = None
         # step_t of recent decode/verify windows, for _split_wait; 4096
         # windows is minutes of decoding at any step time seen so far
         self._boundaries: collections.deque[float] = collections.deque(
@@ -1059,6 +1131,10 @@ class ContinuousEngine:
                 *st.scales_v, *st.tails_k, *st.tails_v,
             )
         ))
+        # what the linear-attention layers hold instead of pages
+        self.recurrent_state_bytes = int(sum(
+            x.nbytes for x in (*st.gdn_state, *st.gdn_conv)
+        ))
         # load-shedding door (ROADMAP item 5): 0 = unbounded (the
         # pre-shedding behavior); > 0 sheds submits once waiting work
         # (queue + holdover + parked) reaches the limit
@@ -1136,6 +1212,8 @@ class ContinuousEngine:
                     f"resume bucket {_bucket(len(prompt) + len(rt))} "
                     f"exceeds slot capacity ({self.cache_len})"
                 )
+        if export_kv and self._recurrent:
+            raise ValueError(_RECURRENT_WIRE)
         if self.queue_depth_limit:
             # same lockless depth read as stats_summary (torn by at
             # most 1); >= so limit=1 means "shed whenever anything is
@@ -1242,6 +1320,8 @@ class ContinuousEngine:
         caught-up slot parks-for-migrate. Idempotent; ``undrain()``
         reverses it (the rebalance caller drains, hands sessions off,
         then rejoins the fleet)."""
+        if self._recurrent:
+            raise ValueError(_RECURRENT_WIRE)
         with self._lock:
             if self._draining:
                 return
@@ -1322,6 +1402,8 @@ class ContinuousEngine:
         already be in the radix cache (landed by the previous chunks) —
         a chunk whose base prefix was evicted between chunks fails with
         ``missing_prefix`` rather than caching a chain with a hole."""
+        if self._recurrent:
+            raise ValueError(_RECURRENT_WIRE)
         if start_block < 0:
             return 0, "shape_mismatch"
         if kv_dtype != self.kv_dtype:
@@ -1487,7 +1569,10 @@ class ContinuousEngine:
             # steps of decode/verify windows, prompt tokens by fate
             "dispatches": dispatches,
             "decode_steps": decode_steps,
+            "decode_row_steps": self.profiler.decode_row_steps,
             "prefill_tokens": dict(self.prefill_tokens),
+            "prefix_refused": dict(self.prefix_refused),
+            "moe": dict(self.moe_counts),
             "chunk_queue": len(self._prefills),
             "parked": len(self._parked),
             # fused decode dispatches (each covers 1..max_window steps)
@@ -1773,7 +1858,14 @@ class ContinuousEngine:
         never degraded correctness)."""
         p = len(tokens)
         bs = self.block_size
-        matched = self._radix.match(tokens)  # +1 ref each, ours now
+        if self._recurrent:
+            # blocks without the state that goes with them resume
+            # nothing: no lookup, no reuse, the whole prompt recomputes
+            # (a parked row too), and the refusal is counted
+            matched = []
+            self.prefix_refused["recurrent_state"] += 1
+        else:
+            matched = self._radix.match(tokens)  # +1 ref each, ours now
         # full blocks only, and never the whole prompt: the last token
         # must be recomputed so the admit has logits to sample from
         reuse = min(len(matched), (p - 1) // bs)
@@ -1950,6 +2042,8 @@ class ContinuousEngine:
                 jnp.int32(task.pos), self.cfg,
                 jnp.asarray(task.table_row), jnp.asarray(task.own_mask),
                 wq_gspmd=self._sharded,
+                **({"slot": jnp.int32(task.slot)}
+                   if self._recurrent else {}),
             )
         task.pos += C
         self.chunks_total += 1
@@ -2053,7 +2147,7 @@ class ContinuousEngine:
             # cache the effective prompt's FULL blocks for later admits —
             # including this one's fresh blocks (their KV is committed by
             # the scatter above; the partial tail block stays private)
-            full = p // self.block_size
+            full = 0 if self._recurrent else p // self.block_size
             if self.kv_dtype == "int8":
                 # every owned full block was quantize-committed by the
                 # scatter above (chunked prefills requantize the same
@@ -2252,7 +2346,9 @@ class ContinuousEngine:
         # match of it (the readmit itself recomputes the tail, but a
         # LONGER continuation would reuse the poisoned block verbatim)
         committed = toks[:-1]
-        full = len(committed) // self.block_size
+        # a model with recurrent layers caches nothing: its readmit
+        # recomputes the whole effective prompt (_plan_kv)
+        full = 0 if self._recurrent else len(committed) // self.block_size
         if full:
             self._radix.insert(committed, blocks[:full])
         self._slot_req[slot] = None
@@ -2857,6 +2953,20 @@ class ContinuousEngine:
                     staged += 1
             span.set_metadata(staged=staged)
 
+    def _read_moe_stats(self) -> None:
+        """The routed experts' device-side counters, read where the
+        window's tokens are (both are outputs of the program that has
+        just been waited for, so this adds no synchronisation). The
+        device's u32 sums wrap; the differences do not."""
+        if not self._state.moe_stats:
+            return
+        now = np.asarray(self._state.moe_stats[0])
+        seen = self._moe_seen if self._moe_seen is not None \
+            else np.zeros_like(now)
+        for name, d in zip(MOE_STATS, (now - seen).tolist()):
+            self.moe_counts[name] += d
+        self._moe_seen = now
+
     def _pick_horizon(self, budgets: list[int], host_work: bool) -> int:
         """Decode-window horizon for this pass, from the static bucket
         set (one compiled shape each). K collapses to 1 whenever the
@@ -3106,8 +3216,13 @@ class ContinuousEngine:
                 # plans while draining
                 self._plan_admissions()
             with annotate("engine.decode.readback"):
+                # the routed experts' counters leave the device with
+                # the tokens, not in a second round trip behind them
+                for stats in self._state.moe_stats:
+                    stats.copy_to_host_async()
                 # lint: allow[host-sync] window boundary: the [n_slots, k] token matrix feeds the Python result queues
                 toks = np.asarray(tokens)
+                self._read_moe_stats()
             # one clock read per WINDOW, outside the lock: token
             # times inside the bracket are interpolated below
             # (docs/OBSERVABILITY.md — traces carry

@@ -59,12 +59,91 @@ class ModelConfig:
     # the q/o projections are then [H, heads*head_dim] rectangles, which
     # the decoder already handles generically). 0 = derive.
     head_dim_override: int = 0
+    # Qwen3-Next family (hybrid linear attention + sparse experts).
+    # ``layer_types`` names each layer's mixer, "linear_attention"
+    # (Gated DeltaNet, gdn.py) or "full_attention"; empty = every layer
+    # is full attention, the only kind the other families have. A GDN
+    # layer holds a fixed-size recurrent state per slot and no pages.
+    layer_types: tuple[str, ...] = ()
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    # full-attention deltas of the family: a per-head sigmoid gate on
+    # the attention output packed into q_proj ([q | gate] per head), RMS
+    # norms on each q and k head, rotary on the head's first
+    # ``partial_rotary_factor`` share only
+    attn_output_gate: bool = False
+    qk_norm: bool = False
+    partial_rotary_factor: float = 1.0
+    # routed experts: the router scores ``num_experts_routed`` experts,
+    # this replica HOLDS ``num_local_experts`` of them starting at
+    # ``expert_offset`` (one expert-parallel rank's share; pairs routed
+    # to an absent expert add nothing here). 0 routed = the Mixtral
+    # case, every scored expert is held. ``moe_intermediate_size`` is
+    # the expert width when it is not ``intermediate_size``; a shared
+    # expert (> 0) runs for every token behind a sigmoid gate.
+    num_experts_routed: int = 0
+    expert_offset: int = 0
+    moe_intermediate_size: int = 0
+    shared_expert_intermediate_size: int = 0
 
     @property
     def head_dim(self) -> int:
         if self.head_dim_override:
             return self.head_dim_override
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts_routed or self.num_local_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a recurrent state instead of pages: prefix
+        blocks alone cannot resume such a model (batching.py)."""
+        return "linear_attention" in self.layer_types
+
+    def check_serving(self, weight_dtype: str = "bf16",
+                      kv_dtype: str = "bf16", tp: int = 1, sp: int = 1,
+                      speculation: bool = False) -> None:
+        """Refuse, at configuration time and by name, what this model
+        cannot be served with yet."""
+        if not self.recurrent:
+            return
+        refused = [
+            what for what, on in (
+                ("--weight-dtype int8", weight_dtype != "bf16"),
+                ("--kv-dtype int8", kv_dtype != "bf16"),
+                ("--tensor-parallel-size > 1", tp > 1),
+                ("--sequence-parallel-size > 1", sp > 1),
+                ("a draft model or speculation", speculation),
+            ) if on
+        ]
+        if refused:
+            raise ValueError(
+                "not implemented for a model with linear-attention "
+                "(recurrent-state) layers: " + ", ".join(refused)
+            )
+
+    def layer_is_linear(self, i: int) -> bool:
+        return bool(self.layer_types) and (
+            self.layer_types[i] == "linear_attention")
+
+    @property
+    def full_attention_layers(self) -> tuple[int, ...]:
+        """Indices of the layers that keep keys and values in pages."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if not self.layer_is_linear(i))
 
     def __post_init__(self) -> None:
         if not self.head_dim_override and (
@@ -83,6 +162,34 @@ class ModelConfig:
             raise ValueError(
                 "num_attention_heads must divide by num_key_value_heads"
             )
+        # a config read back from JSON (checkpoint.py) carries a list
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.num_hidden_layers:
+                raise ValueError(
+                    "layer_types must name every one of the "
+                    f"{self.num_hidden_layers} layers"
+                )
+            bad = set(self.layer_types) - {
+                "linear_attention", "full_attention"}
+            if bad:
+                raise ValueError(f"unknown layer type(s) {sorted(bad)}")
+        if self.recurrent and (
+            self.linear_num_value_heads % max(self.linear_num_key_heads, 1)
+            or self.linear_conv_kernel_dim < 2
+        ):
+            raise ValueError(
+                "linear attention needs value heads that divide by key "
+                "heads and a convolution of at least 2 taps"
+            )
+        if self.num_experts_routed and not (
+            0 <= self.expert_offset
+            and self.expert_offset + self.num_local_experts
+            <= self.num_experts_routed
+        ):
+            raise ValueError(
+                "the held experts must lie inside the routed ones"
+            )
 
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "ModelConfig":
@@ -96,6 +203,8 @@ class ModelConfig:
                 "attention_bias=true (o_proj bias) is not supported; "
                 "only the Qwen2 q/k/v-bias scheme is implemented"
             )
+        if d.get("model_type") == "qwen3_next":
+            return cls._from_qwen3_next(d)
         return cls(
             vocab_size=d["vocab_size"],
             hidden_size=d["hidden_size"],
@@ -121,6 +230,66 @@ class ModelConfig:
             scale_embeddings=d.get("model_type") == "gemma",
             rmsnorm_offset=d.get("model_type") == "gemma",
             head_dim_override=_hf_head_dim_override(d),
+        )
+
+    @classmethod
+    def _from_qwen3_next(cls, d: dict[str, Any]) -> "ModelConfig":
+        """Qwen3-Next: three Gated DeltaNet layers to one gated full-
+        attention layer, every MLP a routed mixture with a shared
+        expert. ``expert_parallel`` {"size", "rank"} (absent = 1, 0) is
+        the share this replica serves: ``num_experts`` counts the
+        experts it HOLDS, the router scores size times as many."""
+        unsupported = [
+            k for k, want in (("decoder_sparse_step", 1),
+                              ("mlp_only_layers", []),
+                              ("rope_scaling", None),
+                              ("norm_topk_prob", True),
+                              ("use_sliding_window", False))
+            if d.get(k, want) != want
+        ]
+        if unsupported:
+            raise ValueError(
+                f"qwen3_next with {unsupported} set is not supported"
+            )
+        L = d["num_hidden_layers"]
+        every = d.get("full_attention_interval", 4)
+        types = d.get("layer_types") or [
+            "full_attention" if (i + 1) % every == 0
+            else "linear_attention" for i in range(L)
+        ]
+        ep = d.get("expert_parallel") or {}
+        size, rank = ep.get("size", 1), ep.get("rank", 0)
+        held = d["num_experts"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d.get("intermediate_size", 0),
+            num_hidden_layers=L,
+            num_attention_heads=d["num_attention_heads"],
+            num_key_value_heads=d["num_key_value_heads"],
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            max_position_embeddings=d.get("max_position_embeddings", 4096),
+            tie_word_embeddings=d.get("tie_word_embeddings", False),
+            num_local_experts=held,
+            num_experts_per_tok=d["num_experts_per_tok"],
+            hidden_act=d.get("hidden_act", "silu"),
+            rmsnorm_offset=True,
+            head_dim_override=_hf_head_dim_override(d),
+            layer_types=tuple(types),
+            linear_num_key_heads=d["linear_num_key_heads"],
+            linear_num_value_heads=d["linear_num_value_heads"],
+            linear_key_head_dim=d["linear_key_head_dim"],
+            linear_value_head_dim=d["linear_value_head_dim"],
+            linear_conv_kernel_dim=d["linear_conv_kernel_dim"],
+            attn_output_gate=True,
+            qk_norm=True,
+            partial_rotary_factor=d.get("partial_rotary_factor", 0.25),
+            num_experts_routed=held * size,
+            expert_offset=held * rank,
+            moe_intermediate_size=d["moe_intermediate_size"],
+            shared_expert_intermediate_size=d.get(
+                "shared_expert_intermediate_size", 0),
         )
 
 

@@ -70,6 +70,18 @@ def init_params(
     norm_init = jnp.zeros if cfg.rmsnorm_offset else jnp.ones
     layers = []
     for i in range(cfg.num_hidden_layers):
+        if cfg.layer_types:
+            # Qwen3-Next family: its own key schedule, one key a leaf
+            # whichever kind the layer is (benchmarks/reference/
+            # qwen3_next.py draws the same)
+            if weight_dtype != "bf16":
+                raise ValueError(
+                    "int8 weights are not implemented for models with "
+                    "linear-attention layers and routed experts"
+                )
+            layers.append(put("layer", _init_hybrid_layer(
+                cfg, jax.random.fold_in(k_layers, i), i, dtype)))
+            continue
         ks = jax.random.split(jax.random.fold_in(k_layers, i), 7)
         layer = {
             "input_layernorm": norm_init((H,), dtype),
@@ -109,6 +121,52 @@ def init_params(
     if not cfg.tie_word_embeddings:
         params["lm_head"] = put("lm_head", dense(k_head, (H, V)))
     return params
+
+
+HYBRID_KEYS = 16  # keys drawn for a Qwen3-Next layer, by leaf below
+
+
+def _init_hybrid_layer(cfg: ModelConfig, key, i: int, dtype) -> dict:
+    """One Qwen3-Next layer: a Gated DeltaNet or a gated full-attention
+    mixer, then routed experts with a shared one. Key j of the layer's
+    split makes the same leaf in every layer, so the mixer's kind
+    changes which keys are used and never what another leaf draws."""
+    from kubeinfer_tpu.inference.gdn import init_gdn_params
+
+    ks = jax.random.split(key, HYBRID_KEYS)
+    H, D = cfg.hidden_size, cfg.head_dim
+    n_q, n_kv = cfg.num_attention_heads, cfg.num_key_value_heads
+    E, F = cfg.num_local_experts, cfg.expert_width
+    Fs = cfg.shared_expert_intermediate_size
+
+    def dense(k, shape):
+        return (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+
+    layer = {
+        "input_layernorm": jnp.zeros((H,), dtype),
+        "post_attention_layernorm": jnp.zeros((H,), dtype),
+        "moe": {
+            "router": dense(ks[8], (H, cfg.router_width)),
+            "gate_proj": dense(ks[9], (E, H, F)),
+            "up_proj": dense(ks[10], (E, H, F)),
+            "down_proj": dense(ks[11], (E, F, H)),
+            "shared_gate_proj": dense(ks[12], (H, Fs)),
+            "shared_up_proj": dense(ks[13], (H, Fs)),
+            "shared_down_proj": dense(ks[14], (Fs, H)),
+            "shared_expert_gate": dense(ks[15], (H, 1)),
+        },
+    }
+    if cfg.layer_is_linear(i):
+        layer["linear_attn"] = init_gdn_params(ks[4:8], cfg, dtype)
+    else:
+        # q_proj packs [query | gate] per head
+        layer["q_proj"] = dense(ks[0], (H, n_q * 2 * D))
+        layer["k_proj"] = dense(ks[1], (H, n_kv * D))
+        layer["v_proj"] = dense(ks[2], (H, n_kv * D))
+        layer["o_proj"] = dense(ks[3], (n_q * D, H))
+        layer["q_norm"] = jnp.zeros((D,), dtype)
+        layer["k_norm"] = jnp.zeros((D,), dtype)
+    return layer
 
 
 def layer_param_template(cfg: ModelConfig) -> dict:
@@ -197,6 +255,12 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     HF llama convention: the head dim is split into halves (x1 = first
     half, x2 = second half), not interleaved pairs.
     """
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        # partial rotary (Qwen3-Next): the head's first ``rot`` dims
+        # turn, the rest pass
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     c = cos[:, :, None, :].astype(x.dtype)
@@ -239,8 +303,16 @@ def decoder_layer(
     tp_size: int = 1,
     block_tables: jax.Array | None = None,  # i32[B, max_blocks] paged write
     wq_gspmd: bool = False,
+    valid_len: jax.Array | None = None,  # i32[B] real tokens of each row
+    moe_stats: list | None = None,  # routed layers append their counters
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
     """One pre-norm block; returns (x, updated kv cache or None).
+
+    What a layer caches follows its kind: a
+    full-attention layer's ``kv_cache`` is the (k, v) pair described
+    below; a linear-attention layer's is its (state, convolution tail)
+    pair, which ``valid_len`` keeps padding and idle rows out of
+    (stepper.SlotState says which layer holds what).
 
     Projection matmuls route through weight_quant.wq_dot so a layer
     whose leaves are quantized dicts rides the fused dequant-matmul;
@@ -277,6 +349,19 @@ def decoder_layer(
         x, layer["input_layernorm"], cfg.rms_norm_eps,
         offset=cfg.rmsnorm_offset,
     )
+    if "linear_attn" in layer:  # static: pytree structure
+        from kubeinfer_tpu.inference.gdn import gdn_mixer, init_gdn_state
+
+        if valid_len is None:
+            valid_len = jnp.full((B,), T, jnp.int32)
+        state, tail = kv_cache if kv_cache is not None else \
+            init_gdn_state(cfg, B, x.dtype)
+        out, state, tail = gdn_mixer(
+            layer["linear_attn"], h, cfg, state, tail, valid_len)
+        if kv_cache is not None:
+            kv_cache = (state, tail)
+        return _mlp_residual(layer, x + out, cfg, tp_axis, wq_gspmd,
+                             valid_len, moe_stats), kv_cache
     q = wq_dot(h, layer["q_proj"], gspmd=wq_gspmd)
     k = wq_dot(h, layer["k_proj"], gspmd=wq_gspmd)
     v = wq_dot(h, layer["v_proj"], gspmd=wq_gspmd)
@@ -284,9 +369,17 @@ def decoder_layer(
         q = q + layer["q_bias"]
         k = k + layer["k_bias"]
         v = v + layer["v_bias"]
+    gate = None
+    if cfg.attn_output_gate:  # q_proj packs [query | gate] per head
+        q, gate = jnp.split(q.reshape(B, T, n_q, 2 * D), 2, axis=-1)
     q = q.reshape(B, T, n_q, D)
     k = k.reshape(B, T, n_kv, D)
     v = v.reshape(B, T, n_kv, D)
+    if cfg.qk_norm:  # over each head's width, before the rotation
+        q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps,
+                     offset=cfg.rmsnorm_offset)
+        k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps,
+                     offset=cfg.rmsnorm_offset)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
 
@@ -387,6 +480,9 @@ def decoder_layer(
         kv_cache = (ck, cv)
 
     attn = attn_fn(q, k, v, mask)
+    if gate is not None:
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            attn.dtype)
     attn_out = wq_dot(
         attn.reshape(B, T, n_q * D), layer["o_proj"], gspmd=wq_gspmd
     )
@@ -394,15 +490,29 @@ def decoder_layer(
         # row-parallel epilogue: each device contracted its own heads
         attn_out = jax.lax.psum(attn_out, tp_axis)
     x = x + attn_out
+    return _mlp_residual(layer, x, cfg, tp_axis, wq_gspmd, valid_len,
+                         moe_stats), kv_cache
 
+
+def _mlp_residual(layer, x, cfg, tp_axis, wq_gspmd, valid_len, moe_stats):
+    """``x + mlp(norm(x))``: the half of a block every kind of layer
+    shares, dense or routed."""
     h = rms_norm(
         x, layer["post_attention_layernorm"], cfg.rms_norm_eps,
         offset=cfg.rmsnorm_offset,
     )
-    if "moe" in layer:  # Mixtral family (static: pytree structure)
-        from kubeinfer_tpu.inference.moe import moe_block
+    if "moe" in layer:  # routed experts (static: pytree structure)
+        from kubeinfer_tpu.inference.moe import moe_forward
 
-        m = moe_block(layer["moe"], h, top_k=cfg.num_experts_per_tok)
+        m, stats = moe_forward(
+            layer["moe"], h, cfg.num_experts_per_tok,
+            expert_offset=cfg.expert_offset, act=_mlp_act(cfg),
+            valid=None if valid_len is None else (
+                jnp.arange(x.shape[1])[None, :] < valid_len[:, None]),
+            gspmd=wq_gspmd or tp_axis is not None,
+        )
+        if moe_stats is not None:
+            moe_stats.append(stats)
         if tp_axis is not None:
             # experts shard like the dense mlp (param_specs): each
             # device holds every expert's F/tp lanes; the router sees
@@ -420,7 +530,7 @@ def decoder_layer(
         if tp_axis is not None:
             mlp = jax.lax.psum(mlp, tp_axis)
         x = x + mlp
-    return x, kv_cache
+    return x
 
 
 # --- full forward ----------------------------------------------------------
@@ -444,8 +554,17 @@ def forward(
     return_hidden: bool = False,
     block_tables: jax.Array | None = None,  # i32[B, max_blocks] paged write
     wq_gspmd: bool = False,
+    valid_len: jax.Array | None = None,  # i32[B] real tokens of each row
+    moe_stats: list | None = None,  # routed layers append their counters
 ) -> tuple[jax.Array, list | None]:
     """Logits [B, T, V] (+ updated KV caches when provided).
+
+    ``kv_caches`` holds one entry per layer, of the layer's own kind: a
+    linear-attention layer's is its (state, convolution tail). Rows
+    whose tokens are not all real (bucket padding, a slot that is not
+    decoding) say so in ``valid_len``, which such layers and the routed
+    experts honour; ``moe_stats`` collects each routed layer's counters
+    (moe.STATS).
 
     ``return_hidden=True`` returns the post-norm hidden states [B, T, H]
     instead of logits — prefill consumes logits at ONE position per row,
@@ -499,7 +618,7 @@ def forward(
     if attn_fn is None:
         attn_fn = attention
 
-    cos, sin = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_tables(positions, cfg.rotary_dim, cfg.rope_theta)
     x = params["embed_tokens"][tokens]
     if cfg.scale_embeddings:
         # Gemma scales embeddings into the residual stream; the HF
@@ -515,7 +634,7 @@ def forward(
             layer, x, cos, sin, attn_mask, cfg,
             kv_cache=cache, cache_offset=cache_offset, attn_fn=attn_fn,
             tp_axis=tp_axis, tp_size=tp_size, block_tables=block_tables,
-            wq_gspmd=wq_gspmd,
+            wq_gspmd=wq_gspmd, valid_len=valid_len, moe_stats=moe_stats,
         )
         if new_caches is not None:
             new_caches.append(cache)

@@ -247,12 +247,63 @@ def _serving_metrics(registry: Registry):
             "Model steps run by decode and verify windows (sum of K)",
             registry=registry,
         ),
+        "decode_row_steps": Counter(
+            "kubeinfer_engine_decode_row_steps_total",
+            "Rows that were decoding, summed over the model steps of "
+            "decode and verify windows (over decode_steps_total: the "
+            "mean live rows of a step)",
+            registry=registry,
+        ),
         "prefill_tokens": Counter(
             "kubeinfer_engine_prefill_tokens_total",
             "Prompt tokens of admitted requests: computed (run through "
             "the prefill programs), cached (taken from the radix "
             "cache), padded (bucket padding computed for nothing)",
             labels=("kind",), registry=registry,
+        ),
+        "prefix_refused": Counter(
+            "kubeinfer_prefix_cache_refused_total",
+            "Admissions whose prefix lookup was refused: "
+            "recurrent_state (the model has linear-attention layers, "
+            "whose state the cached blocks do not carry)",
+            labels=("reason",), registry=registry,
+        ),
+        # routed experts (moe.STATS): summed on the device inside the
+        # step programs over every MoE call, read back with a decode
+        # window's tokens
+        "moe_routed_pairs": Counter(
+            "kubeinfer_moe_routed_pairs_total",
+            "(token, expert) pairs the router chose for real rows",
+            registry=registry,
+        ),
+        "moe_held_pairs": Counter(
+            "kubeinfer_moe_held_pairs_total",
+            "Routed pairs whose expert this replica holds (the rest "
+            "belong to other expert-parallel ranks and add nothing here)",
+            registry=registry,
+        ),
+        "moe_experts_reached": Counter(
+            "kubeinfer_moe_experts_reached_total",
+            "Held experts that at least one row reached, summed over "
+            "MoE calls (only these experts' weights are read)",
+            registry=registry,
+        ),
+        "moe_max_pairs": Counter(
+            "kubeinfer_moe_busiest_expert_pairs_total",
+            "Pairs of the busiest held expert, summed over MoE calls "
+            "(over held_pairs / held experts: the load imbalance)",
+            registry=registry,
+        ),
+        "moe_calls": Counter(
+            "kubeinfer_moe_calls_total",
+            "MoE calls (one per routed layer per model step)",
+            registry=registry,
+        ),
+        "recurrent_state_bytes": Gauge(
+            "kubeinfer_recurrent_state_bytes",
+            "Resident bytes of the linear-attention layers' per-slot "
+            "recurrent state and convolution tails",
+            registry=registry,
         ),
         "admission_wait": Histogram(
             "kubeinfer_engine_admission_wait_seconds",
@@ -786,6 +837,8 @@ class InferenceServer:
         self.metrics["kv_blocks_in_use"].set(stats["blocks_in_use"])
         self.metrics["kv_blocks_free"].set(stats["blocks_free"])
         self.metrics["kv_pool_bytes"].set(stats["pool_bytes"])
+        self.metrics["recurrent_state_bytes"].set(
+            self.continuous.recurrent_state_bytes)
         self.metrics["model_param_bytes"].set(
             self.continuous.model_param_bytes
         )
@@ -839,6 +892,7 @@ class InferenceServer:
                 ("migrated", "migrations"),
                 ("migration_chunks", "migration_chunks"),
                 ("decode_steps", "decode_steps"),
+                ("decode_row_steps", "decode_row_steps"),
             ):
                 delta = sched[key] - self._kv_last.get(key, 0)
                 self.metrics[name].inc(by=delta)
@@ -847,6 +901,8 @@ class InferenceServer:
                 ("dispatches", sched["dispatches"], PHASES),
                 ("prefill_tokens", sched["prefill_tokens"],
                  ("computed", "cached", "padded")),
+                ("prefix_refused", sched["prefix_refused"],
+                 ("recurrent_state",)),
             ):
                 for label in labels:
                     key = f"{name}.{label}"
@@ -855,6 +911,10 @@ class InferenceServer:
                         label, by=total - self._kv_last.get(key, 0)
                     )
                     self._kv_last[key] = total
+            for key, total in sched["moe"].items():
+                self.metrics["moe_" + key].inc(
+                    by=total - self._kv_last.get("moe." + key, 0))
+                self._kv_last["moe." + key] = total
             # a reader of deltas sums phases (decode + verify windows
             # per decode_steps_total): each needs its series at 0
             for phase in PHASES:
@@ -1398,6 +1458,12 @@ class InferenceServer:
                 # the streamed chain from this replica's /kv/blocks)
                 route_box["ext"] = {"migrated": dict(req.migrated)}
         else:
+            if self.engine.cfg.recurrent:
+                raise ValueError(
+                    "a model with linear-attention layers is served by "
+                    "the continuous batcher alone, and this request "
+                    "does not fit a slot of it"
+                )
             route_box["route"] = "engine"
             out = self.engine.generate(
                 [ids], max_new_tokens=max_tokens, eos_id=eos_id,
@@ -1632,6 +1698,16 @@ def main(argv: list[str] | None = None) -> int:
             log.info("--random-init: %r is not a preset; using 'tiny'",
                      args.model)
             cfg = PRESETS["tiny"]
+        try:
+            cfg.check_serving(
+                weight_dtype=args.weight_dtype, kv_dtype=args.kv_dtype,
+                tp=args.tensor_parallel_size,
+                sp=args.sequence_parallel_size,
+                speculation=bool(args.draft_model
+                                 or args.speculative_draft),
+            )
+        except ValueError as e:
+            raise SystemExit(str(e)) from None
         params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype,
                              weight_dtype=args.weight_dtype,
                              mesh=tp_mesh)

@@ -56,11 +56,14 @@ from kubeinfer_tpu.inference.flash_attention import (
     decode_attention_blocks_auto,
     decode_attention_blocks_q8_auto,
 )
+from kubeinfer_tpu.inference.gdn import init_gdn_state
 from kubeinfer_tpu.inference.kv_blocks import quantize_blocks
 from kubeinfer_tpu.inference.model import Params, forward
+from kubeinfer_tpu.inference.moe import STATS as MOE_STATS
 
 __all__ = [
-    "SlotState", "init_slot_state", "sample_rows", "step_forward",
+    "SlotState", "init_slot_state", "layer_caches", "split_layer_caches",
+    "add_moe_stats", "sample_rows", "step_forward",
     "decode_body", "decode_window", "decode_scan", "WINDOW_BUCKETS",
     "DraftState", "init_draft_state", "spec_accept", "verify_window",
 ]
@@ -95,9 +98,21 @@ class SlotState:
     quantizes just-filled slot-0 blocks into the pool
     (:func:`_commit_full_tails`). In bf16 mode all four are EMPTY
     lists — valid pytrees that keep every trace byte-identical to the
-    pre-quantization engine."""
+    pre-quantization engine.
 
-    caches_k: list[jax.Array]  # L x [num_blocks, block_size, n_kv, D]
+    What a layer caches follows its kind, and this class with
+    :func:`init_slot_state`, :func:`layer_caches` and
+    :func:`split_layer_caches` is the one place that says so. A
+    full-attention layer owns one entry of ``caches_k``/``caches_v``
+    (and of the int8 companions): pages, through the slot's table. A
+    linear-attention layer (gdn.py) owns one entry of ``gdn_state`` and
+    ``gdn_conv``: a fixed-size recurrent state per slot and the
+    convolution's last inputs, no pages; an admit starts them from zero
+    and rows that are not decoding leave them untouched. Models without
+    such layers have none of these leaves, and models without routed
+    experts no ``moe_stats``, so their programs are what they were."""
+
+    caches_k: list[jax.Array]  # per full layer [num_blocks, bs, n_kv, D]
     caches_v: list[jax.Array]
     tables: jax.Array  # i32[B, max_blocks] pool indices, seq order
     last_token: jax.Array  # i32[B]
@@ -113,6 +128,12 @@ class SlotState:
     scales_v: list[jax.Array]
     tails_k: list[jax.Array]  # int8: L x [B, 2, bs, n_kv, D]; else []
     tails_v: list[jax.Array]
+    # per linear-attention layer; [] for models that have none
+    gdn_state: list[jax.Array] = dataclasses.field(default_factory=list)
+    gdn_conv: list[jax.Array] = dataclasses.field(default_factory=list)
+    # routed experts: [u32[len(moe.STATS)]] summed over every MoE call
+    # so far (wraps; the host reads differences), [] for dense models
+    moe_stats: list[jax.Array] = dataclasses.field(default_factory=list)
 
 
 jax.tree_util.register_dataclass(
@@ -120,7 +141,7 @@ jax.tree_util.register_dataclass(
     data_fields=["caches_k", "caches_v", "tables", "last_token", "offset",
                  "active", "temperature", "top_k", "top_p", "rep_penalty",
                  "seen", "rng", "scales_k", "scales_v", "tails_k",
-                 "tails_v"],
+                 "tails_v", "gdn_state", "gdn_conv", "moe_stats"],
     meta_fields=[],
 )
 
@@ -132,7 +153,9 @@ def init_slot_state(cfg: ModelConfig, n_slots: int, cache_len: int,
     (the historical layout — the name is the CLI axis, not the literal
     array dtype, so f32 test engines stay f32); ``"int8"`` stores int8
     pages + f32 scales and allocates the per-slot bf16 tails."""
-    L = cfg.num_hidden_layers
+    L = len(cfg.full_attention_layers)  # the layers that hold pages
+    gdn = [init_gdn_state(cfg, n_slots, dtype)
+           for i in range(cfg.num_hidden_layers) if cfg.layer_is_linear(i)]
     shape = (num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim)
     if kv_dtype == "int8":
         page_dt = jnp.int8
@@ -173,7 +196,39 @@ def init_slot_state(cfg: ModelConfig, n_slots: int, cache_len: int,
         # any-penalty-enabled)
         seen=jnp.zeros((n_slots, cfg.vocab_size), bool),
         rng=jnp.zeros((n_slots, 2), jnp.uint32),
+        gdn_state=[s for s, _ in gdn],
+        gdn_conv=[c for _, c in gdn],
+        moe_stats=[jnp.zeros((len(MOE_STATS),), jnp.uint32)]
+        if cfg.num_local_experts > 0 else [],
     )
+
+
+def layer_caches(state: SlotState, cfg: ModelConfig, full=None,
+                 linear=None) -> list:
+    """One cache entry per layer for forward(), each of its layer's
+    kind: by default the pool's pages (:func:`_zip_kv`) and the slots'
+    (state, convolution tail) pairs; the admit paths pass one row's
+    views instead."""
+    full = iter(_zip_kv(state) if full is None else full)
+    linear = iter(zip(state.gdn_state, state.gdn_conv)
+                  if linear is None else linear)
+    return [next(linear) if cfg.layer_is_linear(i) else next(full)
+            for i in range(cfg.num_hidden_layers)]
+
+
+def split_layer_caches(cfg: ModelConfig, caches: list):
+    """forward()'s updated entries back into (full layers' entries,
+    linear layers' (state, tail) pairs)."""
+    linear = [c for i, c in enumerate(caches) if cfg.layer_is_linear(i)]
+    full = [c for i, c in enumerate(caches) if not cfg.layer_is_linear(i)]
+    return full, linear
+
+
+def add_moe_stats(state: SlotState, stats: list) -> dict:
+    """The SlotState field with this program's MoE calls added."""
+    if not stats:
+        return {}
+    return {"moe_stats": [state.moe_stats[0] + sum(stats)]}
 
 
 def sample_rows(
@@ -236,6 +291,8 @@ def step_forward(
     cache_len: int,  # logical per-row cache width S
     block_tables: jax.Array | None = None,  # i32[B, max_blocks] = paged
     sharded: bool = False,  # caller jits under a tp-sharded EngineLayout
+    active: jax.Array | None = None,  # bool[B] rows that are decoding
+    moe_stats: list | None = None,
 ):
     """One decode token's forward pass for a length-ragged batch;
     returns (logits f32[B, V], updated kv_caches).
@@ -266,7 +323,7 @@ def step_forward(
         # (trace-static pytree structure), routed to the dequant-in-
         # kernel readers; decoder_layer scattered the step's K/V into
         # the tail, never the quantized pages
-        quantized = bool(kv_caches) and isinstance(kv_caches[0][0], tuple)
+        quantized = any(isinstance(c[0], tuple) for c in kv_caches)
 
         def attn_fn(q, kc, vc, m):
             if quantized:
@@ -288,6 +345,8 @@ def step_forward(
         block_tables=block_tables,
         attn_fn=attn_fn,
         wq_gspmd=sharded,
+        valid_len=None if active is None else active.astype(jnp.int32),
+        moe_stats=moe_stats,
     )
     return logits[:, 0], kv_caches
 
@@ -361,11 +420,19 @@ def decode_body(
     block_size = state.caches_k[0].shape[1]
     S = state.tables.shape[1] * block_size  # logical per-row cache width
     quantized = state.caches_k[0].dtype == jnp.int8
+    stats: list = []
+    # recurrent layers and routed experts must know which rows decode
+    # (a row mid-prefill holds state this step may not touch); the
+    # other families' trace takes no such operand
+    rows = state.active if (cfg.recurrent or cfg.num_local_experts) \
+        else None
     logits, caches = step_forward(
         params, cfg, state.last_token, state.offset,
-        _zip_kv(state), S,
+        layer_caches(state, cfg), S,
         block_tables=state.tables, sharded=sharded,
+        active=rows, moe_stats=stats,
     )
+    caches, linear = split_layer_caches(cfg, caches)
     # counter offset+1: admit folds prompt_len (== first decode offset),
     # so folding the bare offset here would reuse the admit-time gumbel
     # draw and systematically double the first sampled token
@@ -419,6 +486,9 @@ def decode_body(
             state.seen,
         ),
         **kv_fields,
+        gdn_state=[c[0] for c in linear],
+        gdn_conv=[c[1] for c in linear],
+        **add_moe_stats(state, stats),
     )
     return new_state, jnp.where(keep, nxt, -1)
 

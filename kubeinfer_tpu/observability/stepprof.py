@@ -70,7 +70,7 @@ STEP_PROGRAMS = ("jit__admit_slot", "jit__prefill_chunk",
 KERNEL_NAMES = (
     "quant_matmul", "decode_attention", "decode_attention_blocks",
     "decode_attention_blocks_q8", "flash_attention",
-    "flash_attention_ragged",
+    "flash_attention_ragged", "moe_grouped_matmul", "gdn_decode_step",
 )
 
 # host spans (:func:`annotate`), all on the scheduler thread and inside
@@ -197,10 +197,12 @@ class StepProfiler:
         self._compile_count = 0
         self._last_kv = (-1, -1)
         # monotonic totals, never lost to the ring's wrap: dispatches by
-        # phase, and the model steps decode/verify windows ran (sum of
-        # their ``steps``)
+        # phase, the model steps decode/verify windows ran (sum of
+        # their ``steps``), and the rows that were decoding in them
+        # (sum of ``live_rows * steps``)
         self._dispatches: dict[str, int] = {}
         self._decode_steps = 0
+        self._decode_row_steps = 0
 
     # -- writer (scheduler thread) -----------------------------------------
 
@@ -236,6 +238,7 @@ class StepProfiler:
             self._dispatches[phase] = self._dispatches.get(phase, 0) + 1
             if phase in ("decode", "verify"):
                 self._decode_steps += steps
+                self._decode_row_steps += live_rows * steps
         return rec
 
     # -- readers (any thread) ----------------------------------------------
@@ -250,6 +253,14 @@ class StepProfiler:
         started; the server turns them into counters by delta."""
         with self._lock:
             return dict(self._dispatches), self._decode_steps
+
+    @property
+    def decode_row_steps(self) -> int:
+        """Rows decoding, summed over the model steps of decode and
+        verify windows: over ``totals()``'s decode steps, the mean live
+        rows a step."""
+        with self._lock:
+            return self._decode_row_steps
 
     def snapshot(self, since_seq: int = -1) -> list[StepRecord]:
         """Records with ``seq > since_seq`` (all, by default). The

@@ -69,6 +69,7 @@ BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 QWEN2_7B = (28, 4, 128)
 GEMMA_2B = (8, 1, 256)
 LLAMA3_8B = (32, 8, 128)
+QWEN3_NEXT = (16, 2, 256)  # its full-attention layers: group 8
 
 
 def _ragged(heads, T, S):
@@ -91,16 +92,33 @@ _B, _BS, _MB = 8, 128, 32
 _NB = 1 + 2 * _B * _MB
 
 
-def _decode_blocks(T):
+def _decode_blocks(T, heads=QWEN2_7B, B=_B, NB=_NB):
     from kubeinfer_tpu.inference.flash_attention import (
         decode_attention_blocks,
     )
 
-    nq, nkv, D = QWEN2_7B
-    pool = ((_NB, _BS, nkv, D), BF16)
+    nq, nkv, D = heads
+    pool = ((NB, _BS, nkv, D), BF16)
     return decode_attention_blocks, (
-        ((_B, T, nq, D), BF16), pool, pool, ((_B, _MB), I32), ((_B,), I32),
+        ((B, T, nq, D), BF16), pool, pool, ((B, _MB), I32), ((B,), I32),
     )
+
+
+def _moe_grouped_matmul(M, K, N, E=128):
+    """One projection of Qwen3-Next's held experts: M sorted (row,
+    expert) pairs, 128 of the 512 experts."""
+    from kubeinfer_tpu.inference.moe import grouped_matmul
+
+    return grouped_matmul, (
+        ((M, K), BF16), ((E, K, N), BF16), ((E,), I32))
+
+
+def _gdn_decode_step(B, H=32, D=128):
+    from kubeinfer_tpu.inference.gdn import gdn_decode_step
+
+    vec = ((B, H, D), F32)
+    return gdn_decode_step, (
+        vec, vec, vec, ((B, H), F32), ((B, H), F32), ((B, H, D, D), F32))
 
 
 def _decode_blocks_q8(T):
@@ -152,6 +170,14 @@ CASES = {
     "ragged-llama-3-8b": lambda: _ragged(LLAMA3_8B, 512, 4096),
     "decode-blocks-T1": lambda: _decode_blocks(1),
     "decode-blocks-T5": lambda: _decode_blocks(5),
+    # qwen3-next-80b at --batch-slots 64: one block of pool a slot
+    "decode-blocks-qwen3-next": lambda: _decode_blocks(
+        1, QWEN3_NEXT, B=64, NB=1 + 64 * _MB),
+    "moe-gmm-decode-640x2048x512": lambda: _moe_grouped_matmul(
+        640, 2048, 512),
+    "moe-gmm-prefill-2560x512x2048": lambda: _moe_grouped_matmul(
+        2560, 512, 2048),
+    "gdn-decode-step-64": lambda: _gdn_decode_step(64),
     "decode-blocks-q8-T1": lambda: _decode_blocks_q8(1),
     "decode-blocks-q8-T5": lambda: _decode_blocks_q8(5),
     "quant-matmul-8x3584x18944": lambda: _quant_matmul(8, 3584, 18944),
@@ -208,6 +234,8 @@ def _named_kernel(name):
             qT, kvT, kvT, ((1, 128, 256), jnp.bool_))),
         "flash_attention_ragged": (fa.flash_attention_ragged, (
             qT, kvT, kvT, ((), I32), ((1,), I32))),
+        "moe_grouped_matmul": _moe_grouped_matmul(128, 128, 128, E=4),
+        "gdn_decode_step": _gdn_decode_step(2, H=8),
     }[name]
 
 

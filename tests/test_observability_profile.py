@@ -305,6 +305,15 @@ def test_decode_steps_is_the_sum_of_the_decode_records(counted):
     assert counted["sched"]["decode_steps"] >= 4 + 2 + 1
 
 
+def test_decode_row_steps_is_the_rows_of_each_step(counted):
+    dec = [r for r in counted["records"] if r.phase == "decode"]
+    assert counted["sched"]["decode_row_steps"] == sum(
+        r.live_rows * r.steps for r in dec)
+    # never fewer rows than tokens that reached a request
+    assert counted["sched"]["decode_row_steps"] >= sum(
+        r.live_tokens for r in dec)
+
+
 def test_dispatches_count_the_records_of_each_phase(counted):
     by_phase = {}
     for r in counted["records"]:
@@ -380,6 +389,7 @@ def _complete(srv, body: dict) -> dict:
 
 ZERO_SERIES = [
     'kubeinfer_engine_decode_steps_total 0',
+    'kubeinfer_engine_decode_row_steps_total 0',
     *(f'kubeinfer_engine_dispatches_total{{phase="{p}"}} 0'
       for p in stepprof.PHASES),
     *(f'kubeinfer_engine_prefill_tokens_total{{kind="{k}"}} 0'
