@@ -335,7 +335,18 @@ MATMUL_TOL = 2.0 ** -7
 KERNEL_T = 512  # prefill chunk: Engine's PREFILL_CHUNK, the server's 4 blocks
 KERNEL_POOL_BLOCKS = 1 + 2 * N_SLOTS * (MAX_LEN // BLOCK)  # the server's pool
 VERIFY_T = 5  # speculative verify window: --speculation-depth 4, plus one
-MATMULS = ((8, "H", "F"), (512, "H", "F"), (8, "F", "H"), (512, "F", "H"))
+# every quant_matmul call the int8 step programs issue: decode's 8 live
+# rows, the 64/128/256/512-row admit buckets (64: a question behind a
+# cached document) and the 512-row prefill chunk, each times gate/up,
+# down, q/o and k/v at MODEL's extents
+MATMUL_ROWS = (8, 64, 128, 256, 512)
+
+
+def matmul_projections() -> dict[str, tuple[int, int]]:
+    w = WIDTHS
+    q_dim, kv_dim = w["n_q"] * w["D"], w["n_kv"] * w["D"]
+    return {"gate_up": (w["H"], w["F"]), "down": (w["F"], w["H"]),
+            "q_o": (w["H"], q_dim), "k_v": (w["H"], kv_dim)}
 
 
 def _bits_differ(np, a, b) -> int:
@@ -453,27 +464,39 @@ def phase_kernels(args) -> dict:
                                    f"{name} vs dense f32"),
         }
 
-    # fused dequant-matmul at the decode and prefill-chunk row counts
-    for rows, a, b in MATMULS:
-        K, N = w[a], w[b]
-        x = normal((rows, K))
+    # fused dequant-matmul at every shape the step programs issue, each at
+    # the tiles quant_matmul_tiles picks for it. The rows are the leading
+    # rows of one draw, so a row's bits can be held equal across row
+    # counts: the same prompt rides in an admit bucket, a chunk, a window
+    for proj, (K, N) in matmul_projections().items():
+        x_all = normal((max(MATMUL_ROWS), K))
         qw = jax.random.randint(next(keys), (K, N), -127, 128, jnp.int8)
         scale = jax.random.uniform(next(keys), (N,), jnp.float32,
                                    1e-3, 3e-3)
-        got = jax.jit(wq.quant_matmul)(x, qw, scale)
-        twin = jax.jit(wq.quant_matmul_jnp)(x, qw, scale)
-        name = f"quant_matmul {rows}x{K}x{N}"
-        differ = _bits_differ(np, got, twin)
-        check(differ == 0,
-              f"{name}: kernel is not bit-identical to quant_matmul_jnp "
-              f"({differ} of {rows * N} elements differ)")
-        with jax.default_matmul_precision("highest"):
-            ref = (x.astype(jnp.float32) @ qw.astype(jnp.float32)) * scale
-        results[name] = {
-            "twin_bit_identical": True,
-            "vs_dense_f32": _close(np, got, ref, MATMUL_TOL,
-                                   f"{name} vs dense f32"),
-        }
+        first = None
+        for rows in MATMUL_ROWS:
+            x = x_all[:rows]
+            got = wq.quant_matmul(x, qw, scale)
+            twin = jax.jit(wq.quant_matmul_jnp)(x, qw, scale)
+            name = f"quant_matmul {proj} {rows}x{K}x{N}"
+            differ = _bits_differ(np, got, twin)
+            check(differ == 0,
+                  f"{name}: kernel is not bit-identical to quant_matmul_jnp "
+                  f"({differ} of {rows * N} elements differ)")
+            first = got if first is None else first
+            moved = _bits_differ(np, got[:MATMUL_ROWS[0]], first)
+            check(moved == 0,
+                  f"{name}: {moved} elements of the first {MATMUL_ROWS[0]} "
+                  f"rows differ from the {MATMUL_ROWS[0]}-row call's: a "
+                  "row's sum depends on the rows beside it")
+            with jax.default_matmul_precision("highest"):
+                ref = (x.astype(jnp.float32) @ qw.astype(jnp.float32)) * scale
+            results[name] = {
+                "tiles": list(wq.quant_matmul_tiles(rows, K, N, 2)[:3]),
+                "twin_bit_identical": True, "rows_bit_identical": True,
+                "vs_dense_f32": _close(np, got, ref, MATMUL_TOL,
+                                       f"{name} vs dense f32"),
+            }
 
     lowered = _lower_engine_steps(jax, jnp)
     return {
@@ -530,12 +553,12 @@ def _lower_engine_steps(jax, jnp) -> dict:
     programs = {
         "decode_window": (
             decode_window.lower(params, state, cfg, 1),
-            {"_decode_blocks_kernel", "_quant_matmul_kernel"}),
+            {"decode_attention_blocks", "quant_matmul"}),
         "_prefill_chunk": (
             batching._prefill_chunk.lower(
                 params, state, arg((1, 4 * BLOCK), i32), arg((), i32),
                 cfg, *row),
-            {"_quant_matmul_kernel"}),
+            {"quant_matmul"}),
         "_admit_slot": (
             batching._admit_slot.lower(
                 params, state, arg((1, BLOCK), i32), arg((), i32),
@@ -543,7 +566,7 @@ def _lower_engine_steps(jax, jnp) -> dict:
                 arg((), f32), arg((), i32), arg((), f32), arg((), f32),
                 arg((2,), jnp.uint32),
                 arg((1, cfg.vocab_size), jnp.bool_)),
-            {"_quant_matmul_kernel"}),
+            {"quant_matmul"}),
     }
     out = {"param_bytes": int(param_bytes)}
     for name, (low, want) in programs.items():

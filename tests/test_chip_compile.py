@@ -136,6 +136,15 @@ def _decode_blocks_q8(T):
     )
 
 
+# every call the qwen2-7b step programs issue: decode's 8 live rows, the
+# 64/128/256/512-row admit buckets and prefill chunk x gate/up, down,
+# q/o, k/v. Each compiles at the tiles weight_quant.quant_matmul_tiles
+# picks for it, under the VMEM limit the kernel states.
+QUANT_MATMUL_ROWS = (8, 64, 128, 256, 512)
+QUANT_MATMUL_PROJECTIONS = (
+    (3584, 18944), (18944, 3584), (3584, 3584), (3584, 512))
+
+
 def _quant_matmul(M, K, N):
     from kubeinfer_tpu.inference.weight_quant import quant_matmul
 
@@ -180,8 +189,11 @@ CASES = {
     "gdn-decode-step-64": lambda: _gdn_decode_step(64),
     "decode-blocks-q8-T1": lambda: _decode_blocks_q8(1),
     "decode-blocks-q8-T5": lambda: _decode_blocks_q8(5),
-    "quant-matmul-8x3584x18944": lambda: _quant_matmul(8, 3584, 18944),
-    "quant-matmul-512x18944x3584": lambda: _quant_matmul(512, 18944, 3584),
+    **{
+        f"quant-matmul-{M}x{K}x{N}":
+            functools.partial(_quant_matmul, M, K, N)
+        for M in QUANT_MATMUL_ROWS for K, N in QUANT_MATMUL_PROJECTIONS
+    },
     "route-pick-256x128": lambda: _route_pick(256, 128),
     "solve-mega-12288x1024": lambda: _packed_solve(
         12288, 1024, "jax-greedy", "mega"),
