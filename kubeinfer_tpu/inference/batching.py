@@ -784,22 +784,20 @@ class ContinuousEngine:
     scheduler thread admits requests into free slots and steps the
     shared decode batch.
 
-    Cold-compile stall (ADVICE r5): when ``_place`` forms a draft
-    group, ``speculative.start_group`` runs ON the scheduler thread,
-    and the first group with a new ``(B, prompt bucket, cache_len)``
-    shape pays the full jit compile there — potentially tens of
+    Cold-compile stall (ADVICE r5): the first prefill of each prompt
+    bucket (and the first decode/verify window of each horizon) pays
+    its full jit compile ON the scheduler thread — potentially tens of
     seconds on which EVERY in-flight slot request also stalls (no
-    decode steps run while the scheduler is inside the compile). The
-    same applies to the first prefill of each prompt bucket on the
-    slot path. Deployments that care should call ``prewarm_spec()``
-    (and/or issue a throwaway generate per bucket) before serving
-    traffic; the per-shape compile caches are process-global, so one
-    warmup covers all subsequent groups of that shape.
+    decode steps run while the scheduler is inside the compile).
+    Deployments that care should issue a throwaway generate per bucket
+    before serving traffic; the per-shape compile caches are
+    process-global, so one warmup covers all later requests of that
+    shape.
     """
 
     def __init__(self, params: Params, cfg: ModelConfig,
                  n_slots: int = 8, cache_len: int = 1024,
-                 speculative=None, block_size: int | None = None,
+                 block_size: int | None = None,
                  num_blocks: int | None = None,
                  prefill_chunk_blocks: int = 0,
                  preemption: PreemptionPolicy | None = None,
@@ -828,7 +826,7 @@ class ContinuousEngine:
         cfg.check_serving(
             weight_dtype=weight_dtype, kv_dtype=kv_dtype,
             tp=self.layout.tp,
-            speculation=speculative is not None or spec_draft is not None,
+            speculation=spec_draft is not None,
         )
         # weight precision axis (ISSUE 20), kv_dtype's load-time
         # mirror: "int8" accepts either pre-quantized params (the
@@ -1024,36 +1022,18 @@ class ContinuousEngine:
         # host copy of each slot's owned block ids (shared + fresh), in
         # table order — what retire returns to the pool
         self._slot_blocks: list[list[int]] = [[] for _ in range(n_slots)]
-        # Optional SpeculativeEngine: draft-eligible requests decode
-        # through an INCREMENTAL draft group (speculative.start_group /
-        # step_group) that interleaves with busy slots one round at a
-        # time — r4 verdict item 5: the old route only engaged when the
-        # batcher was fully idle, so spec_served stayed flat exactly
-        # when throughput mattered. One live group at a time; greedy
-        # requests keep token-identity, sampled requests keep the exact
-        # target distribution with PER-ROW warp knobs (speculative.py);
-        # repetition-penalty requests stay on slots (the penalty
-        # reshapes p from state the verifier window cannot see).
-        self.speculative = speculative
-        self.spec_served = 0  # telemetry: requests served via the draft
-        self.spec_accepted = 0  # telemetry: accepted draft tokens, all groups
-        # (member requests, live group handle) — at most one in flight
-        self._spec_group: tuple[list[_Request], object] | None = None
         # arrival-order heads popped from the queue but not yet
-        # placeable (no free slot / not group-joinable); served before
-        # the queue. A deque (oldest first) rather than a single slot:
-        # preemption interleaves parked readmits with fresh arrivals,
-        # so two unplaced requests can be in hand at once.
+        # placeable (no free slot); served before the queue. A deque
+        # (oldest first) rather than a single slot: preemption
+        # interleaves parked readmits with fresh arrivals, so two
+        # unplaced requests can be in hand at once.
         self._holdover: "collections.deque[_Request]" = collections.deque()
-        # Speculative VERIFY path (distinct from the draft-GROUP path
-        # above — this one rides the paged batch itself): a draft model
-        # proposes spec_k tokens per live row and ONE fused
-        # stepper.verify_window dispatch scores/accepts them. When set,
-        # it supersedes the group route entirely (_place gates on it):
-        # the verify window serves every slot request, warm or resumed,
-        # with or without repetition penalty, and composes with
-        # preemption and tensor parallelism — everything the
-        # solo-dense group path cannot.
+        # Speculative decoding rides the paged batch itself: a draft
+        # model proposes spec_k tokens per live row and ONE fused
+        # stepper.verify_window dispatch scores/accepts them. The
+        # window serves every slot request, warm or resumed, with or
+        # without repetition penalty, and composes with preemption and
+        # tensor parallelism.
         self.spec_draft = spec_draft
         self.spec_k = spec_k
         self._dparams: Params | None = None
@@ -1692,36 +1672,6 @@ class ContinuousEngine:
             "cache_summary": self._radix.summary(),
         }
 
-    def prewarm_spec(self, group_sizes: tuple[int, ...] = (1,),
-                     prompt_len: int = 8, max_new_tokens: int = 8,
-                     sampled: bool = False) -> int:
-        """Compile the draft-group path for the given group sizes BEFORE
-        traffic arrives (class docstring: the first group of a new shape
-        otherwise compiles on the scheduler thread, stalling every
-        in-flight slot request behind it). Runs ``start_group`` plus one
-        ``step_group`` round per size on dummy prompts and discards the
-        results; the jit caches are process-global, so one warm covers
-        all later groups of that ``(B, bucket, cache_len)`` shape.
-        ``sampled=True`` warms the sampled trace instead of the greedy
-        one (the greedy/sampled split is a static trace flag — they
-        compile separately). Call before serving; returns the number of
-        shapes warmed. No-op without a speculative engine."""
-        if self.speculative is None:
-            return 0
-        warmed = 0
-        for b in group_sizes:
-            b = int(b)
-            if b < 1 or not self.speculative.fits(prompt_len, max_new_tokens):
-                continue
-            g = self.speculative.start_group(
-                [[1] * prompt_len] * b,
-                max_new_tokens=max_new_tokens,
-                temperatures=0.7 if sampled else 0.0,
-            )
-            self.speculative.step_group(g)
-            warmed += 1
-        return warmed
-
     def start(self) -> "ContinuousEngine":
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="continuous-batcher"
@@ -1746,16 +1696,16 @@ class ContinuousEngine:
             self._note("fail", req=req.rid, reason="engine stopped")
             req.done.set()
         # the join above can expire behind a long jit compile, leaving
-        # the scheduler live — and the scheduler may PUBLISH a slot or
-        # group after this sweep ran (admission was mid-compile during
+        # the scheduler live — and the scheduler may PUBLISH a slot
+        # after this sweep ran (admission was mid-compile during
         # the snapshot). The loop's epilogue runs the same sweep from
         # the scheduler thread when it observes _stop, so whichever
         # side sees the published state last releases the waiters.
         self._fail_inflight()
 
     def _fail_inflight(self) -> None:
-        """Fail over every published in-flight request (slots, live
-        group, holdover, parked rows, chunked prefills) — shared by
+        """Fail over every published in-flight request (slots,
+        holdover, parked rows, chunked prefills) — shared by
         stop() and the scheduler loop's epilogue; all handoff fields
         are swapped under the lock."""
         failed = 0
@@ -1777,7 +1727,6 @@ class ContinuousEngine:
             # slot sweep below releases them; only the task list needs
             # clearing so a mid-compile chunk cannot be re-dispatched
             self._prefills.clear()
-            group, self._spec_group = self._spec_group, None
             for slot, req in enumerate(self._slot_req):
                 if req is not None:
                     self._slot_req[slot] = None
@@ -1788,7 +1737,7 @@ class ContinuousEngine:
                     failed += 1
         for holdover in held:
             holdover.failed = "engine stopped before the request was served"
-            # lint: allow[protocol-order] consecutive sweeps fail DISTINCT request populations (slots, holdover, parked, staged, group); each chain sees exactly one fail
+            # lint: allow[protocol-order] consecutive sweeps fail DISTINCT request populations (slots, holdover, parked, staged); each chain sees exactly one fail
             self._note("fail", req=holdover.rid, reason="stopped unserved")
             holdover.done.set()
             failed += 1
@@ -1811,14 +1760,6 @@ class ContinuousEngine:
         for task in imports:
             task.reason = "stopped"
             task.done.set()
-        if group is not None:
-            for req in group[0]:
-                req.failed = "engine stopped mid-generation"
-                # lint: allow[protocol-order] distinct population from the staged sweep above
-                self._note("fail", req=req.rid,
-                           reason="stopped mid spec-group")
-                req.done.set()
-                failed += 1
         if failed:
             # auto-dump the flight recorder: the post-mortem needs the
             # scheduler's last decisions in the log stream even if the
@@ -2551,11 +2492,10 @@ class ContinuousEngine:
             self._migrate_slot(slot, req, cursor)
             return
         # nothing to advance: drained once every population is empty
-        # (spec groups and prefills finish through their own steppers)
+        # (prefills finish through their own stepper)
         with self._lock:
             live = (
                 any(r is not None for r in self._slot_req)
-                or self._spec_group is not None
                 or bool(self._holdover) or bool(self._parked)
                 or bool(self._prefills) or bool(self._staged)
             )
@@ -2626,191 +2566,13 @@ class ContinuousEngine:
             # older work
             self._admit_pending()
 
-    def _drain_spec_group(
-        self, first: "_Request"
-    ) -> tuple[list["_Request"], "_Request | None"]:
-        """Drain queued requests into ``first``'s draft batch.
-
-        The speculative engine is batched (per-row cache offsets carry
-        rows advancing at different speeds), so concurrent requests need
-        not lose the draft speedup to each other (r3 verdict item 8).
-        Joinable: same MODE as the head (greedy with greedy, sampled
-        with sampled — the rejection correction and warp knobs are
-        per-row, r4 item 5, but the greedy/sampled split is a static
-        trace flag), no repetition penalty, same eos id, equal SEED for
-        sampled joins, and every member still fits the draft cache at
-        the group's max_new high-water mark. The seed requirement is a
-        reproducibility guard (ADVICE r5): the group's key stream is
-        seeded by the HEAD request only (``_start_spec_group`` passes
-        ``first.seed``), so a sampled request joining under a different
-        seed would silently sample from the head's stream — same prompt
-        + seed + params would then give different tokens depending on
-        what else was in flight. Greedy rows draw no noise, so their
-        seeds are irrelevant. The first non-joinable request is
-        returned as a holdover for slot admission — draining must not
-        reorder it behind later arrivals.
-        """
-        group = [first]
-        gmax = first.max_new
-        head_sampled = first.temperature > 0
-        holdover: _Request | None = None
-        while len(group) < self.n_slots:
-            try:
-                nxt = self._queue.get_nowait()
-            except queue.Empty:
-                break
-            if nxt.cancelled.is_set():
-                nxt.done.set()
-                continue
-            cand_max = max(gmax, nxt.max_new)
-            if (
-                nxt.rep_penalty == 1.0
-                and (nxt.temperature > 0) == head_sampled
-                and (not head_sampled or nxt.seed == first.seed)
-                and nxt.eos_id == first.eos_id
-                and all(
-                    self.speculative.fits(len(m.prompt), cand_max)
-                    for m in (*group, nxt)
-                )
-            ):
-                group.append(nxt)
-                gmax = cand_max
-            else:
-                holdover = nxt
-                break
-        return group, holdover
-
-    def _start_spec_group(self, group: list["_Request"]) -> None:
-        """Prefill a draft group (scheduler-thread context). Rows ride
-        the group's max_new and are truncated back to their own
-        request's budget on the way out (a row past its own budget
-        costs ride-along rounds, never wrong tokens). Sampled members
-        keep their own temperature/top_k/top_p rows; the group key
-        stream is seeded by the head request."""
-        first = group[0]
-        try:
-            g = self.speculative.start_group(
-                [r.prompt for r in group],
-                max_new_tokens=max(r.max_new for r in group),
-                eos_id=first.eos_id,
-                temperatures=[r.temperature for r in group],
-                top_ks=[r.top_k for r in group],
-                top_ps=[r.top_p for r in group],
-                seed=first.seed,
-            )
-        except Exception as e:  # noqa: BLE001 — waiters must be released
-            for r in group:
-                r.failed = f"speculative decode failed: {e}"
-                r.done.set()
-            return
-        with self._lock:
-            self._spec_group = (group, g)
-
-    def _step_spec_group(self) -> None:
-        """One speculation round for the live group; emit and retire on
-        completion. Bounded work per call, so busy slots and a live
-        group interleave at step granularity. Device work runs outside
-        the lock; the completion handoff re-checks identity under it
-        (stop() may have failed the members meanwhile)."""
-        with self._lock:
-            live = self._spec_group
-        if live is None:
-            return
-        reqs, g = live
-        if all(r.cancelled.is_set() for r in reqs):
-            # nobody will read any row: drop the group instead of
-            # drafting to the budget (a timed-out burst must not pin
-            # the draft path on dead work). A PARTIALLY cancelled
-            # group keeps riding — rows are interleaved in one batch
-            # and the survivors' tokens are still wanted.
-            with self._lock:
-                if self._spec_group is live:
-                    self._spec_group = None
-            for r in reqs:
-                r.done.set()
-            return
-        spec_t0 = tracing.now()
-        try:
-            done = self.speculative.step_group(g)
-            out = self.speculative.finish_group(g) if done else None
-        except Exception as e:  # noqa: BLE001
-            with self._lock:
-                if self._spec_group is live:
-                    self._spec_group = None
-            for r in reqs:
-                r.failed = f"speculative decode failed: {e}"
-                r.done.set()
-            return
-        # group tokens only become countable when the group finishes
-        # (finish_group copies the accepted rows out); intermediate
-        # rounds record zero live tokens but still carry the dispatch
-        # duration, so the step histogram sees every device round
-        emitted = (
-            sum(min(int(out.lengths[b]), r.max_new)
-                for b, r in enumerate(reqs))
-            if out is not None else 0
-        )
-        self.profiler.record(
-            "spec", bucket=len(reqs), live_rows=len(reqs),
-            live_tokens=emitted, padded_tokens=0,
-            start=spec_t0, end=tracing.now(),
-        )
-        if out is None:
-            return
-        with self._lock:
-            if self._spec_group is not live:
-                return  # stop() already failed the members
-            self._spec_group = None
-        # per-group carry, NOT engine.last_stats: the bulk speculative
-        # route mutates that shared field from HTTP threads concurrently
-        self.spec_accepted += g.accepted_drafts
-        fin = tracing.now()
-        for b, r in enumerate(reqs):
-            n = min(int(out.lengths[b]), r.max_new)
-            r.out_tokens.extend(out.tokens[b, :n].tolist())
-            self.spec_served += 1
-            r.t_done = fin
-            # draft groups have no slot timeline; one span covers the
-            # whole group residency so spec traffic still shows up in
-            # the trace (attrs mark it for the breakdown readers)
-            _TRACER.record_span(
-                "engine.spec_group", start=r.t_submit, end=fin,
-                parent=r.trace_parent, tokens=n, group_size=len(reqs),
-            )
-            r.done.set()
-
     def _place(self, req: "_Request") -> bool:
-        """Route one pending request: draft group if eligible and none
-        is live, else a free slot; False stashes it back at the front
-        of the holdover (all slots busy). Resumed requests never join
-        draft groups — their generated prefix lives in the paged pool,
-        which the speculative engine cannot see. Caller must NOT hold
-        the lock."""
+        """Admit one pending request into a free slot; False stashes it
+        back at the front of the holdover (all slots busy, or pool
+        backpressure). Caller must NOT hold the lock."""
         if req.cancelled.is_set():
             req.t_done = tracing.now()
             req.done.set()
-            return True
-        resumed = bool(req.out_tokens)
-        with self._lock:
-            group_free = self._spec_group is None
-        if (
-            self.speculative is not None
-            # paged verify windows supersede the dense side-car: when a
-            # draft model is wired into the batch itself, every request
-            # should ride the paged path (the group would steal exactly
-            # the prompts that speculate best)
-            and self.spec_draft is None
-            and group_free
-            and not resumed
-            and req.rep_penalty == 1.0
-            and self.speculative.fits(len(req.prompt), req.max_new)
-        ):
-            group, holdover = self._drain_spec_group(req)
-            self._start_spec_group(group)
-            if holdover is not None:
-                with self._lock:
-                    # freshly drained from the queue = newest pending
-                    self._holdover.append(holdover)
             return True
         with self._lock:
             for slot in range(self.n_slots):
@@ -2902,10 +2664,7 @@ class ContinuousEngine:
         next window boundary. No device dispatch and no readback
         happens here, so the whole pass runs while the device chews
         the window; the jit admits (which may compile for tens of
-        seconds) stay at the boundary. Spec-eligible heads are pushed
-        back for ``_place`` — forming a draft group dispatches device
-        work immediately, which must not race the window's donated
-        state."""
+        seconds) stay at the boundary."""
         with annotate("engine.plan_admissions") as span:
             staged = 0
             while True:
@@ -2924,22 +2683,6 @@ class ContinuousEngine:
                     req.t_done = tracing.now()
                     req.done.set()
                     continue
-                resumed = bool(req.out_tokens)
-                with self._lock:
-                    group_free = self._spec_group is None
-                if (
-                    self.speculative is not None
-                    and self.spec_draft is None
-                    and group_free
-                    and not resumed
-                    and req.rep_penalty == 1.0
-                    and self.speculative.fits(len(req.prompt), req.max_new)
-                ):
-                    with self._lock:
-                        # head of the line again: _place routes it at the
-                        # boundary (it was the oldest pending request)
-                        self._holdover.appendleft(req)
-                    break
                 with self._lock:
                     tokens = req.prompt + req.out_tokens
                     kv_plan = self._plan_kv(
@@ -2971,8 +2714,8 @@ class ContinuousEngine:
         """Decode-window horizon for this pass, from the static bucket
         set (one compiled shape each). K collapses to 1 whenever the
         host has competing work — pending admissions, chunked prefills,
-        a live draft group, a cancelled row — so fused windows never
-        starve admission, prefill interleave, or retirement; otherwise
+        a cancelled row — so fused windows never starve admission,
+        prefill interleave, or retirement; otherwise
         K is the largest bucket no row can overshoot (min remaining
         budget), so ``max_new`` is never crossed mid-window, every
         retirement lands exactly at a window boundary, and every write
@@ -3009,8 +2752,7 @@ class ContinuousEngine:
         self._step_import()
         with self._lock:
             busy = any(r is not None for r in self._slot_req)
-            idle = (not busy and self._spec_group is None
-                    and not self._parked)
+            idle = not busy and not self._parked
             have_holdover = bool(self._holdover)
         if idle:
             if self._draining:
@@ -3035,12 +2777,12 @@ class ContinuousEngine:
             return
         # live work: non-blocking admissions, a preemption check
         # when the waiters' SLO pressure warrants one, then one
-        # step of each active machine — the decode batch, at most
-        # ONE prefill chunk, and a live draft group advance in
-        # lockstep per loop pass, so none starves the others. This
-        # interleave is the tentpole: prefill stopped being one
-        # atomic dispatch and became schedulable work competing
-        # with decode under an explicit policy.
+        # step of each active machine — the decode batch and at most
+        # ONE prefill chunk advance in lockstep per loop pass, so
+        # neither starves the other. This interleave is the tentpole:
+        # prefill stopped being one atomic dispatch and became
+        # schedulable work competing with decode under an explicit
+        # policy.
         if self._draining:
             # admission and preemption stand down; the drain pass
             # streams one chunk (or finalizes one caught-up slot)
@@ -3079,7 +2821,6 @@ class ContinuousEngine:
             host_work = (
                 bool(self._holdover) or bool(self._parked)
                 or bool(self._prefills)
-                or self._spec_group is not None
                 or any(
                     r is not None and r.cancelled.is_set()
                     for r in self._slot_req
@@ -3282,4 +3023,3 @@ class ContinuousEngine:
                 start=step_t0, end=step_t, steps=k,
             )
         self._step_prefill()  # at most one chunk per pass
-        self._step_spec_group()  # locked no-op when no group is live

@@ -260,10 +260,12 @@ def chunked_prefill(
     the KV caches; returns (caches, next_logits) where next_logits[b] is
     the logits at row b's LAST real prompt position.
 
-    Shared by the per-request engine and the speculative decoder — the
-    chunking (peak attention memory O(chunk * cache_len), one trace for
-    any prompt bucket) and the last-real-position logit selection must
-    behave identically everywhere. Trace-time cost only: callers jit.
+    The per-request engine's prefill, and the single-chip reference
+    the sequence-parallel KV hand-off is held to
+    (tests/test_inference_sp_serving.py): peak attention memory is
+    O(chunk * cache_len), one trace serves any prompt bucket, and the
+    logits come from each row's last real position. Trace-time cost
+    only: callers jit.
     """
     B, T = prompt.shape
     cache_len = caches[0][0].shape[1]
@@ -389,18 +391,15 @@ def prepare_prompts(
     prompts: list[list[int]],
     max_new_tokens: int,
     max_cache_len: int,
-    slack: int = 0,
 ):
-    """Host-side prompt prep shared by the engines: validate, bucket,
-    pad, and size the KV cache. ``slack`` is extra cache capacity beyond
-    prompt+new (the speculative decoder writes up to k+1 entries past
-    the frontier). Returns (padded i32[B, T_bucket], lens i32[B],
-    cache_len)."""
+    """Host-side prompt prep shared by the per-request and the
+    sequence-parallel engine: validate, bucket, pad, and size the KV
+    cache. Returns (padded i32[B, T_bucket], lens i32[B], cache_len)."""
     lens = np.asarray([len(p) for p in prompts], np.int32)
     if lens.min() < 1:
         raise ValueError("empty prompt")
     T = _bucket(int(lens.max()))
-    need = int(lens.max()) + max_new_tokens + slack
+    need = int(lens.max()) + max_new_tokens
     if need > max_cache_len:
         raise ValueError(
             f"prompt+new tokens ({need}) exceed the model's context "

@@ -25,11 +25,10 @@ except it really generates.
 Reproducibility contract: a completion is a deterministic function of
 (prompt, seed, sampling params) — independent of what else is in
 flight. Greedy requests are trivially so; sampled requests hold it
-because the batcher's draft groups only join sampled requests with
-EQUAL seeds (batching.py _drain_spec_group — the group key stream is
-seeded by the head request, so a different-seed join would silently
-sample from the head's stream), and slot-path sampling keys are
-per-slot, derived from each request's own seed.
+because slot-path sampling keys are per-slot, derived from each
+request's own seed and folded with the token's output index — with or
+without a ``--draft-model`` (stepper.spec_accept emits the target's own
+draws).
 """
 
 from __future__ import annotations
@@ -111,17 +110,8 @@ def _serving_metrics(registry: Registry):
                      30.0),
             labels=("route",), registry=registry,
         ),
-        # speculation effectiveness (r4 verdict weak #3 follow-through:
-        # "spec_served stays flat exactly when throughput matters" must
-        # be OBSERVABLE, not just fixed) — refreshed from the batcher's
-        # counters at scrape time
-        "spec_served": Gauge(
-            "kubeinfer_inference_spec_served_requests",
-            "Requests served via speculative draft groups",
-            registry=registry,
-        ),
-        # paged speculative decoding (batching.py verify windows, gated
-        # by --speculative-draft): the engine's monotonic ints convert
+        # paged speculative decoding (batching.py verify windows, on
+        # with --draft-model): the engine's monotonic ints convert
         # to Prometheus counters by delta at scrape time under the kv
         # lock, same discipline as the radix counters below; the ratio
         # gauge is cumulative accepted/proposed so dashboards read the
@@ -482,13 +472,12 @@ def _serving_metrics(registry: Registry):
 class InferenceServer:
     def __init__(self, engine, model_id: str, tokenizer=None,
                  host: str = "127.0.0.1", port: int = 8000,
-                 continuous=None, speculative=None, sp=None,
+                 continuous=None, sp=None,
                  tls_cert: str = "", tls_key: str = "",
                  token: str = "", slo=None,
                  kv_export_budget_mb: float = 0.0) -> None:
         self.engine = engine
         self.continuous = continuous  # ContinuousEngine | None
-        self.speculative = speculative  # SpeculativeEngine | None
         self.sp = sp  # SPEngine | None (sequence-parallel long prompts)
         self.model_id = model_id
         self.tokenizer = tokenizer
@@ -812,7 +801,7 @@ class InferenceServer:
         by delta under _kv_lock so concurrent scrapes never double-add).
         SLO gauges refresh even without a continuous engine — every
         route feeds _observe_breakdown, so the burn rates are
-        meaningful for per-request/speculative-only servers too."""
+        meaningful for per-request-only servers too."""
         import jax
 
         for d in jax.local_devices():
@@ -832,7 +821,6 @@ class InferenceServer:
             self.metrics["slo_budget"].set(name, obj["budget_remaining"])
         if self.continuous is None:
             return
-        self.metrics["spec_served"].set(self.continuous.spec_served)
         stats = self.continuous.kv_cache_stats()
         self.metrics["kv_blocks_in_use"].set(stats["blocks_in_use"])
         self.metrics["kv_blocks_free"].set(stats["blocks_free"])
@@ -1052,8 +1040,6 @@ class InferenceServer:
             if req.t_first:
                 ttft = max(0.0, req.t_first - req.t_submit)
                 decode_s = max(0.0, end - req.t_first)
-            else:  # draft-group path: no per-token timeline
-                ttft = max(0.0, end - req.t_submit)
         self.metrics["ttft"].observe(route, ttft)
         self.slo.observe("ttft", ttft)
         if decode_s is not None and n_out > 1:
@@ -1291,8 +1277,8 @@ class InferenceServer:
             # exported pages are bit-identical to what a local prefill
             # would have produced — and park the wire-encoded KV in the
             # export cache for a decode replica to pull. This branch
-            # outranks every other route: sp/speculative/engine have no
-            # exportable paged pool.
+            # outranks every other route: sp/engine have no exportable
+            # paged pool.
             if not (
                 self.continuous is not None
                 and self.continuous.fits(len(ids), 0)
@@ -1342,7 +1328,7 @@ class InferenceServer:
             # resume MUST ride the continuous batcher: only its
             # position-folded key schedule reproduces the source's
             # sampling stream mid-generation (park/readmit invariant);
-            # the sp/speculative/per-request engines would re-draw
+            # the sp/per-request engines would re-draw
             if not (
                 self.continuous is not None
                 and self.continuous.fits(len(ids), max_tokens)
@@ -1394,40 +1380,6 @@ class InferenceServer:
                 temperature=temperature, seed=seed,
                 top_k=top_k, top_p=top_p,
                 repetition_penalty=rep_penalty,
-            )
-            gen = out.tokens[0, : out.lengths[0]].tolist()
-        elif (
-            self.speculative is not None
-            # repetition penalty reshapes the target distribution per
-            # step using generated-token state the speculative verifier
-            # does not track; such requests take the normal paths
-            and rep_penalty == 1.0
-            and self.speculative.fits(len(ids), max_tokens)
-            # when a batcher exists and the request fits it, the batcher
-            # OWNS draft-eligible traffic: its incremental groups batch
-            # concurrent eligible requests and interleave with busy
-            # slots (r4 verdict item 5), strictly better than this
-            # serialized per-request bulk path — which remains the
-            # route when there is no batcher, or for requests only the
-            # draft cache can hold
-            and not (
-                self.continuous is not None
-                and self.continuous.speculative is not None
-                and self.continuous.fits(len(ids), max_tokens)
-            )
-        ):
-            # a configured draft model routes requests through
-            # speculative decoding: greedy requests via argmax
-            # acceptance (token-identical to vanilla greedy), sampled
-            # requests via the rejection-sampling correction (exactly
-            # the target's sampling distribution). Requests within the
-            # target's context but beyond the k+1 speculation slack
-            # fall through rather than fail.
-            route_box["route"] = "speculative"
-            out = self.speculative.generate(
-                [ids], max_new_tokens=max_tokens, eos_id=eos_id,
-                temperature=temperature, seed=seed,
-                top_k=top_k, top_p=top_p,
             )
             gen = out.tokens[0, : out.lengths[0]].tolist()
         elif (
@@ -1611,22 +1563,15 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--draft-model", default="",
                    help="draft model dir (HF snapshot) or preset name "
                         "(with --random-init) enabling speculative "
-                        "decoding for greedy requests; must share the "
-                        "target's vocabulary")
+                        "decoding: the draft runs inside the continuous "
+                        "batcher's paged batch, K-query verify windows "
+                        "with accept/rollback at the window boundary, "
+                        "for every slot-served request (greedy, sampled "
+                        "and repetition-penalty alike; tokens are the "
+                        "target's own). Must share the target's "
+                        "vocabulary; needs --batch-slots > 0")
     p.add_argument("--speculation-depth", type=int, default=4,
-                   help="draft tokens proposed per verification round")
-    p.add_argument("--speculative-draft", action="store_true",
-                   help="run the --draft-model inside the continuous "
-                        "batcher's paged batch: K-query verify windows "
-                        "with accept/rollback at the window boundary "
-                        "(supersedes the dense draft-group side-car for "
-                        "slot-served requests; greedy and sampled alike)")
-    p.add_argument("--prewarm-spec", default="",
-                   help="comma-separated draft-group sizes to compile "
-                        "before serving (e.g. '1,2,4'); without it the "
-                        "first group of each size compiles on the "
-                        "scheduler thread, stalling in-flight requests "
-                        "(batching.py ContinuousEngine docstring)")
+                   help="draft tokens proposed per verify window")
     p.add_argument("--tls-cert-file", default="",
                    help="serve completions over TLS (PEM cert; key via "
                         "--tls-key-file)")
@@ -1654,6 +1599,12 @@ def main(argv: list[str] | None = None) -> int:
                         "names: ttft, tpot, queue_wait. Default: loose "
                         "built-ins (observability/slo.py)")
     args = p.parse_args(argv)
+    if args.draft_model and args.batch_slots <= 0:
+        raise SystemExit(
+            "--draft-model requires the continuous batcher "
+            "(--batch-slots > 0): the draft proposes inside the paged "
+            "batch's verify windows, and no other route speculates"
+        )
     # lint: allow[log-discipline] main() is the process entrypoint and owns root logging config
     logging.basicConfig(level=logging.INFO)
     if args.span_sample_every != 1:
@@ -1703,8 +1654,7 @@ def main(argv: list[str] | None = None) -> int:
                 weight_dtype=args.weight_dtype, kv_dtype=args.kv_dtype,
                 tp=args.tensor_parallel_size,
                 sp=args.sequence_parallel_size,
-                speculation=bool(args.draft_model
-                                 or args.speculative_draft),
+                speculation=bool(args.draft_model),
             )
         except ValueError as e:
             raise SystemExit(str(e)) from None
@@ -1741,16 +1691,11 @@ def main(argv: list[str] | None = None) -> int:
         )
 
     engine = Engine(params, cfg, max_cache_len=max_cache)
-    if args.speculative_draft and not args.draft_model:
-        raise SystemExit(
-            "--speculative-draft requires --draft-model (the paged "
-            "verify windows run the same draft weights)"
-        )
-    speculative = None
-    dparams = dcfg = None
+    spec_draft = None
     if args.draft_model:
-        from kubeinfer_tpu.inference.speculative import SpeculativeEngine
-
+        # placed by ContinuousEngine (replicated under tp: the draft is
+        # small, and replication spares it the target's head-count
+        # divisibility)
         if args.random_init:
             dcfg = PRESETS.get(args.draft_model)
             if dcfg is None:
@@ -1763,17 +1708,7 @@ def main(argv: list[str] | None = None) -> int:
             from kubeinfer_tpu.inference.weights import load_pretrained
 
             dparams, dcfg = load_pretrained(args.draft_model, dtype=dtype)
-        if args.tensor_parallel_size > 1:
-            # the draft shards onto the same tp mesh as the target —
-            # left unsharded, GSPMD would replicate its weights on every
-            # device (tp x the intended draft HBM footprint)
-            from kubeinfer_tpu.inference.sharding import shard_params
-
-            dparams = shard_params(dparams, mesh, dcfg)
-        speculative = SpeculativeEngine(
-            params, cfg, dparams, dcfg, k=args.speculation_depth,
-            max_cache_len=max_cache,
-        )
+        spec_draft = (dparams, dcfg)
     continuous = None
     if args.batch_slots > 0:
         from kubeinfer_tpu.inference.batching import (
@@ -1796,13 +1731,10 @@ def main(argv: list[str] | None = None) -> int:
         continuous = ContinuousEngine(
             params, cfg, n_slots=args.batch_slots,
             cache_len=min(max_cache, 4096),
-            speculative=speculative,
             prefill_chunk_blocks=args.prefill_chunk_blocks,
             preemption=preemption,
             layout=layout,
-            spec_draft=(
-                (dparams, dcfg) if args.speculative_draft else None
-            ),
+            spec_draft=spec_draft,
             spec_k=args.speculation_depth,
             kv_dtype=args.kv_dtype,
             weight_dtype=args.weight_dtype,
@@ -1810,14 +1742,6 @@ def main(argv: list[str] | None = None) -> int:
             migration_chunk_blocks=args.migration_chunk_blocks,
             flight_capacity=args.flight_capacity,
         )
-        if args.prewarm_spec and speculative is not None:
-            sizes = tuple(
-                int(s) for s in args.prewarm_spec.split(",") if s.strip()
-            )
-            t0 = time.monotonic()
-            n = continuous.prewarm_spec(group_sizes=sizes)
-            log.info("prewarmed %d draft-group shapes in %.1fs",
-                     n, time.monotonic() - t0)
         continuous.start()
     debug_token = ""
     if args.debug_token_file:
@@ -1833,7 +1757,7 @@ def main(argv: list[str] | None = None) -> int:
     srv = InferenceServer(
         engine, model_id=args.model, tokenizer=tokenizer,
         host=args.host, port=args.port, continuous=continuous,
-        speculative=speculative, sp=sp_engine,
+        sp=sp_engine,
         tls_cert=args.tls_cert_file, tls_key=args.tls_key_file,
         token=debug_token, slo=slo,
         kv_export_budget_mb=args.kv_export_budget_mb,

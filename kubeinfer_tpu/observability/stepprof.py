@@ -47,9 +47,9 @@ __all__ = [
     "STEP_PROGRAMS", "KERNEL_NAMES", "HOST_SPANS", "PROFILE_NAMES",
 ]
 
-# every ``phase`` a record can carry (the batcher records paged verify
-# windows as "verify", the dense draft-group side-car as "spec")
-PHASES = ("prefill", "decode", "verify", "spec", "chunk")
+# every ``phase`` a record can carry ("verify" is a paged speculative
+# verify window)
+PHASES = ("prefill", "decode", "verify", "chunk")
 
 # --- the names the device profile carries ----------------------------------
 # The contract the benchmark's patterns are written against: renaming
@@ -131,7 +131,7 @@ class StepRecord:
 
     seq: int  # monotonic dispatch index (scrape cursors key on it)
     t: float  # dispatch end, tracing-clock seconds
-    phase: str  # "prefill" | "decode" | "verify" | "spec" | "chunk"
+    phase: str  # "prefill" | "decode" | "verify" | "chunk"
     bucket: int  # compiled-shape knob: suffix bucket / batch width
     live_rows: int  # rows carrying a real request
     n_slots: int  # batch capacity the dispatch was padded to

@@ -64,7 +64,7 @@ _COMPONENT_BY_PREFIX = (
     (("test_solver", "test_problem", "test_backends", "test_sharded",
       "test_distributed", "test_multiprocess"),
      "solver"),
-    (("test_inference", "test_flash", "test_sampling", "test_speculative"),
+    (("test_inference", "test_flash", "test_sampling"),
      "inference"),
     # resilience layer + fault-injection scenarios (`make test-chaos`);
     # pure controlplane work — runs under the same virtual CPU mesh

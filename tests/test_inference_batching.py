@@ -150,215 +150,24 @@ class TestMixedLengthSingleDispatch:
             assert out.tokens[i, : out.lengths[i]].tolist() == s, i
 
 
-class TestSpeculativeRouting:
-    """The batcher's idle path routes through the draft; busy periods
-    keep slot batching (VERDICT r2 item 3: speculative inside the
-    continuous batcher for the single-slot case)."""
-
-    def _engines(self, n_slots=2, count_batches=None):
-        cfg = PRESETS["tiny"]
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        from kubeinfer_tpu.inference.speculative import SpeculativeEngine
-
-        spec = SpeculativeEngine(params, cfg, params, cfg, k=2)
-        if count_batches is not None:
-            # record the batch size of every draft group so tests can pin
-            # GROUPING itself, not just per-request outcomes (the batcher
-            # runs groups incrementally via start_group, never generate)
-            inner = spec.start_group
-
-            def counting(prompts, **kw):
-                count_batches.append(len(prompts))
-                return inner(prompts, **kw)
-
-            spec.start_group = counting
-        eng = ContinuousEngine(
-            params, cfg, n_slots=n_slots, cache_len=256, speculative=spec
-        )
-        return eng, params, cfg
-
-    def test_idle_request_served_speculatively(self):
-        eng, params, cfg = self._engines()
-        eng.start()
-        try:
-            toks = eng.generate([5, 6, 7], max_new_tokens=6)
-            assert eng.spec_served == 1
-            # token identity with the per-request engine (greedy)
-            from kubeinfer_tpu.inference.engine import Engine
-
-            ref = Engine(params, cfg).generate([[5, 6, 7]], max_new_tokens=6)
-            assert toks == ref.tokens[0, : ref.lengths[0]].tolist()
-        finally:
-            eng.stop()
-
-    def test_greedy_burst_batches_through_draft(self):
-        """r3 verdict item 8: concurrent greedy requests must NOT lose
-        the draft speedup to each other — a pre-queued burst drains into
-        ONE batched draft call (spec_served counts every member), with
-        per-request token identity against the plain engine, including
-        ragged max_new budgets (rows ride the group max and truncate)."""
-        batches: list[int] = []
-        eng, params, cfg = self._engines(n_slots=4, count_batches=batches)
-        prompts = [[5, 6, 7], [2, 3], [9, 1, 4, 8]]
-        budgets = [6, 3, 5]
-        reqs = [
-            eng.submit(p, max_new_tokens=m)
-            for p, m in zip(prompts, budgets)
-        ]
-        eng.start()
-        try:
-            for r in reqs:
-                assert r.done.wait(120)
-                assert not r.failed
-            assert eng.spec_served == 3
-            # the batching itself: one draft call served all three (a
-            # regression to singleton groups would still pass the
-            # per-request asserts below)
-            assert batches == [3], batches
-            from kubeinfer_tpu.inference.engine import Engine
-
-            ref = Engine(params, cfg)
-            for r, p, m in zip(reqs, prompts, budgets):
-                out = ref.generate([p], max_new_tokens=m)
-                assert r.out_tokens == out.tokens[
-                    0, : out.lengths[0]
-                ].tolist(), (p, m)
-        finally:
-            eng.stop()
-
-    def test_mixed_burst_holdover_goes_to_slots(self):
-        """Draining stops at the first non-joinable request (queue order
-        must not be violated): the greedy prefix rides the draft in one
-        batch, the repetition-penalty HOLDOVER (popped from the queue
-        but not joinable) is admitted to a slot, not dropped. n_slots=4
-        so the drain hits the holdover before the group-size cap."""
-        batches: list[int] = []
-        eng, _, _ = self._engines(n_slots=4, count_batches=batches)
-        g1 = eng.submit([5, 6], max_new_tokens=4)
-        g2 = eng.submit([7, 8], max_new_tokens=4)
-        rp = eng.submit([4, 5], max_new_tokens=4, repetition_penalty=1.3)
-        eng.start()
-        try:
-            for r in (g1, g2, rp):
-                assert r.done.wait(120)
-                assert not r.failed
-            assert eng.spec_served == 2
-            assert batches == [2], batches
-            assert len(rp.out_tokens) == 4
-        finally:
-            eng.stop()
-
-    def test_sampled_burst_batches_through_draft(self):
-        """r4 verdict item 5: sampled requests batch into one draft
-        group too — the warp knobs (temperature/top_k/top_p) are
-        per-row, so heterogeneous sampled arrivals no longer forfeit
-        speculation to each other. Distribution exactness of the
-        per-row correction is pinned in test_speculative; here the
-        GROUPING is the contract. Seeds must MATCH: the group's key
-        stream is seeded by the head request, so a join with a
-        different seed would silently drop the joiner's seed (PR 1
-        reproducibility guard)."""
-        batches: list[int] = []
-        eng, _, _ = self._engines(n_slots=4, count_batches=batches)
-        reqs = [
-            eng.submit([2, 3], max_new_tokens=4,
-                       temperature=0.6 + 0.2 * i, seed=7)
-            for i in range(3)
-        ]
-        eng.start()
-        try:
-            for r in reqs:
-                assert r.done.wait(120)
-                assert not r.failed
-                assert len(r.out_tokens) == 4
-            assert eng.spec_served == 3
-            assert batches == [3], batches
-        finally:
-            eng.stop()
-
-    def test_sampled_mismatched_seeds_do_not_join(self):
-        """The other half of the reproducibility guard: a sampled
-        request whose seed differs from the group head is NOT joinable
-        (it would sample from the head's key stream, making its output
-        depend on concurrent traffic). The drain stops at it, the head
-        rides the draft alone, and the holdover lands on a slot — same
-        mechanics as the repetition-penalty holdover above."""
-        batches: list[int] = []
-        eng, _, _ = self._engines(n_slots=4, count_batches=batches)
-        head = eng.submit([2, 3], max_new_tokens=4, temperature=0.7, seed=1)
-        other = eng.submit([2, 3], max_new_tokens=4, temperature=0.7, seed=2)
-        eng.start()
-        try:
-            for r in (head, other):
-                assert r.done.wait(120)
-                assert not r.failed
-                assert len(r.out_tokens) == 4
-            assert eng.spec_served == 1
-            assert batches == [1], batches
-        finally:
-            eng.stop()
-
-    def test_spec_group_survives_sustained_slot_load(self):
-        """r4 verdict item 5 (the load half): with slots continuously
-        BUSY on a repetition-penalty request, draft-eligible arrivals
-        must still ride speculation — the incremental group interleaves
-        with slot decoding instead of waiting for full idleness.
-        Greedy members keep token identity under the interleave."""
-        eng, params, cfg = self._engines(n_slots=2)
-        # a long rep-penalty request occupies a slot for the whole test
-        pinned = eng.submit([4, 5], max_new_tokens=48,
-                            repetition_penalty=1.3)
-        eng.start()
-        try:
-            import time
-
-            deadline = time.time() + 120
-            while not eng.spec_served and time.time() < deadline:
-                # greedy arrivals while the slot request is mid-decode
-                r = eng.submit([5, 6, 7], max_new_tokens=4)
-                assert r.done.wait(120)
-                assert not r.failed
-                if len(pinned.out_tokens) >= 48:
-                    break  # pinned finished before a group formed
-            assert eng.spec_served > 0, (
-                "speculation never engaged while a slot was busy"
-            )
-            from kubeinfer_tpu.inference.engine import Engine
-
-            ref = Engine(params, cfg).generate([[5, 6, 7]], max_new_tokens=4)
-            assert r.out_tokens == ref.tokens[0, : ref.lengths[0]].tolist()
-            assert pinned.done.wait(120)
-            assert not pinned.failed
-        finally:
-            eng.stop()
-
-    def test_repetition_penalty_skips_speculative(self):
-        eng, _, _ = self._engines()
-        eng.start()
-        try:
-            toks = eng.generate([4, 5], max_new_tokens=4,
-                                repetition_penalty=1.3)
-            assert len(toks) == 4
-            assert eng.spec_served == 0
-        finally:
-            eng.stop()
-
-
 class TestSpecTelemetry:
     def test_spec_counters_accumulate(self):
-        """spec_served counts members; spec_accepted accumulates the
-        groups' accepted draft tokens (a self-draft accepts ~all)."""
+        """The verify path's monotonic counters: proposed and accepted
+        draft tokens grow with traffic (a self-draft accepts all)."""
         cfg = PRESETS["tiny"]
         params = init_params(cfg, jax.random.PRNGKey(0))
-        from kubeinfer_tpu.inference.speculative import SpeculativeEngine
-
-        spec = SpeculativeEngine(params, cfg, params, cfg, k=2)
         eng = ContinuousEngine(
-            params, cfg, n_slots=2, cache_len=256, speculative=spec
+            params, cfg, n_slots=2, cache_len=256,
+            spec_draft=(params, cfg), spec_k=2,
         ).start()
         try:
             eng.generate([5, 6, 7], max_new_tokens=8)
-            assert eng.spec_served == 1
-            assert eng.spec_accepted > 0  # self-draft: high acceptance
+            first = eng.scheduler_stats()
+            eng.generate([5, 6, 7], max_new_tokens=8)
+            second = eng.scheduler_stats()
         finally:
             eng.stop()
+        assert first["spec_draft_tokens"] > 0
+        assert first["spec_accepted_tokens"] == first["spec_draft_tokens"]
+        assert second["spec_draft_tokens"] > first["spec_draft_tokens"]
+        assert second["spec_rollbacks"] == 0

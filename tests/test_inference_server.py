@@ -10,6 +10,7 @@ changes.
 from __future__ import annotations
 
 import json
+import re
 import sys
 import time
 import urllib.error
@@ -20,6 +21,7 @@ import pytest
 
 from kubeinfer_tpu.agent.runtime import RuntimeConfig
 from kubeinfer_tpu.inference import PRESETS, init_params
+from kubeinfer_tpu.inference import server as server_mod
 from kubeinfer_tpu.inference.engine import Engine
 from kubeinfer_tpu.inference.server import InferenceServer
 
@@ -157,63 +159,84 @@ class TestRuntimeLauncherIntegration:
             RuntimeConfig.from_env({"RUNTIME_KIND": "tgi"})
 
 
-class TestSpeculativeServing:
+class TestDraftModelServing:
+    """``--draft-model``: the draft runs inside the paged batch, so a
+    request takes route ``continuous`` and keeps the tokens the same
+    server gives without a draft."""
+
     @pytest.fixture(scope="class")
-    def spec_server(self):
-        from kubeinfer_tpu.inference.speculative import SpeculativeEngine
+    def pair(self):
+        from kubeinfer_tpu.inference.batching import ContinuousEngine
 
         params = init_params(TINY, jax.random.PRNGKey(0))
-        engine = Engine(params, TINY)
-        # self-draft: acceptance 1.0, output must equal vanilla greedy
-        spec = SpeculativeEngine(params, TINY, params, TINY, k=3)
-        srv = InferenceServer(
-            engine, model_id="tiny-spec", port=0, speculative=spec
-        ).start()
-        yield srv, engine
-        srv.stop()
+        dparams = init_params(TINY, jax.random.PRNGKey(1))
+        engines, servers = [], []
+        for spec_draft in (None, (dparams, TINY)):
+            cont = ContinuousEngine(
+                params, TINY, n_slots=2, cache_len=64, block_size=8,
+                spec_draft=spec_draft, spec_k=3,
+            ).start()
+            engines.append(cont)
+            servers.append(InferenceServer(
+                Engine(params, TINY), model_id="tiny", port=0,
+                continuous=cont,
+            ))
+        yield servers
+        for cont in engines:
+            cont.stop()
 
-    def test_greedy_request_routes_through_speculation(self, spec_server):
-        srv, engine = spec_server
-        body = {"prompt": [5, 6, 7], "max_tokens": 8, "temperature": 0.0}
-        code, resp = post(
-            f"http://127.0.0.1:{srv.port}/v1/completions", body
-        )
-        assert code == 200
-        ref = engine.generate([[5, 6, 7]], max_new_tokens=8)
-        assert resp["choices"][0]["tokens"] == ref.tokens[0].tolist()
-        # the speculative path actually ran (stats recorded)
-        assert srv.speculative.last_stats["rounds"] >= 1
+    @pytest.mark.parametrize("extra", [
+        {},
+        {"temperature": 0.8, "seed": 7, "top_k": 20},
+        {"repetition_penalty": 1.3},
+    ], ids=["greedy", "sampled", "penalised"])
+    def test_tokens_match_the_draftless_server(self, pair, extra):
+        plain, drafted = pair
+        body = {"prompt": [5, 6, 7], "max_tokens": 8, **extra}
+        want = plain.complete(dict(body))
+        got = drafted.complete(dict(body))
+        assert got["kubeinfer"]["route"] == "continuous"
+        assert got["choices"][0]["tokens"] == want["choices"][0]["tokens"]
 
-    def test_sampled_request_takes_speculation(self, spec_server):
-        """Sampled requests ride the draft too since r3's rejection-
-        sampling correction (speculative.py) — only repetition-penalty
-        requests still skip it."""
-        srv, _ = spec_server
-        srv.speculative.last_stats = None
-        body = {
-            "prompt": [5, 6, 7], "max_tokens": 4,
-            "temperature": 0.8, "seed": 7,
-        }
-        code, resp = post(
-            f"http://127.0.0.1:{srv.port}/v1/completions", body
-        )
-        assert code == 200
-        assert len(resp["choices"][0]["tokens"]) >= 1
-        assert srv.speculative.last_stats is not None  # path taken
+    def test_request_moves_the_draft_counters(self, pair):
+        _, drafted = pair
+        drafted.complete({"prompt": [2, 3, 4], "max_tokens": 6})
+        drafted._refresh_spec_metrics()
+        out = drafted.registry.render().replace("'", '"')
+        assert 'route="continuous",outcome="ok"' in out
+        line = next(ln for ln in out.splitlines()
+                    if ln.startswith("kubeinfer_spec_draft_tokens_total "))
+        assert float(line.split()[1]) > 0
+        assert "kubeinfer_inference_spec" not in out
 
-    def test_repetition_penalty_skips_speculation(self, spec_server):
-        srv, _ = spec_server
-        srv.speculative.last_stats = None
-        body = {
-            "prompt": [5, 6, 7], "max_tokens": 4,
-            "repetition_penalty": 1.3,
-        }
-        code, resp = post(
-            f"http://127.0.0.1:{srv.port}/v1/completions", body
-        )
-        assert code == 200
-        assert len(resp["choices"][0]["tokens"]) >= 1
-        assert srv.speculative.last_stats is None  # path not taken
+
+class TestDraftModelFlags:
+    BASE = ["--model", "tiny", "--random-init", "--port", "0"]
+
+    def test_draft_model_needs_the_batcher(self):
+        with pytest.raises(SystemExit) as e:
+            server_mod.main(
+                [*self.BASE, "--draft-model", "tiny", "--batch-slots", "0"]
+            )
+        assert "--draft-model requires the continuous batcher" in str(
+            e.value)
+
+    @pytest.mark.parametrize("flag", [
+        ["--speculative-draft"], ["--prewarm-spec", "1,2"],
+    ], ids=["speculative-draft", "prewarm-spec"])
+    def test_removed_flags_are_unknown(self, capsys, flag):
+        with pytest.raises(SystemExit) as e:
+            server_mod.main([*self.BASE, "--draft-model", "tiny", *flag])
+        assert e.value.code == 2
+        assert "unrecognized arguments: " + flag[0] in capsys.readouterr().err
+
+    def test_help_lists_26_options(self, capsys):
+        with pytest.raises(SystemExit):
+            server_mod.main(["--help"])
+        flags = set(re.findall(r"^  (--[a-z-]+)", capsys.readouterr().out,
+                               flags=re.M))
+        assert len(flags) == 26, sorted(flags)
+        assert "--draft-model" in flags and "--speculation-depth" in flags
 
 
 class TestServingMetrics:
@@ -273,38 +296,3 @@ class TestServingMetrics:
             code = e.code
         assert code == 400
         assert server.metrics["requests"].value("invalid", "invalid") == before + 1
-
-
-class TestBatcherOwnsDraftTraffic:
-    def test_eligible_requests_route_to_batcher_groups(self):
-        """With a batcher configured, draft-eligible requests route
-        'continuous' and ride the batcher's incremental spec groups
-        (visible in the spec gauges) — the serialized bulk 'speculative'
-        route remains only for batcher-less servers (r4 verdict item 5:
-        speculation must survive load, and the batcher is where load
-        lives)."""
-        from kubeinfer_tpu.inference.batching import ContinuousEngine
-        from kubeinfer_tpu.inference.server import InferenceServer
-        from kubeinfer_tpu.inference.speculative import SpeculativeEngine
-
-        cfg = PRESETS["tiny"]
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        spec = SpeculativeEngine(params, cfg, params, cfg, k=2)
-        cont = ContinuousEngine(
-            params, cfg, n_slots=2, cache_len=256, speculative=spec
-        ).start()
-        srv = InferenceServer(
-            Engine(params, cfg), model_id="tiny", port=0,
-            continuous=cont, speculative=spec,
-        )
-        try:
-            resp = srv.complete({"prompt": [5, 6, 7], "max_tokens": 5})
-            assert resp["usage"]["completion_tokens"] == 5
-            m = srv.registry.render().replace("'", '"')
-            assert 'route="continuous",outcome="ok"' in m
-            assert 'route="speculative"' not in m
-            srv._refresh_spec_metrics()
-            out = srv.registry.render()
-            assert "spec_served_requests 1" in out, out.splitlines()[-4:]
-        finally:
-            cont.stop()

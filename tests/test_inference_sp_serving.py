@@ -166,25 +166,34 @@ class TestServerRoute:
 
 
 class TestRoutePrecedence:
-    def test_sp_outranks_speculative_for_long_prompts(self):
+    def test_sp_outranks_the_draft_for_long_prompts(self):
         """A long prompt must shard its prefill even when a draft is
-        configured — speculative prefills on one chip and would OOM at
-        truly long context; caught by the r3 server drive."""
-        from kubeinfer_tpu.inference.speculative import SpeculativeEngine
+        configured — a slot prefills on one chip and would OOM at truly
+        long context; a short one rides the batcher's verify windows."""
+        from kubeinfer_tpu.inference.batching import ContinuousEngine
 
         params = _params()
         mesh = make_inference_mesh(tp=1, sp=2)
+        cont = ContinuousEngine(
+            params, TINY, n_slots=2, cache_len=64, block_size=8,
+            spec_draft=(params, TINY), spec_k=2,
+        ).start()
         srv = InferenceServer(
             Engine(params, TINY), model_id="tiny", port=0,
             sp=SPEngine(params, TINY, mesh, min_prompt=32),
-            speculative=SpeculativeEngine(params, TINY, params, TINY, k=2),
+            continuous=cont,
         )
-        srv.complete({"prompt": _prompt(48), "max_tokens": 2})
-        m = srv.registry.render().replace("'", '"')
-        assert 'route="sp",outcome="ok"' in m
-        srv.complete({"prompt": _prompt(8), "max_tokens": 2})
-        m = srv.registry.render().replace("'", '"')
-        assert 'route="speculative",outcome="ok"' in m
+        try:
+            long_resp = srv.complete(
+                {"prompt": _prompt(48), "max_tokens": 2})
+            short_resp = srv.complete(
+                {"prompt": _prompt(8), "max_tokens": 4})
+            drafted = cont.scheduler_stats()["spec_draft_tokens"]
+        finally:
+            cont.stop()
+        assert long_resp["kubeinfer"]["route"] == "sp"
+        assert short_resp["kubeinfer"]["route"] == "continuous"
+        assert drafted > 0
 
 
 class TestSPTimesTP:

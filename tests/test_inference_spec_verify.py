@@ -18,6 +18,12 @@ without anyone being able to tell.
   every decode-phase advance routes through the fused verify dispatch
   (phase "verify", bucket == spec_k) and repeating a seen workload
   registers zero fresh first-seen shapes.
+
+- **The request surface.** The window is the only speculator
+  (``--draft-model``), so what the dense speculators' tests held moves
+  here: every depth x draft, EOS at each place in a window, budgets
+  below the depth, sampling knobs and seeds, repetition penalty, and a
+  mixed burst in one window — each against the plain engine.
 """
 
 from __future__ import annotations
@@ -63,6 +69,34 @@ def _engine(params, cfg=TINY, **kw):
     kw.setdefault("cache_len", 64)
     kw.setdefault("block_size", 8)
     return ContinuousEngine(params, cfg, **kw).start()
+
+
+def _bigram(params):
+    """0-layer draft (embed/norm/lm_head only): the prompt-lookup /
+    n-gram end of the draft spectrum, with no draft KV at all."""
+    dcfg = dataclasses.replace(TINY, num_hidden_layers=0)
+    return ({
+        "embed_tokens": params["embed_tokens"],
+        "layers": [],
+        "norm": params["norm"],
+        "lm_head": params["lm_head"],
+    }, dcfg)
+
+
+def _run(params, requests, **engine_kw):
+    """Each request dict through one engine, one at a time; returns
+    (token lists, scheduler_stats)."""
+    eng = _engine(params, **engine_kw)
+    try:
+        out = [eng.generate(**r) for r in requests]
+        return out, eng.scheduler_stats()
+    finally:
+        eng.stop()
+
+
+# the greedy stream of this prompt under ``params`` has twelve distinct
+# tokens, so an EOS chosen at index i stops the request at length i + 1
+DISTINCT_PROMPT = [38, 212, 167, 92]
 
 
 class TestVerifyIdentity:
@@ -125,13 +159,6 @@ class TestVerifyIdentity:
         uses): no draft KV exists, so the repair forward and propose
         scan run cache-free, and admit installs only ``prev``. Identity
         must hold like any other draft."""
-        dcfg = dataclasses.replace(TINY, num_hidden_layers=0)
-        dparams = {
-            "embed_tokens": params["embed_tokens"],
-            "layers": [],
-            "norm": params["norm"],
-            "lm_head": params["lm_head"],
-        }
         rng = np.random.default_rng(47)
         prompt = rng.integers(0, TINY.vocab_size, 8).tolist()
         ref = _engine(params, max_window=1)
@@ -140,7 +167,7 @@ class TestVerifyIdentity:
             want_s = ref.generate(prompt, max_new_tokens=8, **SAMPLED)
         finally:
             ref.stop()
-        eng = _engine(params, spec_draft=(dparams, dcfg), spec_k=4)
+        eng = _engine(params, spec_draft=_bigram(params), spec_k=4)
         try:
             got_g = eng.generate(prompt, max_new_tokens=8)
             got_s = eng.generate(prompt, max_new_tokens=8, **SAMPLED)
@@ -272,13 +299,155 @@ class TestVerifyShapes:
             eng2.stop()
         assert buckets == {2}
 
-    def test_constructor_validation(self, params, draft):
+    @pytest.mark.parametrize("vocab,spec_k,cache_len,match", [
+        (128, 4, 64, "vocabulary"),
+        (TINY.vocab_size, 0, 64, "spec_k must be >= 1"),
+        (TINY.vocab_size, 8, 8, "leaves no room"),
+    ], ids=["vocab-mismatch", "depth-below-one", "depth-over-cache"])
+    def test_constructor_validation(self, params, draft, vocab, spec_k,
+                                    cache_len, match):
         dparams, dcfg = draft
-        with pytest.raises(ValueError, match="spec_k must be >= 1"):
-            ContinuousEngine(params, TINY, n_slots=2, cache_len=64,
-                             block_size=8, spec_draft=draft, spec_k=0)
-        bad_cfg = dataclasses.replace(dcfg, vocab_size=128)
-        with pytest.raises(ValueError, match="vocabulary"):
-            ContinuousEngine(params, TINY, n_slots=2, cache_len=64,
-                             block_size=8,
-                             spec_draft=(dparams, bad_cfg))
+        dcfg = dataclasses.replace(dcfg, vocab_size=vocab)
+        with pytest.raises(ValueError, match=match):
+            ContinuousEngine(params, TINY, n_slots=2, cache_len=cache_len,
+                             block_size=8, spec_draft=(dparams, dcfg),
+                             spec_k=spec_k)
+
+
+class TestVerifyRequestSurface:
+    """What a request can ask of a ``--draft-model`` server, each held
+    to the plain engine's stream (the contract the dense speculators'
+    tests held before the window became the only speculator)."""
+
+    @pytest.fixture(scope="class")
+    def plain(self, params):
+        """Memoised plain-engine streams: one engine for the class."""
+        eng = _engine(params, max_window=1)
+        seen: dict[str, list[int]] = {}
+
+        def want(**req):
+            key = repr(sorted(req.items()))
+            if key not in seen:
+                seen[key] = eng.generate(**req)
+            return seen[key]
+
+        yield want
+        eng.stop()
+
+    @pytest.mark.parametrize("spec_k", [1, 2, 3, 4])
+    @pytest.mark.parametrize("kind", ["self", "random", "bigram"])
+    def test_greedy_identity_any_depth_any_draft(self, params, draft,
+                                                  plain, kind, spec_k):
+        spec_draft = {"self": (params, TINY), "random": draft,
+                      "bigram": _bigram(params)}[kind]
+        req = dict(prompt=[5, 4, 3, 2], max_new_tokens=10)
+        (got,), stats = _run(params, [req], spec_draft=spec_draft,
+                             spec_k=spec_k)
+        assert got == plain(**req)
+        assert stats["spec_draft_tokens"] > 0
+        if kind == "self":
+            assert stats["spec_rollbacks"] == 0
+
+    @pytest.mark.parametrize("at", [0, 3, 5],
+                             ids=["first-token", "mid-window",
+                                  "window-last"])
+    def test_eos_position_in_window(self, params, plain, at):
+        """Self-draft, spec_k=4: the admit emits token 0 and the first
+        window tokens 1..5, so index 3 is mid-window and index 5 the
+        window's last position."""
+        stream = plain(prompt=DISTINCT_PROMPT, max_new_tokens=12)
+        req = dict(prompt=DISTINCT_PROMPT, max_new_tokens=12,
+                   eos_id=stream[at])
+        want = plain(**req)
+        assert want == stream[:at + 1]
+        (got,), _ = _run(params, [req], spec_draft=(params, TINY),
+                         spec_k=4)
+        assert got == want
+
+    @pytest.mark.parametrize("max_new", [1, 2, 3])
+    def test_budget_below_depth(self, params, draft, plain, max_new):
+        req = dict(prompt=[1, 2], max_new_tokens=max_new)
+        (got,), _ = _run(params, [req], spec_draft=draft, spec_k=4)
+        assert got == plain(**req)
+        assert len(got) == max_new
+
+    @pytest.mark.parametrize("knobs", [
+        dict(temperature=0.8),
+        dict(temperature=1.2, top_k=7),
+        dict(temperature=0.6, top_k=40, top_p=0.85),
+    ], ids=["temperature", "top-k", "top-k-top-p"])
+    def test_sampled_identity_and_seed_determinism(self, params, draft,
+                                                   plain, knobs):
+        req = dict(prompt=[5, 6, 7], max_new_tokens=9, seed=7, **knobs)
+        (a, b, other), stats = _run(
+            params, [req, req, {**req, "seed": 8}],
+            spec_draft=draft, spec_k=3,
+        )
+        assert a == b == plain(**req)
+        assert other == plain(**{**req, "seed": 8})
+        assert stats["spec_draft_tokens"] > 0
+
+    def test_sampled_self_draft_is_accepted(self, params, plain):
+        """The draft proposes under the key and counter of the target
+        draw it guesses, so a draft with the target's distribution is
+        accepted when sampling too: correlated noise moves the
+        acceptance rate, never the stream."""
+        req = dict(prompt=[5, 6, 7], max_new_tokens=12, **SAMPLED)
+        (got,), stats = _run(params, [req], spec_draft=(params, TINY),
+                             spec_k=4)
+        assert got == plain(**req)
+        assert stats["spec_accepted_tokens"] > 0
+
+    def test_request_without_room_for_the_window_decodes_plain(
+            self, params, draft, plain):
+        """prompt + max_new fits the slot but not the +spec_k slack:
+        the request is served by plain decode, not refused."""
+        req = dict(prompt=list(range(1, 51)), max_new_tokens=12)
+        eng = _engine(params, spec_draft=draft, spec_k=4)
+        try:
+            got = eng.generate(**req)
+            phases = {r.phase for r in eng.profiler.snapshot()}
+            stats = eng.scheduler_stats()
+        finally:
+            eng.stop()
+        assert got == plain(**req)
+        assert "verify" not in phases and "decode" in phases
+        assert stats["spec_draft_tokens"] == 0
+
+    def test_repetition_penalty_speculates(self, params, plain):
+        # unpenalised, this prompt's greedy stream cycles 39, 201: the
+        # penalty must bite inside the window, not only between windows
+        req = dict(prompt=[207, 21, 45, 60], max_new_tokens=10,
+                   repetition_penalty=1.3)
+        (got,), stats = _run(params, [req], spec_draft=(params, TINY),
+                             spec_k=4)
+        assert got == plain(**req)
+        assert got != plain(prompt=req["prompt"], max_new_tokens=10)
+        assert stats["spec_draft_tokens"] > 0
+        assert stats["spec_accepted_tokens"] > 0
+
+    def test_mixed_burst_shares_one_window(self, params, draft, plain):
+        """Greedy, sampled and penalised rows queued together decode
+        in the same verify windows, each as if alone."""
+        reqs = [
+            dict(prompt=[5, 6, 7], max_new_tokens=9),
+            dict(prompt=[2, 3], max_new_tokens=7, temperature=0.9,
+                 seed=3, top_k=11),
+            dict(prompt=[9, 1, 4, 8], max_new_tokens=8,
+                 repetition_penalty=1.3),
+        ]
+        eng = ContinuousEngine(params, TINY, n_slots=4, cache_len=64,
+                               block_size=8, spec_draft=draft, spec_k=4)
+        subs = [eng.submit(**r) for r in reqs]
+        eng.start()
+        try:
+            for r in subs:
+                assert r.done.wait(120)
+                assert not r.failed
+            rows = {r.live_rows for r in eng.profiler.snapshot()
+                    if r.phase == "verify"}
+        finally:
+            eng.stop()
+        assert 3 in rows, rows
+        for sub, req in zip(subs, reqs):
+            assert sub.out_tokens == plain(**req), req

@@ -397,7 +397,7 @@ ZERO_SERIES = [
     *(f'kubeinfer_engine_admission_wait_seconds_count{{stage="{s}"}} 0'
       for s in ("window", "backlog")),
     *(f'kubeinfer_engine_step_duration_seconds_count{{phase="{p}"}} 0'
-      for p in ("verify", "spec", "chunk")),
+      for p in ("verify", "chunk")),
 ]
 
 
