@@ -378,6 +378,7 @@ def phase_kernels(args) -> dict:
 
     from kubeinfer_tpu.inference import flash_attention as fa
     from kubeinfer_tpu.inference import weight_quant as wq
+    from kubeinfer_tpu.inference.kv_blocks import pool_shape
     from kubeinfer_tpu.inference.model import attention as dense_attention
 
     w = WIDTHS
@@ -419,16 +420,15 @@ def phase_kernels(args) -> dict:
     live = [VERIFY_T, BLOCK - 1, BLOCK, BLOCK + 1, 7 * BLOCK + 3,
             MAX_LEN // 2, MAX_LEN - 1, MAX_LEN][:Bq]
     lengths = jnp.asarray(live, jnp.int32)
-    k_pool, v_pool = (normal((nb, BLOCK, nkv, D)),
-                      normal((nb, BLOCK, nkv, D)))
-    kq = jax.random.randint(next(keys), (nb, BLOCK, nkv, D), -127, 128,
-                            jnp.int8)
-    vq = jax.random.randint(next(keys), (nb, BLOCK, nkv, D), -127, 128,
-                            jnp.int8)
+    # pools and tails in the stored (head-major) page layout
+    pool, tail = (pool_shape(nb, BLOCK, nkv, D),
+                  (Bq, *pool_shape(2, BLOCK, nkv, D)))
+    k_pool, v_pool = normal(pool), normal(pool)
+    kq = jax.random.randint(next(keys), pool, -127, 128, jnp.int8)
+    vq = jax.random.randint(next(keys), pool, -127, 128, jnp.int8)
     ks = jax.random.uniform(next(keys), (nb, nkv), jnp.float32, 0.005, 0.03)
     vs = jax.random.uniform(next(keys), (nb, nkv), jnp.float32, 0.005, 0.03)
-    k_tail, v_tail = (normal((Bq, 2, BLOCK, nkv, D)),
-                      normal((Bq, 2, BLOCK, nkv, D)))
+    k_tail, v_tail = normal(tail), normal(tail)
     for T in (1, VERIFY_T):
         q = normal((Bq, T, nq, D))
         # the routers' mask contract: query t sits at lengths - T + t

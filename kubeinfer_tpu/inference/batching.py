@@ -5,12 +5,15 @@ scheduler feature is continuous batching; this is the TPU-native
 equivalent, built from static shapes:
 
 - A fixed pool of B decode **slots**. KV lives in a shared PAGED pool:
-  per-layer [num_blocks, block_size, n_kv, D] tensors plus a static
-  i32[B, max_blocks] block table per slot (vLLM's PagedAttention
-  layout, Kwon et al. 2023). All device state lives in one
-  ``SlotState`` pytree that never changes shape; every
-  allocation/refcount/free decision is host-side (kv_blocks.py),
-  between device steps.
+  per-layer [num_blocks, n_kv, block_size, D] tensors (head-major
+  pages: kv_blocks' page layout, read in place by the decode kernel)
+  plus a static i32[B, max_blocks] block table per slot (vLLM's
+  PagedAttention, Kwon et al. 2023). The dense prefill and the KV wire
+  are token-major; pages become rows only for the blocks one table
+  names (``_row_caches``, ``_export_pages``), never for a pool. All
+  device state lives in one ``SlotState`` pytree that never changes
+  shape; every allocation/refcount/free decision is host-side
+  (kv_blocks.py), between device steps.
 - ``stepper.decode_window`` advances EVERY active slot K tokens in ONE
   jitted call — compiled once per horizon bucket (K ∈ {1, 2, 4, 8}),
   so the per-dispatch floor is paid once per K tokens. Each fused step's
@@ -56,8 +59,12 @@ from kubeinfer_tpu.inference.kv_blocks import (
     BlockPool,
     RadixCache,
     dequantize_blocks,
+    page_dims,
+    pages_to_rows,
+    pool_shape,
     prefix_fingerprints,
     quantize_blocks,
+    rows_to_pages,
 )
 from kubeinfer_tpu.analysis.racecheck import guard, make_lock
 from kubeinfer_tpu.inference.model import Params, forward
@@ -137,6 +144,40 @@ def _put_row_recurrent(state: SlotState, slot, linear: list) -> dict:
     )
 
 
+def _row_caches(state: SlotState, table_row) -> list:
+    """One row's dense (k, v) view ``[1, S, n_kv, D]`` of every
+    full-attention layer, gathered through ``table_row``: the named
+    pages only are fetched and turned token-major for the dense
+    forward (the cost follows the row, never the pool). A quantized
+    pool's committed blocks arrive dequantized (shared-prefix KV is
+    approximate: that IS the int8 contract); the window a prefill then
+    recomputes is bf16, and requantizing a block whose values came from
+    dequantization is exact (the amax element always quantizes to ±127,
+    so the recovered scale round-trips)."""
+    bs, n_kv, D = page_dims(state.caches_k[0])
+    S = table_row.shape[0] * bs
+
+    def view(pool, scales=None):
+        pages = pool[table_row]
+        if scales is not None:
+            pages = dequantize_blocks(
+                pages, scales[table_row], state.tails_k[0].dtype)
+        return pages_to_rows(pages).reshape(1, S, n_kv, D)
+
+    if state.caches_k[0].dtype == jnp.int8:
+        return [(view(ck, sk), view(cv, sv)) for ck, sk, cv, sv in zip(
+            state.caches_k, state.scales_k,
+            state.caches_v, state.scales_v)]
+    return [(view(ck), view(cv))
+            for ck, cv in zip(state.caches_k, state.caches_v)]
+
+
+def _view_pages(view, M: int):
+    """A row's dense view ``[1, S, n_kv, D]`` back as its ``M`` pages."""
+    _, S, n_kv, D = view.shape
+    return rows_to_pages(view.reshape(M, S // M, n_kv, D))
+
+
 @functools.partial(
     jax.jit, static_argnames=("cfg", "wq_gspmd"), donate_argnums=(1,)
 )
@@ -175,7 +216,7 @@ def _admit_slot(
     view contribute exactly 0 to attention, so a cold admit here is
     bit-identical to the pre-paging dense prefill."""
     T = suffix.shape[1]
-    nb, bs, n_kv, D = state.caches_k[0].shape
+    bs = page_dims(state.caches_k[0])[0]
     M = table_row.shape[0]
     S = M * bs  # logical per-row width == engine cache_len
     q_pos = start + jnp.arange(T)
@@ -188,36 +229,7 @@ def _admit_slot(
         & (cache_pos[None, None, :] < prompt_len)
     )
     quantized = state.caches_k[0].dtype == jnp.int8
-    if quantized:
-        # quantized pool: the gathered view dequantizes committed
-        # blocks (shared-prefix KV arrives approximate — that IS the
-        # int8 contract); the suffix window recomputes in bf16, and
-        # requantizing a block whose values came from dequantization
-        # is exact (the amax element always quantizes to ±127, so the
-        # recovered scale round-trips)
-        dt = state.tails_k[0].dtype
-        caches = [
-            (
-                dequantize_blocks(
-                    ck[table_row], sk[table_row], dt
-                ).reshape(1, S, n_kv, D),
-                dequantize_blocks(
-                    cv[table_row], sv[table_row], dt
-                ).reshape(1, S, n_kv, D),
-            )
-            for ck, sk, cv, sv in zip(
-                state.caches_k, state.scales_k,
-                state.caches_v, state.scales_v,
-            )
-        ]
-    else:
-        caches = [
-            (
-                ck[table_row].reshape(1, S, n_kv, D),
-                cv[table_row].reshape(1, S, n_kv, D),
-            )
-            for ck, cv in zip(state.caches_k, state.caches_v)
-        ]
+    caches = _row_caches(state, table_row)
     stats: list = []
     logits, caches = forward(
         params, suffix, cfg, positions=q_pos[None, :], attn_mask=mask,
@@ -240,9 +252,8 @@ def _admit_slot(
     own = own_mask[:, None, None, None]
 
     def put(pool, view):
-        new_blocks = view.reshape(M, bs, n_kv, D)
         return pool.at[table_row].set(
-            jnp.where(own, new_blocks, pool[table_row])
+            jnp.where(own, _view_pages(view, M), pool[table_row])
         )
 
     if quantized:
@@ -255,8 +266,7 @@ def _admit_slot(
         own_q = own_mask & (jnp.arange(M) < tb)
 
         def putq(pool, scales, view):
-            blocks = view.reshape(M, bs, n_kv, D)
-            qv, sv = quantize_blocks(blocks)
+            qv, sv = quantize_blocks(_view_pages(view, M))
             pool = pool.at[table_row].set(
                 jnp.where(own_q[:, None, None, None], qv,
                           pool[table_row])
@@ -267,7 +277,7 @@ def _admit_slot(
             return pool, scales
 
         def tail_pair(tails, view):
-            blocks = view.reshape(M, bs, n_kv, D)
+            blocks = _view_pages(view, M)
             # slot 0 = the current partial block tb (clipped gather:
             # tb == M only for prefill-only full rows, which never
             # decode); slot 1 = zeroed spill room
@@ -353,37 +363,13 @@ def _prefill_chunk(
     chunk. Compiled once per chunk width C (a fixed multiple of
     block_size), never per prompt length."""
     T = window.shape[1]
-    nb, bs, n_kv, D = state.caches_k[0].shape
     M = table_row.shape[0]
-    S = M * bs
+    S = M * page_dims(state.caches_k[0])[0]
     q_pos = pos + jnp.arange(T)
     cache_pos = jnp.arange(S)
     mask = cache_pos[None, None, :] <= q_pos[None, :, None]
     quantized = state.caches_k[0].dtype == jnp.int8
-    if quantized:
-        dt = state.tails_k[0].dtype
-        caches = [
-            (
-                dequantize_blocks(
-                    ck[table_row], sk[table_row], dt
-                ).reshape(1, S, n_kv, D),
-                dequantize_blocks(
-                    cv[table_row], sv[table_row], dt
-                ).reshape(1, S, n_kv, D),
-            )
-            for ck, sk, cv, sv in zip(
-                state.caches_k, state.scales_k,
-                state.caches_v, state.scales_v,
-            )
-        ]
-    else:
-        caches = [
-            (
-                ck[table_row].reshape(1, S, n_kv, D),
-                cv[table_row].reshape(1, S, n_kv, D),
-            )
-            for ck, cv in zip(state.caches_k, state.caches_v)
-        ]
+    caches = _row_caches(state, table_row)
     stats: list = []
     _, caches = forward(
         params, window, cfg, positions=q_pos[None, :], attn_mask=mask,
@@ -401,9 +387,8 @@ def _prefill_chunk(
     own = own_mask[:, None, None, None]
 
     def put(pool, view):
-        new_blocks = view.reshape(M, bs, n_kv, D)
         return pool.at[table_row].set(
-            jnp.where(own, new_blocks, pool[table_row])
+            jnp.where(own, _view_pages(view, M), pool[table_row])
         )
 
     if quantized:
@@ -414,8 +399,7 @@ def _prefill_chunk(
         # already-committed earlier-chunk blocks requantize exactly,
         # see _admit_slot)
         def putq(pool, scales, view):
-            blocks = view.reshape(M, bs, n_kv, D)
-            qv, sv = quantize_blocks(blocks)
+            qv, sv = quantize_blocks(_view_pages(view, M))
             pool = pool.at[table_row].set(
                 jnp.where(own, qv, pool[table_row])
             )
@@ -513,7 +497,7 @@ def _import_blocks(
     state: SlotState,
     table_row: jax.Array,  # i32[max_blocks] freshly allocated block ids
     own_mask: jax.Array,  # bool[max_blocks] True = real imported page
-    pages_k: jax.Array,  # [L, max_blocks, bs, n_kv, D], zero-padded
+    pages_k: jax.Array,  # [L, max_blocks, n_kv, bs, D], zero-padded
     pages_v: jax.Array,
     scales_k: jax.Array,  # f32[L, max_blocks, n_kv]; all-ones for bf16
     scales_v: jax.Array,
@@ -1422,6 +1406,27 @@ class ContinuousEngine:
             return 0, "timeout"
         return task.imported, task.reason
 
+    def _export_pages(self, idx: jax.Array):
+        """The pool blocks ``idx`` names, as the wire carries them:
+        ``(pages_k, pages_v)`` host arrays ``[L, n, block_size, n_kv,
+        D]``, token-major whatever the stored layout (the edge where
+        stored pages become wire rows; the gather fetches the named
+        blocks only). Scheduler thread only: the sole safe ``_state``
+        reader, since jit donation deletes buffers under a racing
+        read."""
+        def rows(pools):
+            # a host sync by design: the pages must reach host memory
+            # before the request completes or the chunk streams (one
+            # gather per layer, one strided copy into wire order)
+            bs, n_kv, D = page_dims(pools[0])
+            out = np.empty((len(pools), idx.shape[0], bs, n_kv, D),
+                           np.dtype(pools[0].dtype))
+            for layer, pool in zip(out, pools):
+                layer[...] = pages_to_rows(np.asarray(pool[idx]))
+            return out
+
+        return rows(self._state.caches_k), rows(self._state.caches_v)
+
     def _step_import(self) -> None:
         """Service at most ONE staged KV import per scheduler pass —
         the same pass quantum as chunked prefill, so a burst of imports
@@ -1440,8 +1445,8 @@ class ContinuousEngine:
         with annotate("engine.import"):
             n = int(task.pages_k.shape[1])
             L = len(self._state.caches_k)
-            _nb, bs, n_kv, D = self._state.caches_k[0].shape
-            want = (L, n, bs, n_kv, D)
+            bs, n_kv, D = page_dims(self._state.caches_k[0])
+            want = (L, n, bs, n_kv, D)  # the wire is token-major
             cache_dt = np.dtype(self._state.caches_k[0].dtype)
             if (
                 np.dtype(task.pages_k.dtype) != cache_dt
@@ -1501,10 +1506,13 @@ class ContinuousEngine:
             table_row[:n] = fresh
             own_mask = np.zeros(self.max_blocks, bool)
             own_mask[:n] = True
-            pk = np.zeros((L, self.max_blocks, bs, n_kv, D), cache_dt)
-            pk[:, :n] = task.pages_k
-            pv = np.zeros((L, self.max_blocks, bs, n_kv, D), cache_dt)
-            pv[:, :n] = task.pages_v
+            # the edge where wire rows become stored pages: a strided
+            # host copy of the imported blocks, nothing on the device
+            pk = np.zeros(
+                (L, *pool_shape(self.max_blocks, bs, n_kv, D)), cache_dt)
+            pk[:, :n] = rows_to_pages(task.pages_k)
+            pv = np.zeros_like(pk)
+            pv[:, :n] = rows_to_pages(task.pages_v)
             # all-ones padding keeps null-block scales at their init value;
             # the bf16 pytree carries no scale leaves and jit drops these
             sk = np.ones((L, self.max_blocks, n_kv), np.float32)
@@ -2110,14 +2118,7 @@ class ContinuousEngine:
                 idx = jnp.asarray(
                     np.asarray(task.table_row[:full], np.int32)
                 )
-                pages_k = np.stack([
-                    # lint: allow[host-sync] export capture: the prefilled pages must reach host memory before the request completes (one gather per layer, prefill-only requests never decode)
-                    np.asarray(ck[idx]) for ck in self._state.caches_k
-                ])
-                pages_v = np.stack([
-                    # lint: allow[host-sync] export capture (same boundary as pages_k above)
-                    np.asarray(cv[idx]) for cv in self._state.caches_v
-                ])
+                pages_k, pages_v = self._export_pages(idx)
                 pairs = self._radix.match_with_fingerprints(
                     tokens[:full * self.block_size]
                 )
@@ -2441,12 +2442,7 @@ class ContinuousEngine:
             # _state, so the gather cannot race a donation; same
             # boundary as _finalize_admit's export capture
             idx = jnp.asarray(np.asarray(blocks, np.int32))
-            pages_k = np.stack([
-                np.asarray(ck[idx]) for ck in self._state.caches_k
-            ])
-            pages_v = np.stack([
-                np.asarray(cv[idx]) for cv in self._state.caches_v
-            ])
+            pages_k, pages_v = self._export_pages(idx)
             # fingerprints recomputed from the tokens, not read from
             # the trie: the streamed blocks are slot-held (not yet
             # inserted), and the chain from token 0 is exactly what the

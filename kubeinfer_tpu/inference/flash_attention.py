@@ -73,6 +73,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeinfer_tpu.inference.kv_blocks import (
+    dequantize_blocks,
+    page_dims,
+    pages_to_rows,
+)
 from kubeinfer_tpu.inference.model import attention as dense_attention
 
 TILE_T = 256  # query positions per tile (rows = TILE_T * G)
@@ -663,14 +668,18 @@ def decode_attention_auto(q, k, v, lengths, mask, gspmd=False):
 
 # --- block-table (paged) decode attention ----------------------------------
 #
-# The serving engine's KV lives in a shared pool [num_blocks, block_size,
-# n_kv, D]; each batch row owns an i32[max_blocks] table naming its blocks
-# in sequence order. The decode kernel below is _decode_attn_kernel with
-# one change: the k/v index_map resolves the S-tile index through the
-# scalar-prefetched table, so a tile IS a pool block and rows sharing a
-# prefix DMA the same physical blocks. No reference counterpart — the
-# reference hands paging to the vLLM subprocess (vllm.go:93-112); vLLM's
-# PagedAttention (Kwon et al. 2023) is the design source.
+# The serving engine's KV lives in a shared pool stored HEAD-MAJOR,
+# [num_blocks, n_kv, block_size, D] (kv_blocks' page layout); each batch
+# row owns an i32[max_blocks] table naming its blocks in sequence order.
+# The decode kernel below is _decode_attn_kernel with one change: the k/v
+# index_map resolves the S-tile index through the scalar-prefetched
+# table, so a tile IS one head's [block_size, D] plane of a pool block,
+# read where it lies (the pool is the pallas_call's operand as stored:
+# nothing of the pool's shape is copied in front of a call), and rows
+# sharing a prefix DMA the same physical blocks. No reference
+# counterpart — the reference hands paging to the vLLM subprocess
+# (vllm.go:93-112); vLLM's PagedAttention (Kwon et al. 2023) is the
+# design source.
 #
 # Table contract: EVERY entry of every row — including entries past the
 # row's live blocks — must be a valid pool index (the host pads with the
@@ -756,8 +765,8 @@ def _decode_blocks_kernel(
 
 def decode_attention_blocks(
     q: jax.Array,  # [B, T, n_heads, D] — the row's last T tokens
-    k_pool: jax.Array,  # [num_blocks, block_size, n_kv, D] shared pool
-    v_pool: jax.Array,  # [num_blocks, block_size, n_kv, D]
+    k_pool: jax.Array,  # [num_blocks, n_kv, block_size, D] shared pool
+    v_pool: jax.Array,  # [num_blocks, n_kv, block_size, D]
     block_tables: jax.Array,  # i32[B, max_blocks]: pool indices, seq order
     lengths: jax.Array,  # i32[B]: live entries per row (offset + T)
     *,
@@ -778,7 +787,7 @@ def decode_attention_blocks(
     decode_attention_blocks_jnp (bit-identical, parity-tested in
     tests/test_flash_attention.py)."""
     B, T, n_heads, D = q.shape
-    num_blocks, block_size, n_kv = k_pool.shape[:3]
+    block_size, n_kv, _ = page_dims(k_pool)
     max_blocks = block_tables.shape[1]
     G = n_heads // n_kv
 
@@ -788,9 +797,6 @@ def decode_attention_blocks(
     qf = q.reshape(B, T, n_kv, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B * n_kv, T * G, D
     )
-    # [num_blocks, n_kv, block_size, D]: one (block, head) pair per tile
-    kp = k_pool.transpose(0, 2, 1, 3)
-    vp = v_pool.transpose(0, 2, 1, 3)
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
 
@@ -813,6 +819,7 @@ def decode_attention_blocks(
         (1, T * G, D), lambda bh, ts, tbl_ref, lens_ref: (bh, 0, 0),
         memory_space=pltpu.VMEM,
     )
+    # one (block, head) pair per tile, of the pool as stored
     kv_spec = pl.BlockSpec(
         (1, 1, block_size, D), _kv_map, memory_space=pltpu.VMEM
     )
@@ -836,7 +843,7 @@ def decode_attention_blocks(
         out_shape=jax.ShapeDtypeStruct((B * n_kv, T * G, D), q.dtype),
         interpret=interpret,
         name="decode_attention_blocks",
-    )(tbl, lens, qf, kp, vp)
+    )(tbl, lens, qf, k_pool, v_pool)
     return out.reshape(B, n_kv, T, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, n_heads, D
     )
@@ -844,7 +851,7 @@ def decode_attention_blocks(
 
 def decode_attention_blocks_jnp(
     q: jax.Array,  # [B, T, n_heads, D]
-    k_pool: jax.Array,  # [num_blocks, block_size, n_kv, D]
+    k_pool: jax.Array,  # [num_blocks, n_kv, block_size, D]
     v_pool: jax.Array,
     block_tables: jax.Array,  # i32[B, max_blocks]
     lengths: jax.Array,  # i32[B]
@@ -860,7 +867,7 @@ def decode_attention_blocks_jnp(
     decode_attention_jnp(tile_s=block_size) on the gathered cache —
     parity-tested both ways."""
     B, T, n_heads, D = q.shape
-    block_size, n_kv = k_pool.shape[1], k_pool.shape[2]
+    block_size, n_kv, _ = page_dims(k_pool)
     max_blocks = block_tables.shape[1]
     G = n_heads // n_kv
     BH = B * n_kv
@@ -870,8 +877,6 @@ def decode_attention_blocks_jnp(
     qf = q.reshape(B, T, n_kv, G, D).transpose(0, 2, 1, 3, 4).reshape(
         BH, T * G, D
     )
-    kp = k_pool.transpose(0, 2, 1, 3)  # [num_blocks, n_kv, bs, D]
-    vp = v_pool.transpose(0, 2, 1, 3)
     tbl = jnp.asarray(block_tables, jnp.int32)
     row_tbl = jnp.repeat(tbl, n_kv, axis=0)  # [BH, max_blocks]
     row_head = jnp.tile(jnp.arange(n_kv, dtype=jnp.int32), B)  # [BH]
@@ -882,8 +887,8 @@ def decode_attention_blocks_jnp(
 
         def step(carry, ts):
             m, l, acc = carry
-            k_t = kp[trow[ts], h]  # [bs, D] — the kernel's tile, gathered
-            v_t = vp[trow[ts], h]
+            k_t = k_pool[trow[ts], h]  # [bs, D]: the kernel's tile
+            v_t = v_pool[trow[ts], h]
             s_pos = ts * block_size + jax.lax.broadcasted_iota(
                 jnp.int32, (T, block_size), 1
             )
@@ -912,14 +917,16 @@ def decode_attention_blocks_jnp(
 
 
 def gather_block_kv(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """[num_blocks, bs, n_kv, D] pool -> [B, max_blocks * bs, n_kv, D]
+    """[num_blocks, n_kv, bs, D] pool -> [B, max_blocks * bs, n_kv, D]
     per-row linear view through the tables — the dense-fallback (and
     warm-prefill) materialization of what the block kernel reads
-    in-place. One XLA gather; rows sharing blocks duplicate them here,
-    which is exactly the copy the paged kernel exists to avoid."""
-    nb, bs, n_kv, D = pool.shape
+    in-place. One XLA gather and a transpose of the GATHERED pages (the
+    cost follows the rows, never the pool); rows sharing blocks
+    duplicate them here, which is exactly the copy the paged kernel
+    exists to avoid."""
+    bs, n_kv, D = page_dims(pool)
     B, M = block_tables.shape
-    return pool[block_tables].reshape(B, M * bs, n_kv, D)
+    return pages_to_rows(pool[block_tables]).reshape(B, M * bs, n_kv, D)
 
 
 def decode_blocks_available(block_size: int, D: int) -> bool:
@@ -958,7 +965,7 @@ def decode_attention_blocks_auto(q, k_pool, v_pool, block_tables, lengths,
     (mask[b, t, s] = s <= lengths[b] - T + t) for the branches to
     agree."""
     if (not gspmd) and decode_blocks_available(
-        k_pool.shape[1], q.shape[3]
+        page_dims(k_pool)[0], q.shape[3]
     ):
         return decode_attention_blocks(
             q, k_pool, v_pool, block_tables, lengths
@@ -974,9 +981,10 @@ def decode_attention_blocks_auto(q, k_pool, v_pool, block_tables, lengths,
 # --- int8 (quantized pool) block-table decode attention --------------------
 #
 # kv_dtype="int8" splits each pool side into three tensors: int8 pages
-# [num_blocks, bs, n_kv, D], f32 scales [num_blocks, n_kv] (symmetric
+# [num_blocks, n_kv, bs, D], f32 scales [num_blocks, n_kv] (symmetric
 # per-block-per-head, kv_blocks.quantize_blocks), and a per-slot bf16
-# TAIL [n_slots, 2, bs, n_kv, D] holding the row's current partial
+# TAIL [n_slots, 2, n_kv, bs, D] (two pages a slot, the pool's layout)
+# holding the row's current partial
 # block plus the one a verify window can spill into (n_emit <= k+1 <
 # block_size bounds a window to ONE boundary crossing). Dequantization
 # happens HERE, next to the table gather — committed blocks never
@@ -1076,11 +1084,11 @@ def _decode_blocks_q8_kernel(
 
 def decode_attention_blocks_q8(
     q: jax.Array,  # [B, T, n_heads, D]
-    k_pool: jax.Array,  # int8[num_blocks, block_size, n_kv, D]
+    k_pool: jax.Array,  # int8[num_blocks, n_kv, block_size, D]
     v_pool: jax.Array,  # int8
     k_scales: jax.Array,  # f32[num_blocks, n_kv]
     v_scales: jax.Array,  # f32[num_blocks, n_kv]
-    k_tail: jax.Array,  # [B, 2, block_size, n_kv, D] bf16 partial blocks
+    k_tail: jax.Array,  # [B, 2, n_kv, block_size, D] bf16 partial blocks
     v_tail: jax.Array,
     block_tables: jax.Array,  # i32[B, max_blocks]
     lengths: jax.Array,  # i32[B] live entries per row (offset + T)
@@ -1098,17 +1106,13 @@ def decode_attention_blocks_q8(
     decode_attention_blocks_q8_jnp (bit-identical — parity-tested in
     tests/test_kv_quant.py)."""
     B, T, n_heads, D = q.shape
-    num_blocks, block_size, n_kv = k_pool.shape[:3]
+    block_size, n_kv, _ = page_dims(k_pool)
     max_blocks = block_tables.shape[1]
     G = n_heads // n_kv
 
     qf = q.reshape(B, T, n_kv, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B * n_kv, T * G, D
     )
-    kp = k_pool.transpose(0, 2, 1, 3)  # int8 [num_blocks, n_kv, bs, D]
-    vp = v_pool.transpose(0, 2, 1, 3)
-    kt = k_tail.transpose(0, 1, 3, 2, 4)  # [B, 2, n_kv, bs, D]
-    vt = v_tail.transpose(0, 1, 3, 2, 4)
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
     tb = jnp.maximum(lens - T, 0) // block_size  # i32[B]
@@ -1167,7 +1171,7 @@ def decode_attention_blocks_q8(
         out_shape=jax.ShapeDtypeStruct((B * n_kv, T * G, D), q.dtype),
         interpret=interpret,
         name="decode_attention_blocks_q8",
-    )(tbl, lens, tb, ksb, vsb, qf, kp, vp, kt, vt)
+    )(tbl, lens, tb, ksb, vsb, qf, k_pool, v_pool, k_tail, v_tail)
     return out.reshape(B, n_kv, T, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, T, n_heads, D
     )
@@ -1190,7 +1194,7 @@ def decode_attention_blocks_q8_jnp(
     the UNclamped table like the bf16 twin — dead tiles fold exactly 0
     on both sides whatever they dequantize to."""
     B, T, n_heads, D = q.shape
-    block_size, n_kv = k_pool.shape[1], k_pool.shape[2]
+    block_size, n_kv, _ = page_dims(k_pool)
     max_blocks = block_tables.shape[1]
     G = n_heads // n_kv
     BH = B * n_kv
@@ -1199,10 +1203,6 @@ def decode_attention_blocks_q8_jnp(
     qf = q.reshape(B, T, n_kv, G, D).transpose(0, 2, 1, 3, 4).reshape(
         BH, T * G, D
     )
-    kp = k_pool.transpose(0, 2, 1, 3)
-    vp = v_pool.transpose(0, 2, 1, 3)
-    kt = k_tail.transpose(0, 1, 3, 2, 4)  # [B, 2, n_kv, bs, D]
-    vt = v_tail.transpose(0, 1, 3, 2, 4)
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
     tb = jnp.maximum(lens - T, 0) // block_size
@@ -1222,11 +1222,13 @@ def decode_attention_blocks_q8_jnp(
             use_tail = ts >= tbase
             rel = jnp.clip(ts - tbase, 0, 1)
             k_eff = _dequant_tile(
-                kp[trow[ts], h], ks_row[ts], kt[b, rel, h], use_tail,
+                k_pool[trow[ts], h], ks_row[ts], k_tail[b, rel, h],
+                use_tail,
                 q.dtype,
             )
             v_eff = _dequant_tile(
-                vp[trow[ts], h], vs_row[ts], vt[b, rel, h], use_tail,
+                v_pool[trow[ts], h], vs_row[ts], v_tail[b, rel, h],
+                use_tail,
                 q.dtype,
             )
             s_pos = ts * block_size + jax.lax.broadcasted_iota(
@@ -1264,21 +1266,22 @@ def dequant_gather_block_kv(pool, scales, tail, block_tables, tail_base):
     two tail-resident tiles verbatim, returning the [B, max_blocks*bs,
     n_kv, D] linear view in the tail's (compute) dtype. The dense
     fallback AND the GSPMD route: every op here partitions over n_kv
-    (pages axis 2, scales axis 1, tail axis 3), the gathers index only
+    (pages axis 1, scales axis 1, tail axis 2), the gathers index only
     replicated axes."""
-    nb, bs, n_kv, D = pool.shape
+    bs, n_kv, D = page_dims(pool)
     B, M = block_tables.shape
-    deq = (
-        pool[block_tables].astype(jnp.float32)
-        * scales[block_tables][:, :, None, :, None]
-    )  # [B, M, bs, n_kv, D] f32
+    deq = dequantize_blocks(
+        pool[block_tables], scales[block_tables]
+    )  # [B, M, n_kv, bs, D] f32
     rel = jnp.arange(M, dtype=jnp.int32)[None, :] - tail_base[:, None]
     use_tail = (rel >= 0) & (rel < 2)
     tg = tail[jnp.arange(B)[:, None], jnp.clip(rel, 0, 1)]
     out = jnp.where(
         use_tail[:, :, None, None, None], tg.astype(jnp.float32), deq
     )
-    return out.astype(tail.dtype).reshape(B, M * bs, n_kv, D)
+    return pages_to_rows(out.astype(tail.dtype)).reshape(
+        B, M * bs, n_kv, D
+    )
 
 
 def decode_attention_blocks_q8_auto(
@@ -1291,7 +1294,7 @@ def decode_attention_blocks_q8_auto(
     custom-call constraint). Same lengths/mask live-set contract; the
     tail base both branches derive is (lengths - T) // block_size."""
     T = q.shape[1]
-    block_size = k_pool.shape[1]
+    block_size = page_dims(k_pool)[0]
     if (not gspmd) and decode_blocks_available(block_size, q.shape[3]):
         return decode_attention_blocks_q8(
             q, k_pool, v_pool, k_scales, v_scales, k_tail, v_tail,

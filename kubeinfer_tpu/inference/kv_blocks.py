@@ -106,7 +106,7 @@ def prefix_fingerprints(tokens: Sequence[int], block_size: int) -> list[int]:
 class BlockPool:
     """Fixed-size pool of KV blocks with host-side refcounts.
 
-    Pure bookkeeping — the actual [num_blocks, block_size, n_kv, D]
+    Pure bookkeeping — the actual [num_blocks, n_kv, block_size, D]
     device tensors live in the engine's SlotState; indices handed out
     here are what the block tables (and the Pallas index_map) resolve.
     Indices are logical per the module's device-layout audit: one pool,
@@ -441,6 +441,83 @@ class RadixCache:
             }
 
 
+# -- the page layout ----------------------------------------------------
+#
+# A PAGE is one block's K (or V) of one layer, stored HEAD-MAJOR:
+# ``[n_kv, block_size, D]``. Every paged tensor is some leading axes
+# over pages: a layer's pool ``[num_blocks, n_kv, block_size, D]``, the
+# int8 engine's per-slot tails ``[n_slots, 2, n_kv, block_size, D]``. It
+# is the layout flash_attention's block kernels read in place (one
+# (block, head) tile is the page's contiguous ``[block_size, D]``
+# plane), so no step ever transposes a pool. Everything token-major —
+# the dense forward's ``[B, S, n_kv, D]`` view of a row, the KV wire's
+# ``[layers, blocks, block_size, n_kv, D]`` — is a ROW view, and the
+# functions below are the only place that says how the two relate:
+# change the stored layout here and in the kernels' BlockSpecs, nowhere
+# else. The shape and view functions are methods-only (``.shape``,
+# ``.swapaxes``), so numpy staging buffers and traced jax arrays both
+# pass; jax.numpy is imported inside the functions that compute, as in
+# the quantization below, and this module stays import-light.
+
+
+def pool_shape(num_blocks: int, block_size: int, n_kv: int,
+               head_dim: int) -> tuple[int, int, int, int]:
+    """Shape of one layer's pool of ``num_blocks`` pages."""
+    return (num_blocks, n_kv, block_size, head_dim)
+
+
+def page_dims(pages) -> tuple[int, int, int]:
+    """``(block_size, n_kv, head_dim)`` of any array of pages."""
+    n_kv, block_size, head_dim = pages.shape[-3:]
+    return block_size, n_kv, head_dim
+
+
+def page_axes(lead: int, head):
+    """Per-axis labels of ``lead`` leading axes over pages with
+    ``head`` on the n_kv axis and None elsewhere — what a
+    PartitionSpec that shards pages by KV head is made of."""
+    return (None,) * lead + (head, None, None)
+
+
+def pages_to_rows(pages):
+    """Pages ``[..., n_kv, bs, D]`` -> token-major ``[..., bs, n_kv,
+    D]``. A copy of what it is given: callers hand it the blocks a
+    table names, never a pool."""
+    return pages.swapaxes(-3, -2)
+
+
+def rows_to_pages(rows):
+    """Token-major ``[..., bs, n_kv, D]`` -> pages ``[..., n_kv, bs,
+    D]`` (inverse of :func:`pages_to_rows`)."""
+    return rows.swapaxes(-3, -2)
+
+
+def write_tokens(pages, lead: tuple, slot, new):
+    """Scatter tokens into pages: ``new[..., n_kv, D]`` lands at
+    in-page position ``slot`` of the pages ``lead`` (a tuple of index
+    arrays over the leading axes) names; index arrays broadcast to
+    ``new``'s leading shape.
+
+    The scatter runs over each page seen as ``[n_kv * bs, D]`` rows (a
+    free reshape), one row a (token, head), and that spelling is the
+    point. Written ``pages.at[(*lead, slice(None), slot)].set(new)``,
+    the TPU compiler gives the scatter a token-major operand layout and
+    copies the whole pool into it and back, every step. Over rows
+    nothing but D is a window, so the donated pool is updated in place
+    in the layout it has and nothing else of its shape exists. Merging
+    n_kv with the axis under it (not with the one above) keeps a pool
+    sharded by KV head sharded through the reshape: under GSPMD each
+    device takes the rows of its own heads, where a scatter over
+    ``[rows, D]`` of the whole pool would gather the pool first."""
+    import jax.numpy as jnp
+
+    block_size, n_kv, head_dim = page_dims(pages)
+    row = jnp.arange(n_kv) * block_size + slot[..., None]
+    idx = (*(jnp.asarray(i)[..., None] for i in lead), row)
+    rows = pages.reshape(*pages.shape[:-3], n_kv * block_size, head_dim)
+    return rows.at[idx].set(new).reshape(pages.shape)
+
+
 # -- int8 block quantization --------------------------------------------
 #
 # The kv_dtype="int8" pool stores committed blocks as int8 values plus
@@ -461,22 +538,22 @@ class RadixCache:
 
 
 def quantize_blocks(x):
-    """[..., block_size, n_kv, D] float pages -> (int8 pages,
+    """[..., n_kv, block_size, D] float pages -> (int8 pages,
     f32[..., n_kv] scales). Symmetric round-to-nearest; an all-zero
     block (the null block, unwritten pool space) gets scale 1.0 so
     dequantization is exactly 0 rather than 0/0."""
     import jax.numpy as jnp
 
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=(-3, -1))  # [..., n_kv]
+    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))  # [..., n_kv]
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    inv = 1.0 / scale[..., None, :, None]
+    inv = 1.0 / scale[..., None, None]
     q = jnp.clip(jnp.round(xf * inv), -127.0, 127.0).astype(jnp.int8)
     return q, scale
 
 
 def dequantize_blocks(q, scale, dtype=None):
-    """Inverse of :func:`quantize_blocks`: int8 pages [..., bs, n_kv, D]
+    """Inverse of :func:`quantize_blocks`: int8 pages [..., n_kv, bs, D]
     x f32 scales [..., n_kv] -> float pages (``dtype`` or f32). The
     multiply order (int8 -> f32, then * scale) is the contract the
     in-kernel dequant mirrors (flash_attention._dequant_tile) — parity
@@ -484,5 +561,5 @@ def dequantize_blocks(q, scale, dtype=None):
     read depends on both doing bitwise the same math."""
     import jax.numpy as jnp
 
-    out = q.astype(jnp.float32) * scale[..., None, :, None]
+    out = q.astype(jnp.float32) * scale[..., None, None]
     return out if dtype is None else out.astype(dtype)

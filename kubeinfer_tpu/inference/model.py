@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeinfer_tpu.inference.config import ModelConfig
+from kubeinfer_tpu.inference.kv_blocks import page_dims, write_tokens
 from kubeinfer_tpu.inference.weight_quant import quantize_layer, wq_dot
 
 Params = dict[str, Any]
@@ -322,10 +323,12 @@ def decoder_layer(
     attention kernels.
 
     ``block_tables`` switches the cache write to the paged layout: the
-    cache operands are then the POOL tensors [num_blocks, block_size,
-    n_kv, D] shared across rows, and row b's token at logical position
+    cache operands are then the POOL tensors [num_blocks, n_kv,
+    block_size, D] (head-major pages, kv_blocks' page layout) shared
+    across rows, and row b's token at logical position
     ``cache_offset[b]`` lands in block ``block_tables[b, off // bs]``
-    at slot ``off % bs``. Decode-only (T == 1 with per-row offsets) —
+    at slot ``off % bs`` of each head (kv_blocks.write_tokens).
+    Decode-only (T == 1 with per-row offsets) —
     prefill into the pool goes through the engine's gather/scatter
     admit step, not through here. The paired ``attn_fn`` must read the
     pool through the same tables (batching wires
@@ -392,65 +395,45 @@ def decoder_layer(
                     "(prefill writes go through the engine's paged "
                     "admit, not decoder_layer)"
                 )
+            # row b writes its T tokens at contiguous logical positions
+            # cache_offset[b] + t (T == 1 a decode step, T > 1 the
+            # speculative verify window)
+            rows = jnp.arange(block_tables.shape[0])[:, None]
+            pos = cache_offset[:, None] + jnp.arange(T)
             if isinstance(ck, tuple):
                 # quantized pool: the cache entry is (int8 pages,
                 # scales, bf16 tails). Fresh K/V lands in the per-slot
                 # TAIL, never the pool — quantize-on-commit happens at
                 # the window boundary (stepper._commit_full_tails), so
                 # a partial block never round-trips through int8. Tail
-                # slot rel = pos//bs - offset//bs is 0 or 1: the window
-                # writes at most T <= k + 1 < block_size positions, so
-                # one boundary crossing max. Inactive rows scribble
-                # into their OWN tail slots — harmless, (re)admit
-                # rewrites them.
+                # slot rel = pos//bs - offset//bs is 0 or 1: the tail
+                # was pinned to offset // bs at window start and the
+                # window writes at most T <= k + 1 < block_size
+                # positions, so one boundary crossing max. Inactive
+                # rows scribble into their OWN tail slots — harmless,
+                # (re)admit rewrites them.
                 kq, ks, ktail = ck
                 vq, vs, vtail = cv
-                bs = kq.shape[1]
-                rows = jnp.arange(block_tables.shape[0])
-                if T == 1:
-                    # rel is identically 0: the tail was pinned to
-                    # offset // bs at window start
-                    ktail = ktail.at[rows, 0, cache_offset % bs].set(
-                        k[:, 0])
-                    vtail = vtail.at[rows, 0, cache_offset % bs].set(
-                        v[:, 0])
-                else:
-                    pos = cache_offset[:, None] + jnp.arange(T)
-                    rel = pos // bs - (cache_offset // bs)[:, None]
-                    ktail = ktail.at[
-                        rows[:, None], rel, pos % bs].set(k)
-                    vtail = vtail.at[
-                        rows[:, None], rel, pos % bs].set(v)
+                bs = page_dims(kq)[0]
+                lead = (rows, pos // bs - (cache_offset // bs)[:, None])
                 # repack and fall through: the quantized attn_fn
                 # unpacks the triple, and the epilogue below is
                 # dtype-agnostic
-                ck = (kq, ks, ktail)
-                cv = (vq, vs, vtail)
+                ck = (kq, ks, write_tokens(ktail, lead, pos % bs, k))
+                cv = (vq, vs, write_tokens(vtail, lead, pos % bs, v))
             else:
-                # paged decode write: one batched scatter into the
-                # pool. Rows of a retired slot carry an all-null
-                # table, so their write lands in the sacrificial block
-                # 0 — duplicate indices there make block 0's content
-                # nondeterministic, which is fine because nothing ever
-                # attends to it.
-                bs = ck.shape[1]
-                rows = jnp.arange(block_tables.shape[0])
-                if T == 1:
-                    blk = block_tables[rows, cache_offset // bs]
-                    ck = ck.at[blk, cache_offset % bs].set(k[:, 0])
-                    cv = cv.at[blk, cache_offset % bs].set(v[:, 0])
-                else:
-                    # speculative verify window: row b writes its T
-                    # tokens at contiguous logical positions
-                    # cache_offset[b] + t. Within a live row the
-                    # (block, slot) pairs are distinct; cross-row
-                    # collisions happen only on the null block 0
-                    # above, so scatter order never matters for
-                    # anything attended to.
-                    pos = cache_offset[:, None] + jnp.arange(T)
-                    blk = block_tables[rows[:, None], pos // bs]
-                    ck = ck.at[blk, pos % bs].set(k)
-                    cv = cv.at[blk, pos % bs].set(v)
+                # paged write: one batched scatter of the tokens' n_kv
+                # rows into the (donated) pool, in place: nothing else
+                # here has the pool's shape. Within a live row the
+                # (block, slot) pairs are distinct. Rows of a retired
+                # slot carry an all-null table, so their write lands in
+                # the sacrificial block 0 — duplicate indices there
+                # make block 0's content nondeterministic, which is
+                # fine because nothing ever attends to it.
+                bs = page_dims(ck)[0]
+                lead = (block_tables[rows, pos // bs],)
+                ck = write_tokens(ck, lead, pos % bs, k)
+                cv = write_tokens(cv, lead, pos % bs, v)
         elif getattr(cache_offset, "ndim", 0) == 1:
             # per-row offsets (continuous-batching / ragged decode:
             # rows at different sequence positions in one dispatch)

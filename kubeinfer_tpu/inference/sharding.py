@@ -11,7 +11,7 @@ do exactly that (scaling-book recipe: annotate, don't hand-schedule).
 
 :class:`EngineLayout` extends the same recipe to the serving engine's
 paged state: params per :func:`param_specs`, the shared KV block pool
-``[num_blocks, block_size, n_kv, D]`` sharded along ``n_kv`` (each
+``[num_blocks, n_kv, block_size, D]`` sharded along ``n_kv`` (each
 device holds its own heads' slice of EVERY block — block indices stay
 logical and host bookkeeping never sees the layout), everything else
 replicated. The engine's jits (admit, chunk, decode window) take the
@@ -36,6 +36,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeinfer_tpu.inference.config import ModelConfig
+from kubeinfer_tpu.inference.kv_blocks import page_axes
 from kubeinfer_tpu.inference.model import Params, forward
 from kubeinfer_tpu.inference.ring_attention import ring_attention
 
@@ -240,8 +241,9 @@ class EngineLayout:
     same traces, same compile cache. Under ``tp > 1`` the layout only
     PLACES arrays; it never rewrites the engine's programs. Params
     follow :func:`param_specs` (Megatron column/row parallel), the
-    per-layer pool ``[num_blocks, block_size, n_kv, D]`` shards along
-    ``n_kv`` (dim 2), and every other SlotState leaf — block tables,
+    per-layer pool ``[num_blocks, n_kv, block_size, D]`` shards along
+    ``n_kv`` (dim 1: kv_blocks.page_axes says where), and every other
+    SlotState leaf — block tables,
     sampling knobs, PRNG keys — replicates. Because the ``num_blocks``
     axis is whole on every device, the host's i32 block tables resolve
     per-device KV shards unchanged: a table entry names the same
@@ -313,9 +315,9 @@ class EngineLayout:
         return shard_params(params, self.mesh, cfg)
 
     def pool_sharding(self) -> NamedSharding:
-        """[num_blocks, block_size, n_kv, D]: heads shard, blocks stay
+        """[num_blocks, n_kv, block_size, D]: heads shard, blocks stay
         whole per device so logical table indices resolve everywhere."""
-        return NamedSharding(self.mesh, P(None, None, "tp", None))
+        return NamedSharding(self.mesh, P(*page_axes(1, "tp")))
 
     def scale_sharding(self) -> NamedSharding:
         """[num_blocks, n_kv] int8 dequant scales: shard along n_kv
@@ -325,10 +327,10 @@ class EngineLayout:
         return NamedSharding(self.mesh, P(None, "tp"))
 
     def tail_sharding(self) -> NamedSharding:
-        """[n_slots, 2, block_size, n_kv, D] bf16 tail pairs: n_kv
-        shards with the pool (dim 3); slots and the 2-slot tail axis
+        """[n_slots, 2, n_kv, block_size, D] bf16 tail pairs: n_kv
+        shards with the pool (dim 2); slots and the 2-slot tail axis
         stay whole per device."""
-        return NamedSharding(self.mesh, P(None, None, None, "tp", None))
+        return NamedSharding(self.mesh, P(*page_axes(2, "tp")))
 
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())

@@ -57,7 +57,11 @@ from kubeinfer_tpu.inference.flash_attention import (
     decode_attention_blocks_q8_auto,
 )
 from kubeinfer_tpu.inference.gdn import init_gdn_state
-from kubeinfer_tpu.inference.kv_blocks import quantize_blocks
+from kubeinfer_tpu.inference.kv_blocks import (
+    page_dims,
+    pool_shape,
+    quantize_blocks,
+)
 from kubeinfer_tpu.inference.model import Params, forward
 from kubeinfer_tpu.inference.moe import STATS as MOE_STATS
 
@@ -82,15 +86,19 @@ WINDOW_BUCKETS = (1, 2, 4, 8)
 class SlotState:
     """All device-resident decode state (fixed shapes).
 
-    The KV pool is SHARED across slots: row b's logical cache position
-    p lives in ``caches_k[l][tables[b, p // bs], p % bs]``. Block 0 is
+    The KV pool is SHARED across slots and stored HEAD-MAJOR (the
+    layout the block kernels read in place; kv_blocks' page layout is
+    the one place that spells the axis order): row b's logical cache
+    position p lives in ``caches_k[l][tables[b, p // bs], :, p % bs]``.
+    Block 0 is
     the reserved null block (kv_blocks.NULL_BLOCK): dead table entries
     and retired rows point there, so every gather/scatter index is
     always valid without data-dependent control flow under jit.
 
     ``kv_dtype="int8"`` (trace-static: ``caches_k[0].dtype``) adds the
     quantized-pool companions: per-(block, head) dequant scales and the
-    per-slot bf16 TAIL [B, 2, bs, n_kv, D] — slot 0 is the row's
+    per-slot bf16 TAIL [B, 2, n_kv, bs, D] (two pages a slot, in the
+    pool's layout) — slot 0 is the row's
     current partial block (logical block offset // bs), slot 1 the one
     a verify window may spill into. Decode scatters land in the tail
     (model.decoder_layer), attention overlays it past the committed
@@ -112,7 +120,7 @@ class SlotState:
     such layers have none of these leaves, and models without routed
     experts no ``moe_stats``, so their programs are what they were."""
 
-    caches_k: list[jax.Array]  # per full layer [num_blocks, bs, n_kv, D]
+    caches_k: list[jax.Array]  # per full layer [num_blocks, n_kv, bs, D]
     caches_v: list[jax.Array]
     tables: jax.Array  # i32[B, max_blocks] pool indices, seq order
     last_token: jax.Array  # i32[B]
@@ -126,7 +134,7 @@ class SlotState:
     rng: jax.Array  # u32[B, 2] per-slot PRNG key data
     scales_k: list[jax.Array]  # int8: L x f32[num_blocks, n_kv]; else []
     scales_v: list[jax.Array]
-    tails_k: list[jax.Array]  # int8: L x [B, 2, bs, n_kv, D]; else []
+    tails_k: list[jax.Array]  # int8: L x [B, 2, n_kv, bs, D]; else []
     tails_v: list[jax.Array]
     # per linear-attention layer; [] for models that have none
     gdn_state: list[jax.Array] = dataclasses.field(default_factory=list)
@@ -156,12 +164,14 @@ def init_slot_state(cfg: ModelConfig, n_slots: int, cache_len: int,
     L = len(cfg.full_attention_layers)  # the layers that hold pages
     gdn = [init_gdn_state(cfg, n_slots, dtype)
            for i in range(cfg.num_hidden_layers) if cfg.layer_is_linear(i)]
-    shape = (num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim)
+    shape = pool_shape(num_blocks, block_size, cfg.num_key_value_heads,
+                       cfg.head_dim)
     if kv_dtype == "int8":
         page_dt = jnp.int8
         sshape = (num_blocks, cfg.num_key_value_heads)
-        tshape = (n_slots, 2, block_size, cfg.num_key_value_heads,
-                  cfg.head_dim)
+        tshape = (n_slots, *pool_shape(2, block_size,
+                                       cfg.num_key_value_heads,
+                                       cfg.head_dim))
         scales_k = [jnp.ones(sshape, jnp.float32) for _ in range(L)]
         scales_v = [jnp.ones(sshape, jnp.float32) for _ in range(L)]
         tails_k = [jnp.zeros(tshape, dtype) for _ in range(L)]
@@ -417,7 +427,7 @@ def decode_body(
     cache/offset/token state is preserved unchanged. This is the scan
     body of :func:`decode_window` — kept un-jitted so the window's K
     steps trace into one program."""
-    block_size = state.caches_k[0].shape[1]
+    block_size = page_dims(state.caches_k[0])[0]
     S = state.tables.shape[1] * block_size  # logical per-row cache width
     quantized = state.caches_k[0].dtype == jnp.int8
     stats: list = []
@@ -639,7 +649,7 @@ def verify_window(
     is a first-class engine dispatch so it composes with the paged
     pool, preemption, and the sharded layout."""
     B = state.last_token.shape[0]
-    block_size = state.caches_k[0].shape[1]
+    block_size = page_dims(state.caches_k[0])[0]
     S = state.tables.shape[1] * block_size
     # a 0-layer (bigram) draft carries no KV at all — Ld then only
     # shapes the repair mask, which no layer reads; S keeps the shape

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from kubeinfer_tpu.inference.kv_blocks import pool_shape
 from kubeinfer_tpu.observability.stepprof import KERNEL_NAMES
 
 
@@ -98,7 +99,7 @@ def _decode_blocks(T, heads=QWEN2_7B, B=_B, NB=_NB):
     )
 
     nq, nkv, D = heads
-    pool = ((NB, _BS, nkv, D), BF16)
+    pool = (pool_shape(NB, _BS, nkv, D), BF16)
     return decode_attention_blocks, (
         ((B, T, nq, D), BF16), pool, pool, ((B, _MB), I32), ((B,), I32),
     )
@@ -127,9 +128,9 @@ def _decode_blocks_q8(T):
     )
 
     nq, nkv, D = QWEN2_7B
-    pool = ((_NB, _BS, nkv, D), I8)
+    pool = (pool_shape(_NB, _BS, nkv, D), I8)
     scales = ((_NB, nkv), F32)
-    tail = ((_B, 2, _BS, nkv, D), BF16)
+    tail = ((_B, *pool_shape(2, _BS, nkv, D)), BF16)
     return decode_attention_blocks_q8, (
         ((_B, T, nq, D), BF16), pool, pool, scales, scales, tail, tail,
         ((_B, _MB), I32), ((_B,), I32),
@@ -214,6 +215,98 @@ def test_compiles_for_v5e(case, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# --- the pool stays where it lies -------------------------------------------
+# decode_attention_blocks reads the pool as stored, and the token write
+# is one in-place scatter of n_kv rows a token. Either can come back as
+# a relayout of the whole pool without a test on the CPU noticing: a
+# transpose in front of the kernel, or a scatter spelled so that the
+# TPU compiler wants its operand token-major (it then copies the pool
+# there and back, every step). Only the optimised HLO shows it.
+
+# (query heads, kv heads, head_dim, hidden, slots, pool blocks): the
+# served geometry of each benchmark configuration's full-attention layer
+POOLS = {
+    "qwen2-7b": (*QWEN2_7B, 3584, 8, 513),
+    "qwen3-next": (*QWEN3_NEXT, 2048, 64, 2049),
+}
+_RELAYOUTS = re.compile(
+    r" (copy|copy-start|copy-done|transpose|fusion)\(")
+
+
+def _pool_sized(text, count):
+    """Instructions of an optimised HLO module that copy, transpose or
+    fuse to a result of ``count`` elements, other than the in-place
+    update (a fusion around the scatter)."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    found = []
+    for lines in bodies.values():
+        for line in lines:
+            op = _RELAYOUTS.search(line)
+            if not op or " = " not in line:
+                continue
+            result = line[line.index(" = "):op.start()]
+            sizes = [
+                functools.reduce(int.__mul__, map(int, dims.split(",")))
+                for dims in re.findall(r"\w\[([\d,]+)\]", result)]
+            if count not in sizes:
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            update = called and any(
+                " scatter(" in x or " dynamic-update-slice(" in x
+                for x in bodies.get(called.group(1), ()))
+            if op.group(1) != "fusion" or not update:
+                found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("model", sorted(POOLS))
+def test_decode_window_leaves_the_pool_where_it_lies(
+        model, one_chip, no_compile_cache, monkeypatch):
+    import dataclasses
+
+    from kubeinfer_tpu.inference import flash_attention as fa
+    from kubeinfer_tpu.inference.config import PRESETS
+    from kubeinfer_tpu.inference.model import init_params
+    from kubeinfer_tpu.inference.stepper import (
+        decode_window,
+        init_slot_state,
+    )
+
+    # the router asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(fa, "decode_blocks_available", lambda bs, D: True)
+    nq, nkv, D, hidden, slots, blocks = POOLS[model]
+    cfg = dataclasses.replace(
+        PRESETS["qwen2-7b"], num_hidden_layers=1, vocab_size=1024,
+        hidden_size=hidden, intermediate_size=1024,
+        num_attention_heads=nq, num_key_value_heads=nkv,
+        head_dim_override=D)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(functools.partial(
+        init_params, cfg, dtype=BF16), jax.random.PRNGKey(0)))
+    state = placed(jax.eval_shape(functools.partial(
+        init_slot_state, cfg, slots, _MB * _BS, BF16, blocks, _BS)))
+    pool = state.caches_k[0]
+    assert pool.shape == pool_shape(blocks, _BS, nkv, D)
+    text = decode_window.lower(params, state, cfg, 1).compile().as_text()
+    assert "decode_attention_blocks" in text
+    assert _pool_sized(text, pool.size) == []
+    # the parent's spelling of the read is what the guard is for
+    seen = jax.jit(lambda p: p.transpose(0, 2, 1, 3)).lower(
+        pool).compile().as_text()
+    assert _pool_sized(seen, pool.size)
+
+
 # --- the names the device profile carries ----------------------------------
 # stepprof.KERNEL_NAMES is what a reader of the profile matches; a
 # Pallas kernel's name exists only in a TPU compile, so it is held
@@ -231,9 +324,11 @@ def _named_kernel(name):
     dense = ((2, 256, nkv, D), BF16)
     qT = ((1, 128, nq, D), BF16)
     kvT = ((1, 256, nkv, D), BF16)
-    pool, pool8 = ((9, 128, nkv, D), BF16), ((9, 128, nkv, D), I8)
+    pages = pool_shape(9, 128, nkv, D)
+    pool, pool8 = (pages, BF16), (pages, I8)
     table, lens = ((2, 4), I32), ((2,), I32)
-    scales, tail = ((9, nkv), F32), ((2, 2, 128, nkv, D), BF16)
+    scales = ((9, nkv), F32)
+    tail = ((2, *pool_shape(2, 128, nkv, D)), BF16)
     return {
         "quant_matmul": (wq.quant_matmul, (
             ((8, 256), BF16), ((256, 256), I8), ((256,), F32))),

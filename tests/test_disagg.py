@@ -302,6 +302,68 @@ class TestEngineImport:
         finally:
             b.stop()
 
+    def test_wire_is_token_major_whatever_the_pool_stores(self, params):
+        """Pages leave a pool of known contents as the token-major
+        ``[layers, blocks, block_size, n_kv, D]`` arrays the wire has
+        always carried, byte for byte, and come back the same through
+        an import: the stored (head-major) layout ends at the engine's
+        edge, so no peer and no wire version can tell."""
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        from kubeinfer_tpu.inference.batching import _ImportTask
+        from kubeinfer_tpu.inference.kv_blocks import (
+            pool_shape,
+            rows_to_pages,
+        )
+
+        # never started: this thread is _state's only reader and writer
+        eng = ContinuousEngine(params, TINY, n_slots=2, cache_len=128,
+                               block_size=BS)
+        L, nkv, D = (TINY.num_hidden_layers, TINY.num_key_value_heads,
+                     TINY.head_dim)
+        pool = eng._state.caches_k[0]
+        nb = pool.shape[0]
+        assert pool.shape == pool_shape(nb, BS, nkv, D)
+        # every element names its logical address (layer, block, token,
+        # head, dim): token-major, as the parent's pool held it and put
+        # it on the wire
+        known_k = np.arange(L * nb * BS * nkv * D, dtype=np.float32) \
+            .reshape(L, nb, BS, nkv, D).astype(pool.dtype)
+        known_v = -known_k
+        eng._state = dataclasses.replace(
+            eng._state,
+            caches_k=[jnp.asarray(rows_to_pages(x)) for x in known_k],
+            caches_v=[jnp.asarray(rows_to_pages(x)) for x in known_v],
+        )
+        blocks = [5, 2, 7]
+        pk, pv = eng._export_pages(jnp.asarray(blocks, jnp.int32))
+        for got, known in ((pk, known_k), (pv, known_v)):
+            want = np.ascontiguousarray(known[:, blocks])
+            assert got.shape == (L, len(blocks), BS, nkv, D)
+            assert got.flags["C_CONTIGUOUS"]
+            assert got.tobytes() == want.tobytes()
+        toks = prompt_tokens(len(blocks) * BS)
+        fps = prefix_fingerprints(toks, BS)
+        blob = encode_payload(pk, pv, fps, BS)
+        assert blob == encode_payload(
+            known_k[:, blocks], known_v[:, blocks], fps, BS)
+
+        # import -> export: the blocks land wherever the pool has room
+        # and read back as the bytes that came in
+        payload = decode_payload(blob)
+        eng._imports.append(
+            _ImportTask(toks, payload.pages_k, payload.pages_v))
+        eng._step_import()
+        landed = eng._radix.match(toks)
+        eng._pool.unref(landed)
+        assert len(landed) == len(blocks)
+        rk, rv = eng._export_pages(jnp.asarray(landed, jnp.int32))
+        assert rk.tobytes() == pk.tobytes()
+        assert rv.tobytes() == pv.tobytes()
+        assert encode_payload(rk, rv, fps, BS) == blob
+
     def test_duplicate_import_dedups(self, params):
         p = prompt_tokens(70)
         a = mk_engine(params)

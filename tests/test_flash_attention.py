@@ -17,6 +17,7 @@ from kubeinfer_tpu.inference.flash_attention import (
     attention_auto,
     flash_attention,
 )
+from kubeinfer_tpu.inference.kv_blocks import pool_shape, rows_to_pages
 from kubeinfer_tpu.inference.model import attention as dense_attention
 
 
@@ -604,21 +605,21 @@ class TestBlockDecodeKernel:
         num_blocks = 1 + B * max_blocks + extra_blocks
         jk, jv = jax.random.split(jax.random.fold_in(key, 7))
         kp = jax.random.normal(
-            jk, (num_blocks, block_size, n_kv, D)
+            jk, pool_shape(num_blocks, block_size, n_kv, D)
         ).astype(dtype)
         vp = jax.random.normal(
-            jv, (num_blocks, block_size, n_kv, D)
+            jv, pool_shape(num_blocks, block_size, n_kv, D)
         ).astype(dtype)
         rng = np.random.default_rng(17)
         perm = rng.permutation(np.arange(1, num_blocks))
         tables = perm[: B * max_blocks].reshape(B, max_blocks)
         tables = np.ascontiguousarray(tables, np.int32)
-        kp = kp.at[tables.reshape(-1)].set(
+        kp = kp.at[tables.reshape(-1)].set(rows_to_pages(
             k.reshape(B * max_blocks, block_size, n_kv, D)
-        )
-        vp = vp.at[tables.reshape(-1)].set(
+        ))
+        vp = vp.at[tables.reshape(-1)].set(rows_to_pages(
             v.reshape(B * max_blocks, block_size, n_kv, D)
-        )
+        ))
         # dead entries (beyond each row's live blocks) point at the
         # null block, as the engine pads them — output must not care
         lens = np.asarray(lens, np.int64)
@@ -706,8 +707,8 @@ class TestBlockDecodeKernel:
             jnp.float32,
         )
         jk, jv = jax.random.split(jax.random.PRNGKey(24))
-        kp = jax.random.normal(jk, (6, bs, n_kv, D))
-        vp = jax.random.normal(jv, (6, bs, n_kv, D))
+        kp = jax.random.normal(jk, pool_shape(6, bs, n_kv, D))
+        vp = jax.random.normal(jv, pool_shape(6, bs, n_kv, D))
         tables = jnp.asarray(
             [[5, 2], [5, 4], [5, 1]], jnp.int32  # block 5 shared 3-ways
         )
@@ -768,21 +769,21 @@ class TestKQueryBlockDecode:
         num_blocks = 1 + B * max_blocks + 3
         jk, jv = jax.random.split(jax.random.fold_in(key, 7))
         kp = jax.random.normal(
-            jk, (num_blocks, block_size, n_kv, D)
+            jk, pool_shape(num_blocks, block_size, n_kv, D)
         ).astype(dtype)
         vp = jax.random.normal(
-            jv, (num_blocks, block_size, n_kv, D)
+            jv, pool_shape(num_blocks, block_size, n_kv, D)
         ).astype(dtype)
         rng = np.random.default_rng(29)
         perm = rng.permutation(np.arange(1, num_blocks))
         tables = perm[: B * max_blocks].reshape(B, max_blocks)
         tables = np.ascontiguousarray(tables, np.int32)
-        kp = kp.at[tables.reshape(-1)].set(
+        kp = kp.at[tables.reshape(-1)].set(rows_to_pages(
             k.reshape(B * max_blocks, block_size, n_kv, D)
-        )
-        vp = vp.at[tables.reshape(-1)].set(
+        ))
+        vp = vp.at[tables.reshape(-1)].set(rows_to_pages(
             v.reshape(B * max_blocks, block_size, n_kv, D)
-        )
+        ))
         lens = np.asarray(lens, np.int64)
         for b in range(B):
             live = -(-int(lens[b]) // block_size)
@@ -888,3 +889,140 @@ class TestKQueryBlockDecode:
             ),
             atol=2e-5, rtol=1e-4,
         )
+
+
+def _pool_transposes(jaxpr, num_blocks):
+    """Every ``transpose`` in ``jaxpr`` (sub-jaxprs included: scan
+    bodies, pallas_call kernels, conds) whose operand leads with
+    ``num_blocks``: a whole-pool relayout."""
+    found = []
+    for eqn in jaxpr.eqns:
+        shape = getattr(eqn.invars[0].aval, "shape", ()) \
+            if eqn.invars else ()
+        if eqn.primitive.name == "transpose" and shape[:1] == (
+                num_blocks,):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pool_transposes(sub, num_blocks)
+    return found
+
+
+class TestStoredLayout:
+    """The pool is stored head-major, the layout the block kernels
+    read: nothing transposes a pool in front of a call or inside a
+    step, and a token written through the tables is the token the
+    dense cache path writes."""
+
+    NB = 37  # a prime no other axis of these shapes has
+
+    def _operands(self, T, quantized):
+        B, M, bs, nq, nkv, D = 2, 2, 16, 4, 2, 8
+        pages = pool_shape(self.NB, bs, nkv, D)
+        tail = (B, *pool_shape(2, bs, nkv, D))
+        q = jnp.zeros((B, T, nq, D), jnp.bfloat16)
+        tables = jnp.ones((B, M), jnp.int32)
+        lens = jnp.full((B,), bs + T, jnp.int32)
+        if not quantized:
+            pool = jnp.zeros(pages, jnp.bfloat16)
+            return q, pool, pool, tables, lens
+        pool = jnp.zeros(pages, jnp.int8)
+        scales = jnp.ones((self.NB, nkv), jnp.float32)
+        tails = jnp.zeros(tail, jnp.bfloat16)
+        return q, pool, pool, scales, scales, tails, tails, tables, lens
+
+    @pytest.mark.parametrize("T", [1, 3])
+    @pytest.mark.parametrize("fn", [
+        "decode_attention_blocks", "decode_attention_blocks_jnp",
+        "decode_attention_blocks_q8", "decode_attention_blocks_q8_jnp",
+    ])
+    def test_no_pool_transpose_in_front_of_the_kernel(self, fn, T):
+        import kubeinfer_tpu.inference.flash_attention as fa
+
+        ops = self._operands(T, quantized="q8" in fn)
+        jaxpr = jax.make_jaxpr(getattr(fa, fn))(*ops).jaxpr
+        assert _pool_transposes(jaxpr, self.NB) == []
+        # the check can see one: the parent's spelling of the read
+        seen = jax.make_jaxpr(lambda p: p.transpose(0, 2, 1, 3))(ops[1])
+        assert len(_pool_transposes(seen.jaxpr, self.NB)) == 1
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    @pytest.mark.parametrize("route", ["kernel", "dense"])
+    def test_no_pool_transpose_in_a_decode_window(self, route, kv_dtype,
+                                                  monkeypatch):
+        import kubeinfer_tpu.inference.flash_attention as fa
+        from kubeinfer_tpu.inference import PRESETS, init_params
+        from kubeinfer_tpu.inference.stepper import (
+            decode_window,
+            init_slot_state,
+        )
+
+        # trace the step both ways a server takes it: the Pallas
+        # branch (TPU) and the gather + dense branch (CPU, GSPMD)
+        monkeypatch.setattr(fa, "decode_blocks_available",
+                            lambda bs, D: route == "kernel")
+        cfg = PRESETS["tiny"]
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        state = init_slot_state(cfg, 2, 64, jnp.float32, self.NB, 16,
+                                kv_dtype=kv_dtype)
+        assert state.caches_k[0].shape[0] == self.NB
+        jaxpr = jax.make_jaxpr(
+            lambda p, s: decode_window.__wrapped__(p, s, cfg, 1)
+        )(params, state).jaxpr
+        assert _pool_transposes(jaxpr, self.NB) == []
+
+    @pytest.mark.parametrize("T", [1, 3])
+    def test_paged_write_reads_back_as_the_dense_write(self, T):
+        """T tokens a row written through the tables into head-major
+        pages, then read back through the tables, are the same tokens
+        ``forward`` writes into a dense [B, S, n_kv, D] cache at the
+        same positions, and attention over either gives the same
+        logits (T = 1 the decode step, T = 3 the verify window)."""
+        import kubeinfer_tpu.inference.flash_attention as fa
+        from kubeinfer_tpu.inference import PRESETS, init_params
+        from kubeinfer_tpu.inference.model import forward
+
+        cfg = PRESETS["tiny"]
+        params = init_params(cfg, jax.random.PRNGKey(1))
+        B, M, bs = 3, 4, 16
+        S = M * bs
+        nkv, D = cfg.num_key_value_heads, cfg.head_dim
+        L = cfg.num_hidden_layers
+        keys = iter(jax.random.split(jax.random.PRNGKey(2), 4 * L + 1))
+        rng = np.random.default_rng(5)
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, 1 + B * M)).reshape(B, M),
+            jnp.int32)
+        nb = 1 + B * M + 2
+        dense, paged = [], []
+        for _ in range(L):
+            pair_d, pair_p = [], []
+            for _ in range(2):
+                rows = jax.random.normal(next(keys), (B, S, nkv, D))
+                pool = jax.random.normal(
+                    next(keys), pool_shape(nb, bs, nkv, D))
+                pool = pool.at[tables.reshape(-1)].set(rows_to_pages(
+                    rows.reshape(B * M, bs, nkv, D)))
+                pair_d.append(rows)
+                pair_p.append(pool)
+            dense.append(tuple(pair_d))
+            paged.append(tuple(pair_p))
+        # offsets that cross a block edge inside the window
+        offset = jnp.asarray([bs - 1, 2 * bs - 2, 5], jnp.int32)
+        toks = jax.random.randint(next(keys), (B, T), 0, cfg.vocab_size)
+        pos = offset[:, None] + jnp.arange(T)[None, :]
+        mask = jnp.arange(S)[None, None, :] <= pos[:, :, None]
+
+        def attn(q, kp, vp, m):
+            return fa.decode_attention_blocks_auto(
+                q, kp, vp, tables, offset + T, m)
+
+        kw = dict(positions=pos, attn_mask=mask, cache_offset=offset)
+        want, dense = forward(params, toks, cfg, kv_caches=dense, **kw)
+        got, paged = forward(params, toks, cfg, kv_caches=paged,
+                             block_tables=tables, attn_fn=attn, **kw)
+        for (dk, dv), (pk, pv) in zip(dense, paged):
+            np.testing.assert_array_equal(
+                np.asarray(fa.gather_block_kv(pk, tables)), np.asarray(dk))
+            np.testing.assert_array_equal(
+                np.asarray(fa.gather_block_kv(pv, tables)), np.asarray(dv))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

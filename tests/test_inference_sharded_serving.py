@@ -110,7 +110,7 @@ class TestEngineLayout:
         lay = EngineLayout.build(2)
         assert lay.sharded and lay.mesh_devices == 2
         assert "tp" in lay.mesh.axis_names
-        assert lay.pool_sharding().spec == P(None, None, "tp", None)
+        assert lay.pool_sharding().spec == P(None, "tp", None, None)
 
     def test_mesh_iff_sharded(self):
         with pytest.raises(ValueError, match="mesh"):
@@ -336,3 +336,38 @@ class TestCompileDiscipline:
             assert eng.profiler.compile_count == c0 + 1
         finally:
             eng.stop()
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_sharded_window_never_gathers_the_pool(self, kv_dtype):
+        """A decode window over a pool sharded by KV head moves the
+        step's own rows between devices (a token's K/V, B x n_kv x D)
+        and nothing of the pool's size: each device writes and reads
+        its own heads' slice of every page. The token write is spelled
+        as a scatter over a reshape of the pool (kv_blocks.write_tokens)
+        and a reshape that merged n_kv with the axis above it would be
+        answered with an all-gather of the pool in every step."""
+        import re
+
+        import jax.numpy as jnp
+
+        from kubeinfer_tpu.inference.stepper import (
+            decode_window,
+            init_slot_state,
+        )
+
+        lay = EngineLayout.build(4)
+        gqa = init_params(GQA, jax.random.PRNGKey(3))
+        state = init_slot_state(GQA, 4, 64, jnp.float32, 33, 16,
+                                kv_dtype=kv_dtype)
+        pool = state.caches_k[0]
+        text = decode_window.lower(
+            lay.shard_params(gqa, GQA), lay.shard_state(state), GQA, 1,
+            sharded=True,
+        ).compile().as_text()
+        moved = [
+            int(np.prod([int(d) for d in dims.split(",")]))
+            for dims in re.findall(
+                r"= \w+\[([\d,]+)\]\S* (?:all-gather|all-to-all|"
+                r"collective-permute|all-reduce)\(", text)
+        ]
+        assert moved and max(moved) < pool.size // lay.tp

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import kubeinfer_tpu.inference.flash_attention as fa
 from kubeinfer_tpu.inference.kv_blocks import (
     dequantize_blocks,
+    pool_shape,
     quantize_blocks,
 )
 from kubeinfer_tpu.inference.model import attention as dense_attention
@@ -39,19 +40,21 @@ def _paged_q8(key, B, max_blocks, block_size, n_heads, n_kv, D, lens,
     )
     num_blocks = 1 + B * max_blocks + 3
     kp = jax.random.randint(
-        kk, (num_blocks, block_size, n_kv, D), -127, 128, jnp.int32
+        kk, pool_shape(num_blocks, block_size, n_kv, D), -127, 128,
+        jnp.int32
     ).astype(jnp.int8)
     vp = jax.random.randint(
-        kv, (num_blocks, block_size, n_kv, D), -127, 128, jnp.int32
+        kv, pool_shape(num_blocks, block_size, n_kv, D), -127, 128,
+        jnp.int32
     ).astype(jnp.int8)
     # positive, spread over two orders of magnitude like real absmax
     ksc = jnp.exp(jax.random.normal(ks1, (num_blocks, n_kv))) * 0.01
     vsc = jnp.exp(jax.random.normal(ks2, (num_blocks, n_kv))) * 0.01
     kt = jax.random.normal(
-        kt1, (B, 2, block_size, n_kv, D), jnp.float32
+        kt1, (B, *pool_shape(2, block_size, n_kv, D)), jnp.float32
     ).astype(jnp.bfloat16)
     vt = jax.random.normal(
-        kt2, (B, 2, block_size, n_kv, D), jnp.float32
+        kt2, (B, *pool_shape(2, block_size, n_kv, D)), jnp.float32
     ).astype(jnp.bfloat16)
     rng = np.random.default_rng(17)
     perm = rng.permutation(np.arange(1, num_blocks))
@@ -133,14 +136,14 @@ class TestQuantRoundTrip:
         # scale = amax/127 per (block, head) — the PINNED bound the
         # tolerance-based parity gates lean on
         x = jax.random.normal(
-            jax.random.PRNGKey(3), (8, 16, 4, 32), jnp.float32
+            jax.random.PRNGKey(3), pool_shape(8, 16, 4, 32), jnp.float32
         ).astype(jnp.bfloat16)
         q, s = quantize_blocks(x)
         deq = dequantize_blocks(q, s, dtype=jnp.float32)
         err = jnp.abs(deq - x.astype(jnp.float32))
-        bound = s[:, None, :, None] / 2.0 * (1.0 + 1e-5)
+        bound = s[:, :, None, None] / 2.0 * (1.0 + 1e-5)
         assert bool(jnp.all(err <= bound)), float(jnp.max(err / bound))
-        amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-3, -1))
+        amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(-2, -1))
         np.testing.assert_allclose(
             np.asarray(s), np.asarray(amax) / 127.0, rtol=1e-6
         )
@@ -148,7 +151,7 @@ class TestQuantRoundTrip:
     def test_zero_block_scale_one(self):
         # all-zero blocks must quantize losslessly with scale 1.0 (not
         # 0, which would NaN the dequant; not amax=0/127)
-        x = jnp.zeros((2, 8, 2, 4), jnp.bfloat16)
+        x = jnp.zeros(pool_shape(2, 8, 2, 4), jnp.bfloat16)
         q, s = quantize_blocks(x)
         assert bool(jnp.all(q == 0))
         np.testing.assert_array_equal(np.asarray(s), 1.0)
@@ -159,7 +162,7 @@ class TestQuantRoundTrip:
         # +-127, so the recovered scale round-trips — the invariant
         # that lets chunked prefill re-scatter already-committed blocks
         x = jax.random.normal(
-            jax.random.PRNGKey(9), (6, 16, 2, 16), jnp.float32
+            jax.random.PRNGKey(9), pool_shape(6, 16, 2, 16), jnp.float32
         ).astype(jnp.bfloat16)
         q1, s1 = quantize_blocks(x)
         q2, s2 = quantize_blocks(dequantize_blocks(q1, s1))
