@@ -55,6 +55,7 @@ import numpy as np
 
 from kubeinfer_tpu.inference.config import ModelConfig
 from kubeinfer_tpu.inference.engine import _bucket, record_seen
+from kubeinfer_tpu.inference.flash_attention import decode_blocks_pages
 from kubeinfer_tpu.inference.kv_blocks import (
     BlockPool,
     RadixCache,
@@ -1558,6 +1559,7 @@ class ContinuousEngine:
             "dispatches": dispatches,
             "decode_steps": decode_steps,
             "decode_row_steps": self.profiler.decode_row_steps,
+            "attn_pages": self.profiler.attn_pages,
             "prefill_tokens": dict(self.prefill_tokens),
             "prefix_refused": dict(self.prefix_refused),
             "moe": dict(self.moe_counts),
@@ -2202,6 +2204,20 @@ class ContinuousEngine:
             _TRACER.finish(sp, end=now)
             self._maybe_retire(slot)
 
+    def _release_row(self, slot: int) -> None:
+        """Return a freed slot's device row to the state a never-used
+        slot has: inactive, its table all-null BEFORE the next decode
+        scatter (freed blocks may be re-issued to another slot, and a
+        stale table would keep writing into them), and its offset 0, so
+        that the paged decode kernel walks one null page for it rather
+        than as many as its last occupant held."""
+        self._state = dataclasses.replace(
+            self._state,
+            active=self._state.active.at[slot].set(False),
+            tables=self._state.tables.at[slot].set(0),
+            offset=self._state.offset.at[slot].set(0),
+        )
+
     def _maybe_retire(self, slot: int) -> None:
         req = self._slot_req[slot]
         if req is None:
@@ -2226,14 +2242,7 @@ class ContinuousEngine:
                 # drop the slot's hold; blocks also cached in the trie
                 # keep the trie's reference and stay reusable
                 self._pool.unref(blocks)
-            self._state = dataclasses.replace(
-                self._state,
-                active=self._state.active.at[slot].set(False),
-                # the row's table goes all-null BEFORE its next decode
-                # scatter: freed blocks may be re-issued to another
-                # slot, and a stale table would keep writing into them
-                tables=self._state.tables.at[slot].set(0),
-            )
+            self._release_row(slot)
             req.t_done = tracing.now()
             self._note("retire", req=req.rid, slot=slot,
                        tokens=len(req.out_tokens),
@@ -2298,14 +2307,7 @@ class ContinuousEngine:
         self._migrate_cursor.pop(slot, None)  # slot id becomes reusable
         if blocks:
             self._pool.unref(blocks)
-        self._state = dataclasses.replace(
-            self._state,
-            active=self._state.active.at[slot].set(False),
-            # all-null BEFORE the next decode scatter: freed blocks may
-            # be re-issued to another slot, and a stale table would
-            # keep writing into them
-            tables=self._state.tables.at[slot].set(0),
-        )
+        self._release_row(slot)
         req.t_parked = tracing.now()
         req.preemptions += 1
         self.preempted_total += 1
@@ -2364,11 +2366,7 @@ class ContinuousEngine:
             self._migrate_cursor.pop(slot, None)
             if blocks:
                 self._pool.unref(blocks)
-            self._state = dataclasses.replace(
-                self._state,
-                active=self._state.active.at[slot].set(False),
-                tables=self._state.tables.at[slot].set(0),
-            )
+            self._release_row(slot)
         self._mark_migrated(req, streamed)
 
     def _step_drain(self) -> None:
@@ -2794,12 +2792,22 @@ class ContinuousEngine:
             # (active=False, null tables); they are padding in the
             # decode dispatch, not live rows
             prefilling = {t.slot for t in self._prefills}
+            decoding = np.array([
+                r is not None and s not in prefilling
+                for s, r in enumerate(self._slot_req)
+            ])
             budgets = [
                 r.max_new - len(r.out_tokens)
-                for s, r in enumerate(self._slot_req)
-                if r is not None and s not in prefilling
+                for d, r in zip(decoding, self._slot_req) if d
             ]
             decode_rows = len(budgets)
+            # each row's device offset (a free or mid-prefill row's is
+            # 0, see _release_row): the window's attention lengths,
+            # and so the pages the paged kernel walks, follow from it
+            offsets = np.array([
+                len(r.prompt) + len(r.out_tokens) - 1 if d else 0
+                for d, r in zip(decoding, self._slot_req)
+            ], np.int64)
             # verify windows are all-or-nothing: a live row whose
             # admit fell back to the plain block budget (_plan_kv
             # spec_ok=False) has no +spec_k slack, and the fused
@@ -2809,9 +2817,7 @@ class ContinuousEngine:
             spec_ready = self.spec_draft is not None and bool(
                 budgets
             ) and all(
-                self._slot_spec_ok[s]
-                for s, r in enumerate(self._slot_req)
-                if r is not None and s not in prefilling
+                ok for ok, d in zip(self._slot_spec_ok, decoding) if d
             )
             waiting = len(self._holdover)
             host_work = (
@@ -2924,13 +2930,15 @@ class ContinuousEngine:
             # proxy (first-seen phase/bucket) stay honest about
             # which compiled shape ran; bucket is spec_k (one
             # compiled verify shape per K)
+            T = self.spec_k + 1
             self.profiler.record(
                 "verify", bucket=self.spec_k,
                 live_rows=decode_rows, live_tokens=accepted,
-                padded_tokens=(
-                    self.n_slots * (self.spec_k + 1) - accepted
-                ),
+                padded_tokens=self.n_slots * T - accepted,
                 start=step_t0, end=step_t, steps=self.spec_k,
+                # one forward scores the window's T queries a row
+                attn_pages=decode_blocks_pages(
+                    offsets + T, T, self.block_size, self.max_blocks),
             )
         elif decode_rows:
             k = self._pick_horizon(budgets, host_work)
@@ -3017,5 +3025,12 @@ class ContinuousEngine:
                 live_tokens=accepted,
                 padded_tokens=self.n_slots * k - accepted,
                 start=step_t0, end=step_t, steps=k,
+                # step j hands the kernel offset + j + 1 for a row
+                # that decodes, offset + 1 for any other
+                attn_pages=sum(
+                    decode_blocks_pages(
+                        offsets + np.where(decoding, j + 1, 1), 1,
+                        self.block_size, self.max_blocks)
+                    for j in range(k)),
             )
         self._step_prefill()  # at most one chunk per pass

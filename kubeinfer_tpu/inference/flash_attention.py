@@ -70,6 +70,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -671,33 +672,56 @@ def decode_attention_auto(q, k, v, lengths, mask, gspmd=False):
 # The serving engine's KV lives in a shared pool stored HEAD-MAJOR,
 # [num_blocks, n_kv, block_size, D] (kv_blocks' page layout); each batch
 # row owns an i32[max_blocks] table naming its blocks in sequence order.
-# The decode kernel below is _decode_attn_kernel with one change: the k/v
-# index_map resolves the S-tile index through the scalar-prefetched
-# table, so a tile IS one head's [block_size, D] plane of a pool block,
-# read where it lies (the pool is the pallas_call's operand as stored:
-# nothing of the pool's shape is copied in front of a call), and rows
-# sharing a prefix DMA the same physical blocks. No reference
-# counterpart — the reference hands paging to the vLLM subprocess
-# (vllm.go:93-112); vLLM's PagedAttention (Kwon et al. 2023) is the
-# design source.
+# The decode kernel below runs one grid step a batch row and walks only
+# the row's live pages: a page of every KV head is one contiguous
+# [n_kv, block_size, D] slab of the pool, copied where it lies (the pool
+# is the pallas_call's operand as stored: nothing of the pool's shape is
+# copied in front of a call), and rows sharing a prefix read the same
+# physical blocks. No reference counterpart — the reference hands paging
+# to the vLLM subprocess (vllm.go:93-112); vLLM's PagedAttention (Kwon
+# et al. 2023) is the design source.
 #
 # Table contract: EVERY entry of every row — including entries past the
 # row's live blocks — must be a valid pool index (the host pads with the
-# reserved null block 0). Dead entries are never folded (same exact-zero
-# skip as the linear kernel) but the index_map still names them when
-# row_len == 0, and the twin gathers them unconditionally.
+# reserved null block 0). The kernel never reads an entry past a row's
+# live pages (except for rows shorter than the window, which walk the
+# whole table), but the twin gathers them unconditionally.
+
+
+def _pages_to_walk(row_len, n_q, block_size, n_blocks, xp=jnp):
+    """Pages the block kernel walks for a row of ``row_len`` live
+    positions under an ``n_q``-query window: the live pages, or the
+    whole table for rows shorter than the window (the kernel's dense
+    fallback, see _decode_blocks_kernel). Elementwise over arrays, so
+    the host counts with ``xp=np`` what the kernel walks with jnp."""
+    live = xp.minimum((row_len + block_size - 1) // block_size, n_blocks)
+    return xp.where(row_len < n_q, n_blocks, live)
+
+
+def decode_blocks_pages(lengths, n_q: int, block_size: int,
+                        n_blocks: int) -> int:
+    """Pages one decode_attention_blocks call walks, summed over the
+    batch's rows, from the ``lengths`` it is handed: host arithmetic
+    (no device work), the kubeinfer_engine_attn_pages_total counter's
+    unit."""
+    lens = np.asarray(lengths, np.int64)
+    return int(_pages_to_walk(lens, n_q, block_size, n_blocks, xp=np).sum())
 
 
 def _decode_blocks_kernel(
     tbl_ref,  # scalar-prefetch i32[B, max_blocks]: per-row block tables
     len_ref,  # scalar-prefetch i32[B]: per-row live lengths
-    q_ref,  # [1, T*G, D] — the row's T queries, (t, group) as MXU rows
-    k_ref,  # [1, 1, block_size, D] — one pool block for one kv head
-    v_ref,  # [1, 1, block_size, D]
-    o_ref,  # [1, T*G, D] out
-    m_scr,  # f32[T*G, 1]
-    l_scr,  # f32[T*G, 1]
-    acc_scr,  # f32[T*G, D]
+    q_ref,  # [1, n_kv, T*G, D] — the row's T queries, (t, group) rows
+    k_hbm,  # [num_blocks, n_kv, block_size, D] pool, left in HBM
+    v_hbm,  # [num_blocks, n_kv, block_size, D]
+    o_ref,  # [1, n_kv, T*G, D] out
+    k_buf,  # [2, n_kv, block_size, D]: two pages in flight
+    v_buf,  # [2, n_kv, block_size, D]
+    sem,  # DMA semaphores [2 sides, 2 buffers]
+    base_ref,  # SMEM i32[1]: pages fetched by earlier rows
+    m_scr,  # f32[n_kv, T*G, 1]
+    l_scr,  # f32[n_kv, T*G, 1]
+    acc_scr,  # f32[n_kv, T*G, D]
     *,
     groups: int,
     scale: float,
@@ -706,63 +730,105 @@ def _decode_blocks_kernel(
     n_kv: int,
     n_q: int,
 ):
-    """_decode_attn_kernel over a paged cache: the grid's S axis walks
-    the row's block table (resolved in the index_map — tbl_ref is unused
-    here) and the penalty is derived from the LOGICAL position
-    ts * block_size + i, so the fold math is position-for-position the
-    linear kernel's. Same bit-identical skip/clamp story: a tile past
-    the live length folds exactly 0, rows shorter than the window stay
-    dense over whatever their (null-padded) table names.
+    """One grid step a batch row: fold the row's live pages, in
+    ascending order, for every KV head with the shared fold, the
+    penalty derived from the LOGICAL position i * block_size + j. The
+    walk's length comes from the scalar-prefetched row length
+    (_pages_to_walk), so the kernel's cost follows the pages a step
+    must read, not B * n_kv * max_blocks grid steps. Pages past the
+    live length are not walked: folded, they would contribute exactly
+    0 (the twin folds them; see _softmax_fold's active note), so the
+    output is the twin's bit for bit.
+
+    Pages are double-buffered across rows, not only within one: the
+    grid runs in order on one core, so the copy of the next page —
+    the row's own, or the first of the next row's — starts before the
+    current page is folded, and buffer (base + i) % 2 holds the row's
+    i-th page, ``base`` counting the pages earlier rows fetched.
 
     ``n_q`` > 1 is the speculative verify window: query t of a row sits
     at logical position row_len - n_q + t (the window's tokens are the
     cache's LAST n_q positions, scattered by the caller before the
     read), so the causal mask within the window is the only new math —
     pen row t admits s_pos <= row_len - n_q + t, which at n_q == 1
-    reduces exactly to the decode rule s_pos < row_len. The live tile
-    set is unchanged (the last query attends precisely s_pos < row_len),
-    so the skip predicate needs no T term — EXCEPT rows with
-    row_len < n_q, whose leading queries are fully masked: their
-    uniform-over-junk output depends on every tile the twin folds, so
-    the dense fallback generalizes from row_len == 0 to row_len < n_q
-    (identical at n_q == 1; the engine never emits such rows, since a
-    verify dispatch sets lengths = offset + T, but the twin contract
-    must hold on the whole operand domain)."""
-    del tbl_ref  # consumed by the BlockSpec index_map, not the body
-    row_len = len_ref[pl.program_id(0) // n_kv]
-    ts = pl.program_id(1)  # innermost: table walk with resident scratch
+    reduces exactly to the decode rule s_pos < row_len. The live page
+    set is unchanged (the last query attends precisely s_pos <
+    row_len) — EXCEPT rows with row_len < n_q, whose leading queries
+    are fully masked: their uniform-over-junk output depends on every
+    page the twin folds, so they walk the whole (null-padded) table
+    (identical at n_q == 1 to the row_len == 0 rule; the engine never
+    emits such rows, since a dispatch sets lengths = offset + T, but
+    the twin contract must hold on the whole operand domain)."""
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    row_len = len_ref[b]
+    n_walk = _pages_to_walk(row_len, n_q, block_size, n_blocks)
 
-    @pl.when(ts == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -1e30)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def copies(row, i, buf):
+        blk = tbl_ref[row, i]
+        return (
+            pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[buf],
+                                  sem.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[buf],
+                                  sem.at[1, buf]),
+        )
 
-    @pl.when((ts == 0) | (row_len < n_q) | (ts * block_size < row_len))
-    def _fold():
-        s_pos = ts * block_size + jax.lax.broadcasted_iota(
+    @pl.when(b == 0)
+    def _prologue():
+        base_ref[0] = 0
+        for c in copies(0, 0, 0):
+            c.start()
+
+    base = base_ref[0]
+    m_scr[:] = jnp.full_like(m_scr, -1e30)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def page(i, carry):
+        buf = jax.lax.rem(base + i, 2)
+        nxt = 1 - buf
+
+        @pl.when(i + 1 < n_walk)
+        def _own_next():
+            for c in copies(b, i + 1, nxt):
+                c.start()
+
+        @pl.when((i + 1 == n_walk) & (b + 1 < n_rows))
+        def _next_row():
+            for c in copies(b + 1, 0, nxt):
+                c.start()
+
+        for c in copies(b, i, buf):
+            c.wait()
+        s_pos = i * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (n_q, block_size), 1
         )
         q_pos = row_len - n_q + jax.lax.broadcasted_iota(
             jnp.int32, (n_q, block_size), 0
         )
         pen = jnp.where(s_pos <= q_pos, 0.0, -1e30)
-        m_new, l_new, acc_new = _fold_tile_math(
-            q_ref[0], k_ref[0, 0], v_ref[0, 0], pen,
-            m_scr[:], l_scr[:], acc_scr[:],
-            groups=groups, scale=scale,
-        )
-        l_scr[:] = l_new
-        acc_scr[:] = acc_new
-        m_scr[:] = m_new
+        for h in range(n_kv):
+            m_new, l_new, acc_new = _fold_tile_math(
+                q_ref[0, h], k_buf[buf, h], v_buf[buf, h], pen,
+                m_scr[h], l_scr[h], acc_scr[h],
+                groups=groups, scale=scale,
+            )
+            l_scr[h] = l_new
+            acc_scr[h] = acc_new
+            m_scr[h] = m_new
+        return carry
 
-    @pl.when(ts == n_blocks - 1)
-    def _finish():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(
+    jax.lax.fori_loop(0, n_walk, page, 0)
+    base_ref[0] = base + n_walk
+    for h in range(n_kv):
+        o_ref[0, h] = (acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)).astype(
             o_ref.dtype
         )
 
 
+# jitted so that a step program lowers the kernel once, not once a layer:
+# Pallas lowers every call site of a pallas_call anew (as quant_matmul)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_blocks(
     q: jax.Array,  # [B, T, n_heads, D] — the row's last T tokens
     k_pool: jax.Array,  # [num_blocks, n_kv, block_size, D] shared pool
@@ -774,55 +840,35 @@ def decode_attention_blocks(
 ) -> jax.Array:
     """Paged decode attention: row b's logical cache position p lives in
     pool block block_tables[b, p // block_size] at slot p % block_size.
-    Both scalar operands prefetch; the k/v index_map clamps the table
-    walk past each row's last live block (same DMA-elision contract as
-    decode_attention) and then indirects through the table, so shared
-    prefix blocks are fetched once per consecutive reuse rather than
-    duplicated per row. T > 1 is the speculative verify window: query t
+    Both scalar operands prefetch; each grid step is one row, which
+    copies only its live pages (every KV head of a page in one DMA)
+    through the table, so the call's cost follows the live pages, not
+    the table's width. T > 1 is the speculative verify window: query t
     attends cache positions <= lengths[b] - T + t (the window occupies
     the row's last T live positions, already scattered into the pool by
     the caller), folded causally inside the kernel's penalty — the T
-    axis rides the MXU row dim next to the GQA groups, so the tile walk
-    and DMA schedule are the T == 1 kernel's unchanged. Twin:
-    decode_attention_blocks_jnp (bit-identical, parity-tested in
-    tests/test_flash_attention.py)."""
+    axis rides the MXU row dim next to the GQA groups, so the page walk
+    is the T == 1 kernel's unchanged. Twin: decode_attention_blocks_jnp
+    (bit-identical, parity-tested in tests/test_flash_attention.py)."""
     B, T, n_heads, D = q.shape
     block_size, n_kv, _ = page_dims(k_pool)
     max_blocks = block_tables.shape[1]
     G = n_heads // n_kv
 
-    # (t, group) flatten with t OUTER: _fold_tile_math reshapes rows as
-    # (tq, groups, sk) + pen[:, None, :], so pen row t must cover the
-    # contiguous run of G MXU rows belonging to query t.
+    # (t, group) flatten with t OUTER within a head: _fold_tile_math
+    # reshapes rows as (tq, groups, sk) + pen[:, None, :], so pen row t
+    # must cover the contiguous run of G MXU rows belonging to query t.
     qf = q.reshape(B, T, n_kv, G, D).transpose(0, 2, 1, 3, 4).reshape(
-        B * n_kv, T * G, D
+        B, n_kv, T * G, D
     )
     tbl = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
 
-    def _kv_map(bh, ts, tbl_ref, lens_ref, n_kv=n_kv, bs=block_size,
-                nq=T):
-        # Same clamp as decode_attention's _kv_map, then the table
-        # lookup: dead steps re-name the row's last live block so
-        # Pallas elides their DMAs. Rows shorter than the window
-        # (row_len < nq, the kernel's dense-fallback predicate) walk
-        # their true (null-padded) table — their defined output is the
-        # uniform average over what the table names, mirroring the
-        # twin, which a clamp would silently re-point at live data.
-        b = bh // n_kv
-        rl = lens_ref[b]
-        live_last = jnp.maximum(rl - 1, 0) // bs
-        step = jnp.where(rl < nq, ts, jnp.minimum(ts, live_last))
-        return (tbl_ref[b, step], bh % n_kv, 0, 0)
-
     q_spec = pl.BlockSpec(
-        (1, T * G, D), lambda bh, ts, tbl_ref, lens_ref: (bh, 0, 0),
+        (1, n_kv, T * G, D), lambda b, tbl_ref, lens_ref: (b, 0, 0, 0),
         memory_space=pltpu.VMEM,
     )
-    # one (block, head) pair per tile, of the pool as stored
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_size, D), _kv_map, memory_space=pltpu.VMEM
-    )
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(
             _decode_blocks_kernel, groups=G, scale=1.0 / float(D) ** 0.5,
@@ -831,16 +877,25 @@ def decode_attention_blocks(
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B * n_kv, max_blocks),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            grid=(B,),
+            in_specs=[q_spec, pool_spec, pool_spec],
             out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((T * G, 1), jnp.float32),
-                pltpu.VMEM((T * G, 1), jnp.float32),
-                pltpu.VMEM((T * G, D), jnp.float32),
+                pltpu.VMEM((2, n_kv, block_size, D), k_pool.dtype),
+                pltpu.VMEM((2, n_kv, block_size, D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((n_kv, T * G, 1), jnp.float32),
+                pltpu.VMEM((n_kv, T * G, 1), jnp.float32),
+                pltpu.VMEM((n_kv, T * G, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B * n_kv, T * G, D), q.dtype),
+        # the rows run in order on one core: a row's first page is
+        # fetched by the row before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, T * G, D), q.dtype),
         interpret=interpret,
         name="decode_attention_blocks",
     )(tbl, lens, qf, k_pool, v_pool)
@@ -857,10 +912,11 @@ def decode_attention_blocks_jnp(
     lengths: jax.Array,  # i32[B]
 ) -> jax.Array:
     """The block kernel's jnp twin: the SAME _fold_tile_math walked
-    per-row with lax.map and per-block with lax.scan, gathering each
-    tile through the row's table exactly as the kernel's index_map does
-    (minus the clamp — dead tiles fold exactly 0 either way, see
-    decode_attention_jnp's note). T > 1 mirrors the kernel's in-window
+    per-(row, head) with lax.map and per-block with lax.scan, gathering
+    each tile through the row's table as the kernel's page copies do,
+    but over the whole table — pages past the live length, which the
+    kernel never walks, fold exactly 0 (see decode_attention_jnp's
+    note). T > 1 mirrors the kernel's in-window
     causal penalty (query t at logical position rl - T + t). Because a
     gathered block holds the same values as the linear cache's
     corresponding tile, this twin is also bitwise equal to

@@ -244,6 +244,13 @@ def _serving_metrics(registry: Registry):
             "mean live rows of a step)",
             registry=registry,
         ),
+        "attn_pages": Counter(
+            "kubeinfer_engine_attn_pages_total",
+            "Pages the paged decode attention kernel walks, summed over "
+            "the rows and model steps of decode and verify windows, once "
+            "a step (over decode_steps_total: the pages a step)",
+            registry=registry,
+        ),
         "prefill_tokens": Counter(
             "kubeinfer_engine_prefill_tokens_total",
             "Prompt tokens of admitted requests: computed (run through "
@@ -881,6 +888,7 @@ class InferenceServer:
                 ("migration_chunks", "migration_chunks"),
                 ("decode_steps", "decode_steps"),
                 ("decode_row_steps", "decode_row_steps"),
+                ("attn_pages", "attn_pages"),
             ):
                 delta = sched[key] - self._kv_last.get(key, 0)
                 self.metrics[name].inc(by=delta)

@@ -198,19 +198,25 @@ class StepProfiler:
         self._last_kv = (-1, -1)
         # monotonic totals, never lost to the ring's wrap: dispatches by
         # phase, the model steps decode/verify windows ran (sum of
-        # their ``steps``), and the rows that were decoding in them
-        # (sum of ``live_rows * steps``)
+        # their ``steps``), the rows that were decoding in them (sum of
+        # ``live_rows * steps``) and the pages the paged attention
+        # kernel walked for them (sum of ``attn_pages``)
         self._dispatches: dict[str, int] = {}
         self._decode_steps = 0
         self._decode_row_steps = 0
+        self._attn_pages = 0
 
     # -- writer (scheduler thread) -----------------------------------------
 
     def record(self, phase: str, bucket: int, live_rows: int,
                live_tokens: int, padded_tokens: int,
-               start: float, end: float, steps: int = 1) -> StepRecord:
+               start: float, end: float, steps: int = 1,
+               attn_pages: int = 0) -> StepRecord:
         """Append one dispatch record; returns it (tests and the flight
-        recorder read fields straight off the return)."""
+        recorder read fields straight off the return). ``attn_pages``:
+        the pages the paged decode kernel walks over a decode or verify
+        window, every row and model step (once a step, not a layer),
+        counted by the caller from the lengths it hands the kernel."""
         kv_in_use, kv_free = self._last_kv
         sample = (
             self._kv_stats is not None
@@ -239,6 +245,7 @@ class StepProfiler:
             if phase in ("decode", "verify"):
                 self._decode_steps += steps
                 self._decode_row_steps += live_rows * steps
+                self._attn_pages += attn_pages
         return rec
 
     # -- readers (any thread) ----------------------------------------------
@@ -261,6 +268,14 @@ class StepProfiler:
         rows a step."""
         with self._lock:
             return self._decode_row_steps
+
+    @property
+    def attn_pages(self) -> int:
+        """Pages the paged decode kernel walked over the model steps of
+        decode and verify windows: over ``totals()``'s decode steps,
+        the pages a step."""
+        with self._lock:
+            return self._attn_pages
 
     def snapshot(self, since_seq: int = -1) -> list[StepRecord]:
         """Records with ``seq > since_seq`` (all, by default). The

@@ -215,6 +215,75 @@ def test_compiles_for_v5e(case, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# --- the paged decode kernel's grid follows the rows ------------------------
+# decode_attention_blocks runs one grid step a batch row and walks the
+# row's live pages inside it; a grid of B * n_kv * max_blocks steps
+# (1,024 at qwen2-7b, 4,096 at Qwen3-Next) cost ~0.18 us a step on the
+# chip whatever the rows held.
+
+PAGED = {
+    "qwen2-7b": functools.partial(_decode_blocks, 1),
+    "qwen3-next": functools.partial(
+        _decode_blocks, 1, QWEN3_NEXT, B=64, NB=1 + 64 * _MB),
+}
+
+
+def _pallas_calls(jaxpr):
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+@pytest.mark.parametrize("model", sorted(PAGED))
+def test_paged_decode_is_one_grid_step_a_row(model, one_chip,
+                                             no_compile_cache):
+    fn, operands = PAGED[model]()
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in operands
+    ]
+    (call,) = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    rows = operands[0][0][0]
+    assert call.params["grid_mapping"].grid == (rows,)
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_a_decode_window_lowers_the_paged_kernel_once(one_chip,
+                                                      monkeypatch):
+    """Every layer calls the kernel with the same operands' shapes;
+    lowered per call site, 28 layers of it were seconds of each decode
+    window program's start-up. The step program holds one lowering."""
+    import dataclasses
+
+    from kubeinfer_tpu.inference import flash_attention as fa
+    from kubeinfer_tpu.inference.config import PRESETS
+    from kubeinfer_tpu.inference.model import init_params
+    from kubeinfer_tpu.inference.stepper import (
+        decode_window,
+        init_slot_state,
+    )
+
+    monkeypatch.setattr(fa, "decode_blocks_available", lambda bs, D: True)
+    cfg = dataclasses.replace(
+        PRESETS["qwen2-7b"], num_hidden_layers=4, vocab_size=1024,
+        intermediate_size=1024)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(functools.partial(
+        init_params, cfg, dtype=BF16), jax.random.PRNGKey(0)))
+    state = placed(jax.eval_shape(functools.partial(
+        init_slot_state, cfg, _B, _MB * _BS, BF16, _NB, _BS)))
+    text = decode_window.lower(params, state, cfg, 2).as_text()
+    assert text.count('"decode_attention_blocks"') == 1
+
+
 # --- the pool stays where it lies -------------------------------------------
 # decode_attention_blocks reads the pool as stored, and the token write
 # is one in-place scatter of n_kv rows a token. Either can come back as

@@ -585,6 +585,11 @@ class TestDecodeKernel:
         np.testing.assert_array_equal(got.lengths, ref.lengths)
 
 
+# the served geometries of the paged kernel, (n_kv, group, head_dim):
+# qwen2-7b, and Qwen3-Next's gated full-attention layers
+SERVED_HEADS = [(4, 7, 128), (2, 8, 256)]
+
+
 class TestBlockDecodeKernel:
     """Block-table decode (paged KV) vs its jnp twin: BIT-identical per
     the kernel/twin invariant — both resolve every KV tile through the
@@ -673,6 +678,42 @@ class TestBlockDecodeKernel:
             dense_atol=3e-2, dense_rtol=1e-1,
         )
 
+    @pytest.mark.parametrize("n_kv,G,D", SERVED_HEADS)
+    def test_served_heads_at_page_edges(self, n_kv, G, D):
+        # one token, a page less one, a page, a page and one, the whole
+        # table: the walk's length on each side of every page edge
+        bs, M = 16, 4
+        self._check(
+            5, M, bs, n_kv * G, n_kv, D, [1, bs - 1, bs, bs + 1, M * bs],
+            dtype=jnp.bfloat16, dense_atol=3e-2, dense_rtol=1e-1,
+        )
+
+    @pytest.mark.parametrize("T", [1, 3])
+    @pytest.mark.parametrize("n_kv,G,D", SERVED_HEADS)
+    def test_dead_pages_are_never_read(self, n_kv, G, D, T):
+        # every dead table entry names a page of NaN: one read of it
+        # would poison the row. The output is finite and is the twin's
+        # on the null-padded table (whose dead pages fold exactly 0)
+        import kubeinfer_tpu.inference.flash_attention as fa
+
+        bs, M = 16, 4
+        lens = [T, bs - 1, bs + 1, 2 * bs + T, M * bs]
+        q, kp, vp, tables, lengths, _, _ = TestKQueryBlockDecode()._paged(
+            jax.random.PRNGKey(26), len(lens), T, M, bs, n_kv * G, n_kv,
+            D, lens, jnp.bfloat16,
+        )
+        nan = kp.shape[0]
+        kp = jnp.concatenate([kp, jnp.full_like(kp[:1], jnp.nan)])
+        vp = jnp.concatenate([vp, jnp.full_like(vp[:1], jnp.nan)])
+        live = jnp.arange(M)[None, :] < -(-lengths[:, None] // bs)
+        poisoned = jnp.where(live, tables, nan)
+        got = fa.decode_attention_blocks(
+            q, kp, vp, poisoned, lengths, interpret=True
+        )
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        twin = fa.decode_attention_blocks_jnp(q, kp, vp, tables, lengths)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(twin))
+
     def test_zero_length_rows(self):
         # retired-slot rows (length 0, table all null) must stay dense
         # over the junk they point at — defined output, never NaN —
@@ -695,15 +736,16 @@ class TestBlockDecodeKernel:
             err_msg="block twin vs linear twin bit-identity",
         )
 
-    def test_shared_prefix_blocks(self):
+    @pytest.mark.parametrize("n_kv,G,D", [(2, 2, 8), *SERVED_HEADS])
+    def test_shared_prefix_blocks(self, n_kv, G, D):
         # radix reuse aliases one physical block into several rows'
         # tables; the kernel only ever reads KV, so aliased tables must
         # behave exactly like their gathered-dense expansion
         import kubeinfer_tpu.inference.flash_attention as fa
 
-        B, bs, n_kv, D = 3, 16, 2, 8
+        B, bs = 3, 16
         q, _, _ = _rand(
-            jax.random.PRNGKey(23), B, 1, 2 * bs, 4, n_kv, D,
+            jax.random.PRNGKey(23), B, 1, 2 * bs, n_kv * G, n_kv, D,
             jnp.float32,
         )
         jk, jv = jax.random.split(jax.random.PRNGKey(24))
@@ -858,6 +900,16 @@ class TestKQueryBlockDecode:
         # contract; the dense reference has no defined answer for a
         # fully-masked query row, so it sits this one out
         self._check(3, 4, 2, 16, 4, 2, 8, [0, 2, 30], dense=False)
+
+    @pytest.mark.parametrize("n_kv,G,D", SERVED_HEADS)
+    def test_served_heads_with_rows_shorter_than_the_window(self, n_kv, G,
+                                                            D):
+        # rows under the window walk the whole null-padded table (the
+        # kernel's dense fallback) beside rows at and past page edges
+        self._check(
+            6, 3, 4, 16, n_kv * G, n_kv, D, [0, 2, 3, 16, 17, 64],
+            dtype=jnp.bfloat16, dense=False,
+        )
 
     def test_reduces_to_single_query(self):
         # the T=1 window through the generalized path must stay

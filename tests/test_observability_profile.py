@@ -314,6 +314,69 @@ def test_decode_row_steps_is_the_rows_of_each_step(counted):
         r.live_tokens for r in dec)
 
 
+@pytest.mark.parametrize("window", ["decode", "verify"])
+def test_attn_pages_is_the_walk_of_the_lengths_each_window_passed(
+        window, params, monkeypatch):
+    """kubeinfer_engine_attn_pages_total, counted on the host from the
+    requests, against the walk rule applied to the lengths the window
+    hands the kernel, read off the device state each window starts
+    from: three slots, a short and a long request, so that one row
+    retires while the other decodes and a third never holds one (a
+    freed row walks one null page, as a never-used one does)."""
+    from kubeinfer_tpu.inference import batching
+    from kubeinfer_tpu.inference.flash_attention import decode_blocks_pages
+
+    eng = ContinuousEngine(
+        params, TINY, n_slots=3, cache_len=64, block_size=8,
+        **({"spec_draft": (params, TINY), "spec_k": 2}
+           if window == "verify" else {}))
+    bs, M = eng.block_size, eng.max_blocks
+    walked, freed = [], []
+
+    def spy(fn, queries):
+        def wrapped(params_, state, *args, **kw):
+            off = np.asarray(state.offset).astype(np.int64)
+            active = np.asarray(state.active)
+            freed.extend(off[~active].tolist())
+            if queries is None:  # decode: k single-query steps
+                k = args[-1]
+                walked.append(sum(
+                    decode_blocks_pages(
+                        off + np.where(active, j + 1, 1), 1, bs, M)
+                    for j in range(k)))
+            else:
+                T = eng.spec_k + 1
+                walked.append(decode_blocks_pages(off + T, T, bs, M))
+            return fn(params_, state, *args, **kw)
+        return wrapped
+
+    monkeypatch.setattr(batching, "decode_window",
+                        spy(batching.decode_window, None))
+    monkeypatch.setattr(batching, "verify_window",
+                        spy(batching.verify_window, "T"))
+    clock = SimulatedClock(start=100.0)
+    prev = tracing.set_clock(clock)
+    try:
+        rng = np.random.default_rng(11)
+        short = eng.submit(rng.integers(0, TINY.vocab_size, 20).tolist(),
+                           max_new_tokens=3)
+        long = eng.submit(rng.integers(0, TINY.vocab_size, 9).tolist(),
+                          max_new_tokens=14)
+        for _ in range(60):
+            if short.done.is_set() and long.done.is_set():
+                break
+            _run_pass(eng, clock)
+        assert short.done.is_set() and long.done.is_set()
+        sched = eng.scheduler_stats()
+    finally:
+        tracing.set_clock(prev)
+        eng.stop()
+    assert sched["dispatches"].get(window, 0) == len(walked) >= 2
+    assert sched["attn_pages"] == sum(walked) > 0
+    # an inactive row always starts a window at offset 0
+    assert freed and set(freed) == {0}
+
+
 def test_dispatches_count_the_records_of_each_phase(counted):
     by_phase = {}
     for r in counted["records"]:
@@ -390,6 +453,7 @@ def _complete(srv, body: dict) -> dict:
 ZERO_SERIES = [
     'kubeinfer_engine_decode_steps_total 0',
     'kubeinfer_engine_decode_row_steps_total 0',
+    'kubeinfer_engine_attn_pages_total 0',
     *(f'kubeinfer_engine_dispatches_total{{phase="{p}"}} 0'
       for p in stepprof.PHASES),
     *(f'kubeinfer_engine_prefill_tokens_total{{kind="{k}"}} 0'
