@@ -14,10 +14,11 @@ Three forms of the same recurrence live here: the token-by-token scan
 (:func:`gdn_recurrence`, the oracle and the CPU branch of decode), the
 chunked scan prefill runs (:func:`gdn_chunk_scan`, the within-chunk
 triangular solve of the delta rule) and the one-token Pallas step
-(:func:`gdn_decode_step`) that updates the state in place. A token with
-``g = 0`` and ``beta = 0`` leaves the state exactly as it was, which is
-how bucket padding and rows that are not decoding pass through
-(:func:`gdn_mixer` sets both from ``valid_len``).
+(:func:`gdn_decode_step`) that updates the state of the decoding slots
+in place and touches no other. A token with ``g = 0`` and ``beta = 0``
+leaves the state exactly as it was, which is how bucket padding and, in
+the scans, rows that are not decoding pass through (:func:`gdn_mixer`
+sets both from ``valid_len``).
 
 The state stays float32 everywhere: in bfloat16 it is a different model
 (benchmarks/reference/qwen3_next.py has that as its control).
@@ -37,7 +38,9 @@ from kubeinfer_tpu.inference.config import ModelConfig
 
 CHUNK = 64  # tokens solved together in prefill
 _HI = lax.Precision.HIGHEST
-_HEADS_PER_BLOCK = 8  # value heads one grid step of the decode kernel takes
+# value heads the decode kernel's one grid step a slot may hold: the
+# slot's state in and out, double-buffered, is 256 KB a head of VMEM
+_MAX_DECODE_HEADS = 32
 
 
 # --- the recurrence, three ways --------------------------------------------
@@ -138,9 +141,10 @@ def _unit_lower_solve(L, rhs):
     return lax.fori_loop(0, C, row, jnp.zeros_like(rhs))
 
 
-def _decode_kernel(x_ref, s_ref, s_out, o_out):
-    """One slot, ``_HEADS_PER_BLOCK`` value heads. x rows per head: q,
-    k, beta*v, exp(g) and exp(g)*beta (both spread over the lanes)."""
+def _decode_block(x_ref, s_ref, s_out, o_out):
+    """One slot's value heads (all, or a block of them). x rows per
+    head: q, k, beta*v, exp(g) and exp(g)*beta (both spread over the
+    lanes)."""
     Dk = s_ref.shape[2]
     ii = lax.broadcasted_iota(jnp.int32, (Dk, Dk), 0)
     jj = lax.broadcasted_iota(jnp.int32, (Dk, Dk), 1)
@@ -163,62 +167,113 @@ def _decode_kernel(x_ref, s_ref, s_out, o_out):
             S * column(x[0:1]), axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=(5,))
-def gdn_decode_step(q, k, v, g, beta, state, interpret: bool = False):
-    """One token for every slot, the state updated in place. q, k
-    f32[B, Hv, Dk]; v f32[B, Hv, Dv]; g, beta f32[B, Hv]; state
-    f32[B, Hv, Dk, Dv]. Returns (o f32[B, Hv, Dv], state)."""
-    B, H, Dk = q.shape
-    Dv = v.shape[-1]
-    hb = _HEADS_PER_BLOCK
+def _decode_kernel(rows_ref, n_ref, x_ref, s_ref, s_out, o_out):
+    """Grid step b: every head of slot ``rows_ref[b]``. Steps past the
+    count sit on the last live slot's block, so Pallas fetches and
+    writes back nothing more; with no live slot at all the one block
+    they sit on is copied through unchanged."""
+    b, n = pl.program_id(0), n_ref[0]
+
+    @pl.when(b < n)
+    def _():
+        _decode_block(x_ref, s_ref, s_out, o_out)
+
+    @pl.when((n == 0) & (b == 0))
+    def _():
+        s_out[...] = s_ref[...]
+
+
+def decoding_slots(valid_len):
+    """The slots a one-token step updates, from ``valid_len`` i32[B]
+    (a row decodes if it is 1): (rows i32[B], count i32[1]), the live
+    slots in order at the front, the rest repeating the last of them
+    (slot 0 if none is live)."""
+    B = valid_len.shape[0]
+    live = valid_len > 0
+    n = jnp.sum(live, dtype=jnp.int32)
+    (rows,) = jnp.nonzero(live, size=B, fill_value=0)
+    rows = jnp.where(jnp.arange(B) < n, rows, rows[jnp.maximum(n - 1, 0)])
+    return rows.astype(jnp.int32), n[None]
+
+
+def _step_rows(q, k, v, g, beta):
+    """The kernel's x, f32[B, Hv, 8, Dk]: the rows :func:`_decode_block`
+    reads, padded to a whole sublane tile."""
     a = jnp.exp(g)
     x = jnp.stack([
         q, k, beta[..., None] * v,
         jnp.broadcast_to(a[..., None], v.shape),
         jnp.broadcast_to((a * beta)[..., None], v.shape),
     ], axis=2)
-    x = jnp.pad(x, ((0, 0), (0, 0), (0, 3), (0, 0)))  # [B, H, 8, Dk]
+    return jnp.pad(x, ((0, 0), (0, 0), (0, 3), (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnums=(5,))
+def gdn_decode_step(q, k, v, g, beta, state, rows, n,
+                    interpret: bool = False):
+    """One token for the slots ``rows[:n[0]]`` (:func:`decoding_slots`),
+    the state updated in place: every other slot's state is not read or
+    written, and its o is 0. q, k f32[B, Hv, Dk]; v f32[B, Hv, Dv]; g,
+    beta f32[B, Hv]; state f32[B, Hv, Dk, Dv]; rows i32[B]; n i32[1].
+    Returns (o f32[B, Hv, Dv], state)."""
+    B, H, Dk = q.shape
+    Dv = v.shape[-1]
+    x = _step_rows(q, k, v, g, beta)
+
+    def slot(b, rows_ref, n_ref):  # past the count: the last live slot
+        return rows_ref[b], 0, 0, 0
+
     state, o = pl.pallas_call(
         _decode_kernel,
-        grid=(B, H // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, 8, Dk), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, hb, Dk, Dv), lambda b, h: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hb, Dk, Dv), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, hb, Dv), lambda b, h: (b, h, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, 8, Dk), slot),
+                pl.BlockSpec((1, H, Dk, Dv), slot),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, H, Dk, Dv), slot),
+                pl.BlockSpec((1, H, Dv), lambda b, rows_ref, n_ref: (
+                    rows_ref[b], 0, 0)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(state.shape, jnp.float32),
             jax.ShapeDtypeStruct((B, H, Dv), jnp.float32),
         ],
-        input_output_aliases={1: 0},
+        # operands: rows, n, x, state
+        input_output_aliases={3: 0},
+        # the steps past the count lean on the step before them
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="gdn_decode_step",
-    )(x, state)
-    return o, state
+    )(rows, n, x, state)
+    walked = jnp.any((jnp.arange(B)[:, None] == rows[None, :])
+                     & (jnp.arange(B)[None, :] < n[0]), axis=1)
+    return jnp.where(walked[:, None, None], o, 0.0), state
 
 
 def gdn_decode_available(H: int, Dk: int, Dv: int) -> bool:
     """Shapes the kernel takes on the current default backend: square
-    lane-wide heads (the row-to-column turn is Dk x Dk), whole blocks."""
+    lane-wide heads (the row-to-column turn is Dk x Dk), and a slot's
+    heads few enough for VMEM."""
     return (
         jax.default_backend() == "tpu"
         and Dk == Dv and Dk % 128 == 0
-        and H % _HEADS_PER_BLOCK == 0
+        and H <= _MAX_DECODE_HEADS
     )
 
 
-def gdn_decode_step_auto(q, k, v, g, beta, state):
+def gdn_decode_step_auto(q, k, v, g, beta, state, valid_len):
     """Decode-step router, like the attention routers: the Pallas
-    kernel where the backend and shapes allow, the scan's one step
-    otherwise."""
+    kernel over the decoding slots where the backend and shapes allow,
+    the scan's one step over every slot otherwise."""
     if gdn_decode_available(q.shape[1], q.shape[2], v.shape[2]):
-        return gdn_decode_step(q, k, v, g, beta, state)
+        return gdn_decode_step(q, k, v, g, beta, state,
+                               *decoding_slots(valid_len))
     o, state = gdn_recurrence(
         q[:, None], k[:, None], v[:, None], g[:, None], beta[:, None],
         state)
@@ -320,7 +375,8 @@ def gdn_mixer(p: dict, x, cfg: ModelConfig, state, tail, valid_len):
 
     if T == 1:
         o, state = gdn_decode_step_auto(
-            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+            valid_len)
         o = o[:, None]
     else:
         o, state = gdn_chunk_scan(q, k, v, g, beta, state)

@@ -119,7 +119,8 @@ def _gdn_decode_step(B, H=32, D=128):
 
     vec = ((B, H, D), F32)
     return gdn_decode_step, (
-        vec, vec, vec, ((B, H), F32), ((B, H), F32), ((B, H, D, D), F32))
+        vec, vec, vec, ((B, H), F32), ((B, H), F32), ((B, H, D, D), F32),
+        ((B,), I32), ((1,), I32))
 
 
 def _decode_blocks_q8(T):
@@ -374,6 +375,66 @@ def test_decode_window_leaves_the_pool_where_it_lies(
     seen = jax.jit(lambda p: p.transpose(0, 2, 1, 3)).lower(
         pool).compile().as_text()
     assert _pool_sized(seen, pool.size)
+
+
+# --- the Gated DeltaNet state stays where it lies --------------------------
+# gdn_decode_step walks the decoding slots and updates their state in
+# place: its state operand is aliased to its output, and the index of
+# that operand counts the scalar-prefetched slot list in front of it.
+# An alias that does not reach the state, or a use of the old state
+# after the call (a select against it), makes XLA copy every layer's
+# whole state (134 MB at gen's 64 slots) each call: more than the walk
+# saves, and invisible on the CPU.
+
+
+def test_gdn_decode_window_updates_the_state_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    from kubeinfer_tpu.inference import flash_attention as fa
+    from kubeinfer_tpu.inference import gdn
+    from kubeinfer_tpu.inference.config import ModelConfig
+    from kubeinfer_tpu.inference.model import init_params
+    from kubeinfer_tpu.inference.stepper import (
+        decode_window,
+        init_slot_state,
+    )
+
+    # the routers ask jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(fa, "decode_blocks_available", lambda bs, D: True)
+    monkeypatch.setattr(gdn, "gdn_decode_available",
+                        lambda H, Dk, Dv: True)
+    nq, nkv, D = QWEN3_NEXT
+    # gen's mixer widths and slots; one period of layers, few experts
+    cfg = ModelConfig.from_hf_dict(dict(
+        model_type="qwen3_next", vocab_size=1024, hidden_size=2048,
+        intermediate_size=512, num_hidden_layers=4,
+        num_attention_heads=nq, num_key_value_heads=nkv, head_dim=D,
+        full_attention_interval=4, linear_conv_kernel_dim=4,
+        linear_key_head_dim=128, linear_num_key_heads=16,
+        linear_num_value_heads=32, linear_value_head_dim=128,
+        moe_intermediate_size=512, num_experts=8, num_experts_per_tok=2,
+        shared_expert_intermediate_size=512, norm_topk_prob=True,
+        partial_rotary_factor=0.25, rms_norm_eps=1e-6, rope_theta=1e7,
+        tie_word_embeddings=False, max_position_embeddings=4096))
+    slots = 64
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(functools.partial(
+        init_params, cfg, dtype=BF16), jax.random.PRNGKey(0)))
+    state = placed(jax.eval_shape(functools.partial(
+        init_slot_state, cfg, slots, _MB * _BS, BF16,
+        1 + slots * _MB, _BS)))
+    (held, *_) = state.gdn_state
+    assert held.shape == (slots, 32, 128, 128) and held.dtype == F32
+    assert len(state.gdn_state) == 3
+    lowered = decode_window.lower(params, state, cfg, 2)
+    # three layers, one lowering of the kernel
+    assert lowered.as_text().count('"gdn_decode_step"') == 1
+    text = lowered.compile().as_text()
+    assert "gdn_decode_step" in text
+    assert _pool_sized(text, held.size) == []
 
 
 # --- the names the device profile carries ----------------------------------

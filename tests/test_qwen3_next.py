@@ -15,6 +15,7 @@ that should not matter (padding, chunking) are held tighter still.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from kubeinfer_tpu.inference import gdn, moe
 from kubeinfer_tpu.inference.batching import (
@@ -255,6 +257,56 @@ def test_other_models_hold_no_new_state(model):
     assert len(jax.tree.leaves(theirs)) == 2 * dense.num_hidden_layers + 10
 
 
+class _Reached(Exception):
+    pass
+
+
+def _trace_steps(cfg, params):
+    """Trace (not run) the decode window, an admit and a chunk, each
+    afresh: the jitted programs' own trace caches are bypassed."""
+    state = jax.eval_shape(functools.partial(_fresh, cfg))
+    M = CACHE // BS
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    f32 = jax.ShapeDtypeStruct((), jnp.float32)
+    row = jax.ShapeDtypeStruct((M,), jnp.int32)
+    own = jax.ShapeDtypeStruct((M,), jnp.bool_)
+    jax.eval_shape(functools.partial(
+        decode_window.__wrapped__, cfg=cfg, k=2), params, state)
+    jax.eval_shape(functools.partial(
+        _admit_slot.__wrapped__, cfg=cfg), params, state,
+        jax.ShapeDtypeStruct((1, 32), jnp.int32), i32, i32, i32,
+        slot=i32, table_row=row, own_mask=own, temperature=f32, top_k=i32,
+        top_p=f32, rep_penalty=f32,
+        key_data=jax.ShapeDtypeStruct((2,), jnp.uint32),
+        seen_row=jax.ShapeDtypeStruct((1, cfg.vocab_size), jnp.bool_))
+    jax.eval_shape(functools.partial(
+        _prefill_chunk.__wrapped__, cfg=cfg), params, state,
+        jax.ShapeDtypeStruct((1, 16), jnp.int32), i32, table_row=row,
+        own_mask=own, slot=i32)
+
+
+def test_a_dense_model_never_reaches_the_slot_list(model, monkeypatch):
+    """The list of decoding slots is built in the linear-attention
+    branch of a one-token step and nowhere else: with decoding_slots
+    made to raise and the kernel's branch taken, a dense model's decode
+    window, admit and chunk still trace, and the hybrid model's decode
+    window is where it raises (its admit and chunk take the scan)."""
+
+    def reached(valid_len):
+        raise _Reached
+
+    monkeypatch.setattr(gdn, "decoding_slots", reached)
+    monkeypatch.setattr(gdn, "gdn_decode_available", lambda H, Dk, Dv: True)
+    dense = PRESETS["tiny"]
+    _trace_steps(dense, init_params(dense, jax.random.PRNGKey(0),
+                                    dtype=jnp.float32))
+    cfg, params, _, _ = model
+    with pytest.raises(_Reached):
+        jax.eval_shape(functools.partial(
+            decode_window.__wrapped__, cfg=cfg, k=2), params,
+            jax.eval_shape(functools.partial(_fresh, cfg)))
+
+
 # --- the Gated DeltaNet forms ---------------------------------------------------
 
 
@@ -284,7 +336,7 @@ def test_chunked_scan_is_the_token_by_token_recurrence(T):
 def test_decode_kernel_is_the_recurrences_one_step():
     """The Pallas step in interpret mode (the chip's compile of it is
     tests/test_chip_compile.py's): in place, heads in blocks, an idle
-    row (g = 0, beta = 0) left exactly as it was."""
+    row not walked and left exactly as it was."""
     q, k, v, g, beta, state = _gdn_inputs(1, B=3, T=1, H=8, D=128)
     g = g.at[1].set(0.0)
     beta = beta.at[1].set(0.0)
@@ -292,10 +344,80 @@ def test_decode_kernel_is_the_recurrences_one_step():
     keep = np.asarray(state[1])
     got_o, got_s = gdn.gdn_decode_step(
         q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+        *gdn.decoding_slots(jnp.asarray([1, 0, 1], jnp.int32)),
         interpret=True)
-    np.testing.assert_allclose(got_o, want_o[:, 0], atol=1e-5, rtol=0)
+    np.testing.assert_allclose(got_o[::2], want_o[::2, 0], atol=1e-5,
+                               rtol=0)
     np.testing.assert_allclose(got_s, want_s, atol=1e-5, rtol=0)
     assert np.array_equal(np.asarray(got_s[1]), keep)
+    assert not np.asarray(got_o[1]).any()
+
+
+def _full_walk(x, state):
+    """The step as it was before it walked a list: every slot, in
+    blocks of 8 heads, the same body."""
+    B, H, _, Dk = x.shape
+    hb = 8
+    return pl.pallas_call(
+        gdn._decode_block,
+        grid=(B, H // hb),
+        in_specs=[
+            pl.BlockSpec((1, hb, 8, Dk), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, hb, Dk, Dk), lambda b, h: (b, h, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, hb, Dk, Dk), lambda b, h: (b, h, 0, 0)),
+            pl.BlockSpec((1, hb, Dk), lambda b, h: (b, h, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, Dk), jnp.float32)],
+        input_output_aliases={1: 0},
+        interpret=True,
+    )(x, state)
+
+
+LIVE_SETS = {
+    "none": [],
+    "slot-63": [63],
+    "six-scattered": [2, 9, 23, 40, 41, 63],
+    "all-64": list(range(64)),
+}
+
+
+@pytest.mark.parametrize("live", sorted(LIVE_SETS))
+def test_decode_kernel_walks_only_the_decoding_slots(live):
+    """Gen's geometry: 64 slots, 32 value heads of 128 x 128. The
+    decoding slots are the recurrence's one step; every other slot's
+    state is the input bit for bit and its o is 0; with every slot
+    decoding the result is the full walk's, bit for bit."""
+    rows = LIVE_SETS[live]
+    q, k, v, g, beta, state = _gdn_inputs(6, B=64, T=1, H=32, D=128)
+    valid = np.zeros(64, np.int32)
+    valid[rows] = 1
+    idle = jnp.asarray(valid == 0)[:, None, None]
+    g, beta = jnp.where(idle, 0.0, g), jnp.where(idle, 0.0, beta)
+    want_o, want_s = map(np.asarray, gdn.gdn_recurrence(
+        q, k, v, g, beta, state))
+    q, k, v, g, beta = q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+    keep = np.asarray(state)
+    slots, n = gdn.decoding_slots(jnp.asarray(valid))
+    assert int(n[0]) == len(rows)
+    assert np.asarray(slots[:len(rows)]).tolist() == rows
+    got_o, got_s = gdn.gdn_decode_step(
+        q, k, v, g, beta, jnp.asarray(keep), slots, n, interpret=True)
+    got_o, got_s = np.asarray(got_o), np.asarray(got_s)
+    np.testing.assert_allclose(got_o[rows], want_o[rows, 0], atol=1e-5,
+                               rtol=0)
+    np.testing.assert_allclose(got_s[rows], want_s[rows], atol=1e-5,
+                               rtol=0)
+    rest = valid == 0
+    assert np.array_equal(got_s[rest], keep[rest])
+    assert not got_o[rest].any()
+    if live == "all-64":
+        full_s, full_o = _full_walk(gdn._step_rows(q, k, v, g, beta),
+                                    jnp.asarray(keep))
+        assert np.array_equal(got_s, np.asarray(full_s))
+        assert np.array_equal(got_o, np.asarray(full_o))
 
 
 # --- the experts ----------------------------------------------------------------
