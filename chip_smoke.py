@@ -111,9 +111,12 @@ def failure(e: Exception) -> str:
 def _start_jax():
     """Compile cache on, then the device — or fail: the phases are
     written for the chip and are not run on anything else."""
-    from kubeinfer_tpu.utils.compile_cache import enable_compile_cache
+    from kubeinfer_tpu.utils.compile_cache import (
+        enable_compile_cache, watch_compiles,
+    )
 
     cache_dir = enable_compile_cache()
+    watch_compiles()
     import jax
 
     devs = jax.devices()
@@ -125,29 +128,14 @@ def _start_jax():
     return jax, device, cache_dir
 
 
-class _CompileClock:
-    """Seconds JAX spent in backend compiles, and how many programs came
-    out of the persistent cache instead, from jax.monitoring."""
+def _compile_readings() -> dict:
+    """Seconds this child spent in JAX's backend compiles, and how many
+    programs came out of the persistent cache instead."""
+    from kubeinfer_tpu.utils.compile_cache import compile_watch
 
-    def __init__(self) -> None:
-        import jax.monitoring as mon
-
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        mon.register_event_duration_secs_listener(self._dur)
-        mon.register_event_listener(self._evt)
-
-    def _dur(self, event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def _evt(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def readings(self) -> dict:
-        return {"compile_s": round(self.compile_s, 1),
-                "compile_cache_hits": self.cache_hits}
+    totals = compile_watch().totals()
+    return {"compile_s": round(totals["backend_s"], 1),
+            "compile_cache_hits": totals["fetched"]}
 
 
 def _peak_bytes(jax) -> int:
@@ -233,7 +221,6 @@ def _check_constraints(req, assignment) -> None:
 
 def phase_solver(args) -> dict:
     jax, device, cache_dir = _start_jax()
-    clock = _CompileClock()
     import numpy as np
 
     from kubeinfer_tpu.api.types import SchedulerPolicy
@@ -281,7 +268,7 @@ def phase_solver(args) -> dict:
     return {
         "device": device, "compile_cache_dir": cache_dir, "cases": cases,
         "routes": routes, "smoke_peak_bytes_in_use": _peak_bytes(jax),
-        **clock.readings(),
+        **_compile_readings(),
     }
 
 
@@ -372,7 +359,6 @@ def _close(np, got, ref, tol: float, what: str) -> float:
 
 def phase_kernels(args) -> dict:
     jax, device, cache_dir = _start_jax()
-    clock = _CompileClock()
     import jax.numpy as jnp
     import numpy as np
 
@@ -503,7 +489,7 @@ def phase_kernels(args) -> dict:
         "device": device, "compile_cache_dir": cache_dir,
         "tolerance": {"attention": ATTN_TOL, "matmul": MATMUL_TOL},
         "kernels": results, "lowered_steps": lowered,
-        "smoke_peak_bytes_in_use": _peak_bytes(jax), **clock.readings(),
+        "smoke_peak_bytes_in_use": _peak_bytes(jax), **_compile_readings(),
     }
 
 

@@ -33,4 +33,10 @@ operator scheduling distributed LLM inference workloads; see
 - ``utils``        — clock abstraction (real + simulated), logging, env config.
 """
 
+from kubeinfer_tpu.observability import tracing as _tracing
+
 __version__ = "0.1.0"
+
+# when this process first imported the package: where the server's
+# ``import`` start-up phase begins (inference/server.py, ``main``)
+IMPORTED_AT = _tracing.now()

@@ -43,6 +43,7 @@ import time
 from http.server import ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
+import kubeinfer_tpu
 from kubeinfer_tpu.analysis.racecheck import make_lock
 from kubeinfer_tpu.metrics.registry import (
     Counter, Gauge, Histogram, Registry,
@@ -50,6 +51,9 @@ from kubeinfer_tpu.metrics.registry import (
 from kubeinfer_tpu.observability import tracing
 from kubeinfer_tpu.observability.slo import SLOMonitor
 from kubeinfer_tpu.observability.stepprof import PHASES
+from kubeinfer_tpu.utils.compile_cache import (
+    compile_watch, enable_compile_cache, watch_compiles,
+)
 from kubeinfer_tpu.utils.httpbase import BaseEndpointHandler, token_matches
 
 log = logging.getLogger(__name__)
@@ -314,9 +318,42 @@ def _serving_metrics(registry: Registry):
         ),
         "compiles": Counter(
             "kubeinfer_engine_compiles_total",
-            "Device dispatches that hit a first-seen (phase, bucket) "
-            "shape (jit compile proxy)",
+            "Engine dispatches of a (phase, bucket) shape the engine had "
+            "not dispatched before, whether JAX compiled the program or "
+            "fetched it from the persistent cache "
+            "(kubeinfer_compile_programs_total says which)",
             registry=registry,
+        ),
+        # start-up (main and JAX's own compile events): where the
+        # seconds before the first request went
+        "startup": Gauge(
+            "kubeinfer_startup_seconds",
+            "Seconds of each start-up phase: import (package import to "
+            "main), device_init, weights (made, quantised, on the "
+            "device), engine (built, started, listening)",
+            labels=("phase",), registry=registry,
+        ),
+        "compile_seconds": Counter(
+            "kubeinfer_compile_seconds_total",
+            "Seconds JAX spent compiling programs, by stage: trace "
+            "(Python to jaxpr), lower (jaxpr to MLIR), backend (XLA "
+            "compile or persistent-cache fetch); a stage nested in "
+            "another counts in the outer one",
+            labels=("stage",), registry=registry,
+        ),
+        "compile_fetch": Counter(
+            "kubeinfer_compile_cache_fetch_seconds_total",
+            "Seconds of the backend stage spent reading executables "
+            "from the persistent compilation cache",
+            registry=registry,
+        ),
+        "compile_programs": Counter(
+            "kubeinfer_compile_programs_total",
+            "Backend compiles by result: fetched (from the persistent "
+            "cache), kept (compiled and written to it), unkept "
+            "(compiled and not written: under the cache's minimum "
+            "compile time, so every start compiles them again)",
+            labels=("result",), registry=registry,
         ),
         # preemptive scheduling + chunked prefill (batching.py): the
         # monotonic engine counters convert by delta at scrape time
@@ -826,6 +863,7 @@ class InferenceServer:
             for w, d in obj["windows"].items():
                 self.metrics["slo_burn"].set(name, f"{w}s", d["burn_rate"])
             self.metrics["slo_budget"].set(name, obj["budget_remaining"])
+        self._fold_compiles()
         if self.continuous is None:
             return
         stats = self.continuous.kv_cache_stats()
@@ -945,6 +983,45 @@ class InferenceServer:
                     self.metrics["compiles"].inc()
             if recs:
                 self._prof_seq = recs[-1].seq
+
+    def _fold_compiles(self) -> None:
+        """JAX's compile totals (utils/compile_cache.CompileWatch) into
+        counters, by delta like the engine's; nothing while no watch is
+        registered, so the series are absent rather than a false 0."""
+        watch = compile_watch()
+        if watch is None:
+            return
+        totals = watch.totals()
+        with self._kv_lock:
+            for key, name, labels in (
+                ("trace_s", "compile_seconds", ("trace",)),
+                ("lower_s", "compile_seconds", ("lower",)),
+                ("backend_s", "compile_seconds", ("backend",)),
+                ("fetch_s", "compile_fetch", ()),
+                ("fetched", "compile_programs", ("fetched",)),
+                ("kept", "compile_programs", ("kept",)),
+                ("unkept", "compile_programs", ("unkept",)),
+            ):
+                last = self._kv_last.get("compile." + key, 0)
+                self.metrics[name].inc(*labels, by=totals[key] - last)
+                self._kv_last["compile." + key] = totals[key]
+
+    def record_startup(self, stamps: list[tuple[str, float]],
+                       start: float) -> None:
+        """Publish start-up as ``kubeinfer_startup_seconds{phase}`` and
+        as spans: one ``server.startup`` root from ``start`` to the last
+        stamp, a child per phase. ``stamps`` are (phase, end) in order,
+        each phase beginning where the one before ended; every time on
+        ``tracing.now()``'s clock."""
+        root = _TRACER.start_span("server.startup", start=start,
+                                  replica=self.model_id)
+        t = start
+        for phase, end in stamps:
+            self.metrics["startup"].set(phase, end - t)
+            _TRACER.record_span(f"server.startup.{phase}", t, end,
+                                parent=root.context)
+            t = end
+        _TRACER.finish(root, end=t)
 
     def _merge_counter_tracks(self, doc: dict) -> None:
         """Append the engine's counter tracks (batch occupancy, padded
@@ -1080,16 +1157,9 @@ class InferenceServer:
         if fps[-1] in advertised:
             return  # already warm locally (earlier import or admit)
         t0 = time.perf_counter()
-        # the ledger's "stream" phase: the span brackets the network
-        # fetch + verify + staged scatter, parented under the active
-        # server.complete span so it joins the request's trace
-        with _TRACER.span("server.kv_import", kind="prefix",
-                          replica=self.model_id) as sp:
-            imported, reason, wire_bytes = import_remote_prefix(
-                eng, ids, base_url,
-            )
-            sp.set(blocks=imported,
-                   **({"fallback": reason} if reason else {}))
+        imported, reason, wire_bytes = import_remote_prefix(
+            eng, ids, base_url,
+        )
         if imported > 0:
             self.metrics["kv_stream_blocks"].inc("import", by=imported)
             self.metrics["kv_stream_bytes"].inc("import", by=wire_bytes)
@@ -1200,16 +1270,10 @@ class InferenceServer:
         if fps[-1] in advertised:
             return  # whole chain already warm (bounce-back resume)
         t0 = time.perf_counter()
-        # same stream-phase span as _maybe_import_prefix: one name for
-        # both import shapes so ledger joins need a single rule
-        with _TRACER.span("server.kv_import", kind="chain",
-                          replica=self.model_id) as sp:
-            imported, reason, wire_bytes = import_remote_chain(
-                eng, tokens, base_url,
-                chunk_blocks=getattr(eng, "migration_chunk_blocks", 4),
-            )
-            sp.set(blocks=imported,
-                   **({"fallback": reason} if reason else {}))
+        imported, reason, wire_bytes = import_remote_chain(
+            eng, tokens, base_url,
+            chunk_blocks=getattr(eng, "migration_chunk_blocks", 4),
+        )
         if imported > 0:
             self.metrics["kv_stream_blocks"].inc("import", by=imported)
             self.metrics["kv_stream_bytes"].inc("import", by=wire_bytes)
@@ -1497,6 +1561,9 @@ def _load_tokenizer(model_dir: str):
 
 
 def main(argv: list[str] | None = None) -> int:
+    # start-up phases, each (name, end) on tracing.now()'s clock; the
+    # first begins when this process imported the package
+    stamps = [("import", tracing.now())]
     p = argparse.ArgumentParser(prog="kubeinfer-inference-server")
     # flag surface = runtime.py build_args (vllm.go:93-112 parity)
     p.add_argument("--model", required=True,
@@ -1620,9 +1687,8 @@ def main(argv: list[str] | None = None) -> int:
         # across every tracer in this process or ledgers shear mid-hop
         tracing.set_span_sampling(args.span_sample_every)
 
-    from kubeinfer_tpu.utils.compile_cache import enable_compile_cache
-
     enable_compile_cache()
+    watch_compiles()  # before anything compiles
     import jax
     import jax.numpy as jnp
 
@@ -1630,6 +1696,8 @@ def main(argv: list[str] | None = None) -> int:
     from kubeinfer_tpu.inference.engine import Engine
     from kubeinfer_tpu.inference.model import init_params
 
+    jax.devices()
+    stamps.append(("device_init", tracing.now()))
     dtype = {"auto": jnp.bfloat16, "bfloat16": jnp.bfloat16,
              "float32": jnp.float32}[args.dtype]
     mesh = None
@@ -1676,6 +1744,9 @@ def main(argv: list[str] | None = None) -> int:
                                       weight_dtype=args.weight_dtype,
                                       mesh=tp_mesh)
         tokenizer = _load_tokenizer(args.model)
+    # the weights made and quantised on the device, not only dispatched
+    jax.block_until_ready(params)
+    stamps.append(("weights", tracing.now()))
     if args.weight_dtype == "int8" and args.sequence_parallel_size > 1:
         # the SP engine shard_maps with manual param_specs and has no
         # quantized-leaf path; refusing beats silently serving a
@@ -1769,7 +1840,11 @@ def main(argv: list[str] | None = None) -> int:
         tls_cert=args.tls_cert_file, tls_key=args.tls_key_file,
         token=debug_token, slo=slo,
         kv_export_budget_mb=args.kv_export_budget_mb,
-    ).start()
+    )
+    # listening: a connection waits in the backlog from here
+    stamps.append(("engine", tracing.now()))
+    srv.record_startup(stamps, start=kubeinfer_tpu.IMPORTED_AT)
+    srv.start()
     log.info("native inference server on %s:%d (model %s)",
              args.host, srv.port, args.model)
 

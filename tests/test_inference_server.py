@@ -296,3 +296,114 @@ class TestServingMetrics:
             code = e.code
         assert code == 400
         assert server.metrics["requests"].value("invalid", "invalid") == before + 1
+
+
+class TestStartup:
+    PHASES = ("import", "device_init", "weights", "engine")
+
+    def test_main_publishes_its_phases_and_their_spans(self):
+        import os
+        import signal
+        import socket
+        import subprocess
+
+        from tests.conftest import subprocess_pythonpath
+
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        url = f"http://127.0.0.1:{port}"
+        env = dict(os.environ, PYTHONPATH=subprocess_pythonpath())
+        t0 = time.monotonic()
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "kubeinfer_tpu.inference.server",
+             "--model", "tiny", "--random-init", "--host", "127.0.0.1",
+             "--port", str(port), "--batch-slots", "2"],
+            env=env, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+        )
+        try:
+            deadline = time.monotonic() + 120
+            while True:
+                assert proc.poll() is None, "the server exited"
+                assert time.monotonic() < deadline, "never healthy"
+                try:
+                    with urllib.request.urlopen(url + "/health",
+                                                timeout=2):
+                        break
+                except OSError:
+                    time.sleep(0.2)
+            with urllib.request.urlopen(url + "/metrics", timeout=30) as r:
+                page = r.read().decode()
+            _, doc = get(url + "/debug/spans")
+            wall = time.monotonic() - t0
+        finally:
+            proc.send_signal(signal.SIGTERM)
+            proc.wait(timeout=60)
+        phases = {
+            m.group(1): float(m.group(2)) for m in re.finditer(
+                r'^kubeinfer_startup_seconds\{phase="(\w+)"\} (\S+)$',
+                page, flags=re.M)}
+        assert set(phases) == set(self.PHASES)
+        assert all(v >= 0 for v in phases.values())
+        assert sum(phases.values()) < wall
+        # the weights and their programs compiled before it listened
+        assert re.search(r'^kubeinfer_compile_programs_total\{result='
+                         r'"unkept"\} [1-9]', page, flags=re.M)
+        spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        (root,) = [e for e in spans if e["name"] == "server.startup"]
+        kids = [e for e in spans
+                if e["args"]["parent_id"] == root["args"]["span_id"]]
+        assert [e["name"] for e in sorted(kids, key=lambda e: e["ts"])] \
+            == [f"server.startup.{p}" for p in self.PHASES]
+        assert any(e["name"] == "compile.backend" for e in spans)
+
+    def test_record_startup_chains_the_phases_under_one_root(self):
+        from kubeinfer_tpu.observability import tracing
+
+        srv = InferenceServer(None, model_id="boot", port=0)
+        try:
+            srv.record_startup(
+                [("import", 11.0), ("device_init", 11.5),
+                 ("weights", 14.0), ("engine", 14.25)], start=10.0)
+        finally:
+            srv.stop()
+        got = {p: srv.metrics["startup"].value(p) for p in self.PHASES}
+        assert got == {"import": 1.0, "device_init": 0.5,
+                       "weights": 2.5, "engine": 0.25}
+        (root,) = [s for s in tracing.RECORDER.snapshot()
+                   if s.name == "server.startup"
+                   and s.attrs.get("replica") == "boot"]
+        assert (root.start, root.end) == (10.0, 14.25)
+        kids = [s for s in tracing.RECORDER.snapshot(root.trace_id)
+                if s.parent_id == root.span_id]
+        assert [(s.name, s.start, s.end) for s in kids] == [
+            ("server.startup.import", 10.0, 11.0),
+            ("server.startup.device_init", 11.0, 11.5),
+            ("server.startup.weights", 11.5, 14.0),
+            ("server.startup.engine", 14.0, 14.25)]
+
+    def test_compile_counters_follow_the_watch_by_delta(self, server):
+        from kubeinfer_tpu.utils.compile_cache import watch_compiles
+
+        watch = watch_compiles()
+        server._refresh_spec_metrics()
+        first = dict(watch.totals())
+        assert server.metrics["compile_programs"].value("unkept") \
+            == first["unkept"]
+
+        salt = float(time.time_ns() % 1_000_003)
+        jax.jit(lambda x: x - salt)(jax.numpy.ones(3)).block_until_ready()
+        server._refresh_spec_metrics()
+        server._refresh_spec_metrics()  # a second scrape adds nothing
+        now = watch.totals()
+        assert now["unkept"] + now["kept"] + now["fetched"] > \
+            first["unkept"] + first["kept"] + first["fetched"]
+        for label, key in (("fetched", "fetched"), ("kept", "kept"),
+                           ("unkept", "unkept")):
+            assert server.metrics["compile_programs"].value(label) == \
+                now[key]
+        for stage in ("trace", "lower", "backend"):
+            assert server.metrics["compile_seconds"].value(stage) == \
+                pytest.approx(now[f"{stage}_s"])
+        assert server.metrics["compile_fetch"].value() == \
+            pytest.approx(now["fetch_s"])
