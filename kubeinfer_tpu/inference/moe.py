@@ -34,7 +34,7 @@ Params = dict
 ROW_TILE = 128  # rows of sorted pairs one grid step of the kernel takes
 # what one MoE call adds to the device-side counters (SlotState.moe_stats)
 STATS = ("routed_pairs", "held_pairs", "experts_reached", "max_pairs",
-         "calls")
+         "calls", "work_items")
 
 
 def init_moe_params(
@@ -96,7 +96,8 @@ def _gmm_kernel(tile_ref, group_ref, n_work_ref, bounds_ref,
     """Work item ``i``: rows of tile ``tile_ref[i]`` that belong to
     expert ``group_ref[i]`` times that expert's weights. Items come
     sorted by tile, so an output tile stays in VMEM over its items and
-    is zeroed by the first."""
+    is zeroed by the first. The grid is the items; with none it is one
+    step that computes nothing."""
     i = pl.program_id(0)
     tile, group = tile_ref[i], group_ref[i]
     first = (i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile)
@@ -115,27 +116,38 @@ def _gmm_kernel(tile_ref, group_ref, n_work_ref, bounds_ref,
         o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
 
 
-def _work_items(sizes: jax.Array, n_tiles: int):
-    """(tile, group, n_work) of the (row tile, expert) pairs that share
-    a row, in row order; the list is padded to its static length with
-    repeats of the last item, which the kernel skips and which move no
-    block."""
-    E = sizes.shape[0]
+def _tile_spans(sizes: jax.Array):
+    """(first row tile, row tiles) of each expert's rows, which lie in
+    expert order: an expert with no row spans none."""
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
-    # tiles an expert's rows touch: first..last, none when it has no row
     t_first = starts // ROW_TILE
-    t_last = jnp.where(sizes > 0, (ends - 1) // ROW_TILE, t_first - 1)
-    per = t_last - t_first + 1
-    W = n_tiles + E - 1
+    per = jnp.where(sizes > 0, (ends - 1) // ROW_TILE - t_first + 1, 0)
+    return t_first, per
+
+
+def _work_items(sizes: jax.Array, n_tiles: int):
+    """(tile, group, n_work) of the (row tile, expert) pairs that share
+    a row, in row order. The list has its static worst-case length,
+    ``n_tiles + E - 1``, padded with repeats of the last item; the
+    kernel walks the first ``n_work``. Built from one comparison of
+    every item with every expert's span of items: no search, no
+    gather."""
+    E = sizes.shape[0]
+    t_first, per = _tile_spans(sizes)
     item_end = jnp.cumsum(per)
+    item_start = item_end - per
     n_work = item_end[-1]
-    i = jnp.minimum(jnp.arange(W), jnp.maximum(n_work - 1, 0))
-    group = jnp.searchsorted(item_end, i, side="right").astype(jnp.int32)
-    group = jnp.minimum(group, E - 1)
-    tile = t_first[group] + (i - (item_end[group] - per[group]))
-    tile = jnp.clip(tile, 0, n_tiles - 1).astype(jnp.int32)
-    return tile, group, n_work.astype(jnp.int32)[None]
+    i = jnp.minimum(jnp.arange(n_tiles + E - 1),
+                    jnp.maximum(n_work - 1, 0))[:, None]
+    # the one expert whose items span item i (none when there is none)
+    owner = (item_start <= i) & (i < item_end)  # [W, E]
+    group = jnp.sum(jnp.where(owner, jnp.arange(E), 0), axis=1)
+    tile = i[:, 0] + jnp.sum(jnp.where(owner, t_first - item_start, 0),
+                             axis=1)
+    tile = jnp.clip(tile, 0, n_tiles - 1)
+    return (tile.astype(jnp.int32), group.astype(jnp.int32),
+            n_work.astype(jnp.int32)[None])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -145,19 +157,21 @@ def grouped_matmul(x, w, sizes, interpret: bool = False):
     ``w [E, K, N]`` the experts' weights: row r of the result is
     ``x[r] @ w[e]`` for the expert e that owns it. Rows past the sum
     are not computed and come back as they lie in memory: the caller
-    masks them. An expert with no row is never read."""
+    masks them. An expert with no row is never read, and the grid has
+    one step a work item (a row tile of one expert), however many the
+    shapes would allow."""
     M, K = x.shape
     E, _, N = w.shape
     assert M % ROW_TILE == 0, (M, ROW_TILE)
-    n_tiles = M // ROW_TILE
-    tile, group, n_work = _work_items(sizes, n_tiles)
+    tile, group, n_work = _work_items(sizes, M // ROW_TILE)
     bounds = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n_tiles + E - 1,),
+            # a bound read at run time: the items there are, at least one
+            grid=(jnp.maximum(n_work[0], 1),),
             in_specs=[
                 pl.BlockSpec((ROW_TILE, K),
                              lambda i, t, g, n, b: (t[i], 0)),
@@ -246,6 +260,8 @@ def moe_forward(params: Params, x: jax.Array, top_k: int,
     stats = jnp.stack([
         n_rows * top_k, n_held, jnp.sum(sizes > 0), jnp.max(sizes),
         jnp.ones((), jnp.int32),
+        # the grouped matmul's grid: (row tile, expert) items
+        jnp.sum(_tile_spans(sizes)[1]),
     ]).astype(jnp.uint32)
     return out.reshape(B, T, H), stats
 

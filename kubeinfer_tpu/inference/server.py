@@ -300,6 +300,12 @@ def _serving_metrics(registry: Registry):
             "MoE calls (one per routed layer per model step)",
             registry=registry,
         ),
+        "moe_work_items": Counter(
+            "kubeinfer_moe_work_items_total",
+            "Work items (a row tile of one expert) the grouped matmul "
+            "walked, summed over MoE calls: its grid steps a call",
+            registry=registry,
+        ),
         "recurrent_state_bytes": Gauge(
             "kubeinfer_recurrent_state_bytes",
             "Resident bytes of the linear-attention layers' per-slot "

@@ -285,6 +285,43 @@ def test_a_decode_window_lowers_the_paged_kernel_once(one_chip,
     assert text.count('"decode_attention_blocks"') == 1
 
 
+# --- the grouped expert matmul walks its work items -------------------------
+# moe.grouped_matmul runs one grid step a (row tile, expert) item, a
+# bound read at run time: a grid sized by the shapes (n_tiles + E - 1,
+# 132 at gen's decode for ~10 items) cost ~0.08 us an idle step on a
+# TPU v5e. The item list depends on the sizes alone, so the three
+# projections of one MoE call share one.
+
+
+def test_moe_layer_walks_one_work_list_for_its_three_matmuls(
+        one_chip, no_compile_cache, monkeypatch):
+    from kubeinfer_tpu.inference import moe
+
+    # the router asks jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(moe, "grouped_matmul_available", lambda x, w: True)
+    B, H, F, E = 64, 2048, 512, 128  # gen's decode: 64 slots, top-10
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    p = {"router": shaped(H, 4 * E), "gate_proj": shaped(E, H, F),
+         "up_proj": shaped(E, H, F), "down_proj": shaped(E, F, H)}
+    x = shaped(B, 1, H)
+    layer = jax.jit(functools.partial(moe.moe_forward, top_k=10))
+    calls = _pallas_calls(layer.trace(p, x).jaxpr.jaxpr)
+    assert len(calls) == 3
+    for call in calls:
+        assert call.params["grid_mapping"].num_dynamic_grid_bounds == 1
+    text = layer.lower(p, x).compile().as_text()
+    kernels = re.findall(
+        r"%moe_grouped_matmul[\w.]* = [^\n]* custom-call\(([^)]*)\)", text)
+    assert len(kernels) == 3
+    # operands: the bound, the list's three parts, the row bounds, then
+    # x and the weights
+    scalars = {tuple(k.split(", ")[:5]) for k in kernels}
+    assert len(scalars) == 1, scalars
+
+
 # --- the pool stays where it lies -------------------------------------------
 # decode_attention_blocks reads the pool as stored, and the token write
 # is one in-place scatter of n_kv rows a token. Either can come back as

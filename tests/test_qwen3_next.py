@@ -451,9 +451,12 @@ def test_sparse_block_is_the_dense_oracle():
     got, stats = moe.moe_forward(p, x, top_k=4)
     np.testing.assert_allclose(got, moe.moe_block(p, x, top_k=4),
                                atol=1e-6, rtol=0)
-    routed, held, reached, busiest, calls = (int(s) for s in stats)
+    routed, held, reached, busiest, calls, items = (int(s) for s in stats)
     assert (routed, held, calls) == (2 * 24 * 4, 2 * 24 * 4, 1)
     assert 1 <= reached <= 16 and held / 16 <= busiest <= 48
+    # an expert's rows span one 128-row tile, or two where they cross
+    tiles = -(-held // moe.ROW_TILE)
+    assert reached <= items <= reached + tiles - 1
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -487,22 +490,88 @@ def test_rows_that_are_not_real_reach_no_expert():
     assert int(stats[0]) == int(stats[1]) == 5 * 4
 
 
-def test_grouped_matmul_kernel_is_a_matmul_per_group():
-    """The Pallas kernel in interpret mode against ragged_dot: groups
-    that straddle row tiles, empty groups, rows past the last group."""
+def _gmm_case(name):
+    """(x, w, sizes) of one grouped-matmul case. Most are small; gen's
+    decode is the served shape: 64 slots x top-10 = 640 sorted pairs,
+    128 held experts, ~10 held pairs on scattered experts (the others'
+    weights are zeros: an expert no row reached is never read)."""
     ks = jax.random.split(jax.random.PRNGKey(6), 2)
+    if name == "gen-decode-10":
+        reached = [3, 17, 40, 41, 77, 90, 100, 101, 120, 127]
+        x = jax.random.normal(ks[0], (640, 2048)).astype(jnp.bfloat16)
+        w = jnp.zeros((128, 2048, 512), jnp.bfloat16).at[
+            jnp.asarray(reached)].set(jax.random.normal(
+                ks[1], (10, 2048, 512)).astype(jnp.bfloat16))
+        sizes = np.zeros(128, np.int32)
+        sizes[reached] = 1
+        return x, w, jnp.asarray(sizes)
     x = jax.random.normal(ks[0], (384, 128)).astype(jnp.bfloat16)
     w = jax.random.normal(ks[1], (6, 128, 128)).astype(jnp.bfloat16)
-    sizes = jnp.asarray([0, 130, 3, 0, 127, 1], jnp.int32)
+    sizes = {
+        # groups that straddle row tiles, empty groups, rows past the last
+        "tiles-straddled": [0, 130, 3, 0, 127, 1],
+        "none": [0] * 6,
+        "one-pair": [0, 0, 0, 1, 0, 0],
+        "every-expert": [60, 70, 1, 128, 50, 75],
+    }[name]
+    return x, w, jnp.asarray(sizes, jnp.int32)
+
+
+GMM_CASES = ("tiles-straddled", "none", "one-pair", "every-expert",
+             "gen-decode-10")
+
+
+def _gmm_items(sizes, row_tile=128):
+    """(tile, expert) of every row tile an expert's rows touch, in row
+    order: the work the kernel has."""
+    items, start = [], 0
+    for e, n in enumerate(int(s) for s in sizes):
+        if n:
+            items += [(t, e) for t in range(
+                start // row_tile, (start + n - 1) // row_tile + 1)]
+        start += n
+    return items
+
+
+@pytest.mark.parametrize("case", GMM_CASES)
+def test_grouped_matmul_kernel_is_a_matmul_per_group(case):
+    """The Pallas kernel in interpret mode against ragged_dot on every
+    row the groups own; rows past the last group are the caller's to
+    mask, and with no group the call still runs."""
+    x, w, sizes = _gmm_case(case)
     got = moe.grouped_matmul(x, w, sizes, interpret=True)
-    want = jax.lax.ragged_dot(x, w, sizes)
+    assert got.shape == (x.shape[0], w.shape[2])
     n = int(sizes.sum())
+    if n == 0:
+        return
+    want = jax.lax.ragged_dot(x[:n], w, sizes)
     np.testing.assert_allclose(np.asarray(got[:n], np.float32),
-                               np.asarray(want[:n], np.float32),
+                               np.asarray(want, np.float32),
                                atol=0.1, rtol=0.02)
-    none = moe.grouped_matmul(x, w, jnp.zeros((6,), jnp.int32),
-                              interpret=True)
-    assert none.shape == (384, 128)
+
+
+@pytest.mark.parametrize("case", GMM_CASES)
+def test_grouped_matmul_walks_its_work_items(case, monkeypatch):
+    """One grid step a work item, in row order, however many the shapes
+    would allow (n_tiles + E - 1: 132 at gen's decode, for ~10 items);
+    with none, one step that computes nothing."""
+    x, w, sizes = _gmm_case(case)
+    walked = []
+    kernel = moe._gmm_kernel
+
+    def counting(tile_ref, group_ref, *refs):
+        i = pl.program_id(0)
+        jax.debug.callback(lambda t, g: walked.append((int(t), int(g))),
+                           tile_ref[i], group_ref[i])
+        kernel(tile_ref, group_ref, *refs)
+
+    monkeypatch.setattr(moe, "_gmm_kernel", counting)
+    # unjitted, so that the patched kernel is traced
+    moe.grouped_matmul.__wrapped__(x, w, sizes, interpret=True)
+    items = _gmm_items(sizes)
+    assert len(walked) == max(len(items), 1)
+    if items:
+        assert walked == items
 
 
 def test_mixtral_routes_as_before():
